@@ -187,16 +187,42 @@ class FedAvgAPI:
         if str(device_resident).lower() in ("0", "false", "none", ""):
             device_resident = False
         chunk = getattr(args, "client_chunk", 8) or 8
+        wave_mode = int(getattr(args, "wave_mode", 1))
         # stacking copies the whole dataset host-side: only do it for the
         # paths that will consume it (single-chip residency, or mesh lanes);
         # compressed rounds thread EF residuals, which only the packed-
         # cohort round function does -- residency is bypassed there
-        wants_residency = (mesh is None
-                           or int(getattr(args, "wave_mode", 1)) in (2, 3))
-        stacked = (self._stack_if_fits(args)
-                   if device_resident and wants_residency
-                   and self.compressor is None
-                   and self.bucket_runner is None else None)
+        wants_residency = (device_resident and self.compressor is None
+                           and self.bucket_runner is None
+                           and (mesh is None or wave_mode in (2, 3)))
+        if wave_mode in (2, 3):
+            # lanes only exist over device-resident data: an option that
+            # bypasses residency would run the host-packed, compressed or
+            # bucketed round under the requested mode's name
+            bypass = ("--device_resident 0" if not device_resident
+                      else "--compressor" if self.compressor is not None
+                      else "--bucket_edges/--async_agg"
+                      if self.bucket_runner is not None else None)
+            if bypass is not None:
+                raise ValueError(
+                    f"--wave_mode {wave_mode} runs lanes over device-"
+                    f"resident data, which {bypass} bypasses; drop one of "
+                    "the two (--wave_mode 1 is the default)")
+            if wave_mode == 3 and spec.lane_loss_builder is None:
+                raise ValueError(
+                    f"--wave_mode 3 (MXU-packed lanes) needs a model "
+                    f"family with a lane-packed lowering "
+                    f"(models/lane_packed.py); spec '{spec.name}' has none "
+                    "-- use --wave_mode 2 for the generic vmap lanes")
+        stacked, nbytes = (self._stack_if_fits(args) if wants_residency
+                           else (None, 0))
+        if stacked is None and wave_mode in (2, 3):
+            raise ValueError(
+                f"--wave_mode {wave_mode} runs lanes over device-resident "
+                f"data, but the stacked client shards need "
+                f"{nbytes / 1e9:.2f} GB and --device_data_cap_gb is "
+                f"{float(getattr(args, 'device_data_cap_gb', 2.0)):g}; "
+                "raise the cap or use --wave_mode 1")
         self.packed_lane_runner = None
         if stacked is not None and mesh is None:
             import jax.numpy as jnp
@@ -205,29 +231,26 @@ class FedAvgAPI:
             self._client_ns = stacked["n"]
             # execution modes for device-resident rounds (--wave_mode):
             # 3 = MXU-packed lanes (lane axis folded into channels,
-            # models/lane_packed.py; falls back to 2 for model families
-            # without a packed lowering), 2 = packed lanes (one dispatch,
+            # models/lane_packed.py), 2 = packed lanes (one dispatch,
             # LPT-balanced), 1 = size-sorted waves (default), 0 = flat
             # single program (A/B / debugging)
             self.wave_runner = WaveRunner(
                 spec, cfg, payload_fn, server_fn, client_chunk=chunk)
             self.lane_runner = LaneRunner(
                 spec, cfg, payload_fn, server_fn, n_lanes=chunk)
-            if (int(getattr(args, "wave_mode", 1)) == 3
-                    and spec.lane_loss_builder is not None):
+            if wave_mode == 3:
                 self.packed_lane_runner = LaneRunner(
                     spec, cfg, payload_fn, server_fn, n_lanes=chunk,
                     packed=True)
             self.indexed_round_fn = make_indexed_sim_round(
                 spec, cfg, payload_fn, server_fn,
                 client_chunk=getattr(args, "client_chunk", None))
-        elif (stacked is not None and mesh is not None
-                and int(getattr(args, "wave_mode", 1)) in (2, 3)):
+        elif stacked is not None:
             # mesh + lanes: client rows live SHARDED over the mesh's
             # clients axis; each shard runs its residents as packed lanes
             # and aggregation is one psum (ShardedLaneRunner); wave_mode 3
             # additionally folds each shard's lane axis into channels
-            # (MXU-shaped lowering) when the model family supports it
+            # (MXU-shaped lowering)
             from fedml_tpu.parallel.multihost import global_cohort
             host = stacked["host"]
             placed = global_cohort(mesh, {"x": host["x"], "y": host["y"]})
@@ -235,9 +258,9 @@ class FedAvgAPI:
             self._client_ns = stacked["n"]
             self.sharded_lane_runner = ShardedLaneRunner(
                 spec, cfg, mesh, payload_fn, server_fn, n_lanes=chunk,
-                packed=(int(getattr(args, "wave_mode", 1)) == 3
-                        and spec.lane_loss_builder is not None))
-        self.server_state = server_state if server_state is not None else ()
+                packed=wave_mode == 3)
+        self.server_state = self.place_state(
+            server_state if server_state is not None else ())
 
         # over-selection + simulated deadline misses (--overselect /
         # --straggler_p): cohort restriction IS the renormalized partial
@@ -263,7 +286,8 @@ class FedAvgAPI:
 
         seed = getattr(args, "seed", 0)
         self.rng = jax.random.PRNGKey(seed)
-        self.global_state = spec.init_fn(jax.random.fold_in(self.rng, 0))
+        self.global_state = self.place_state(
+            spec.init_fn(jax.random.fold_in(self.rng, 0)))
         self._data_rng = np.random.default_rng(seed)
         self.round_idx = 0
         self.history = []
@@ -293,11 +317,24 @@ class FedAvgAPI:
             self._raw_payload_bytes = raw_payload_nbytes(
                 self.global_state["params"])
 
+    def place_state(self, tree):
+        """Put a global/server state pytree where the round functions
+        return it: replicated over the mesh on the sharded paths (a state
+        left on device 0 gives round 1 a new input sharding, and the whole
+        round program compiles a second time), untouched otherwise."""
+        if self.mesh is None:
+            return tree
+        from jax.sharding import PartitionSpec as P
+
+        from fedml_tpu.parallel.multihost import global_put
+        return global_put(self.mesh, tree, P())
+
     def _stack_if_fits(self, args):
         """Stack every client's padded shard for HBM residency when the
         result fits ``device_data_cap_gb``. Applies the optional bf16 cast
         (floating x only -- token ids would be corrupted). Returns
-        ``{"host": {"x","y"} numpy (cast applied), "n": [C]}`` or None."""
+        ``(stacked, nbytes)``: ``{"host": {"x","y"} numpy (cast applied),
+        "n": [C]}`` or None when over the cap, and the stack's size."""
         import jax.numpy as jnp
 
         C = len(self.train_data_local_dict)
@@ -312,14 +349,16 @@ class FedAvgAPI:
         row = (int(np.prod(x0.shape[1:], dtype=np.int64)) * x_itemsize
                + int(np.prod(y0.shape[1:], dtype=np.int64) or 1)
                * y0.dtype.itemsize)
+        nbytes = C * n_max * row
         cap = float(getattr(args, "device_data_cap_gb", 2.0)) * 1e9
-        if C * n_max * row > cap:
-            return None
+        if nbytes > cap:
+            return None, nbytes
         stacked = stack_clients(
             [self.train_data_local_dict[i] for i in range(C)])
         xh = (np.asarray(stacked["x"], dtype=jnp.bfloat16) if cast_bf16
               else stacked["x"])
-        return {"host": {"x": xh, "y": stacked["y"]}, "n": stacked["n"]}
+        return ({"host": {"x": xh, "y": stacked["y"]}, "n": stacked["n"]},
+                nbytes)
 
     def _sample_cohort(self, round_idx):
         """Cohort for one round: plain seeded sampling, or -- with
@@ -451,12 +490,10 @@ class FedAvgAPI:
                         self.global_state, self.server_state,
                         self.device_data, client_indexes, sched, round_rng)
             elif mode in (2, 3):
-                runner = (self.packed_lane_runner
-                          if mode == 3 and self.packed_lane_runner is not None
+                runner = (self.packed_lane_runner if mode == 3
                           else self.lane_runner)
                 with tracer.span("local-train",
-                                 mode="mxu-lanes" if runner is
-                                 self.packed_lane_runner else "lanes"):
+                                 mode="mxu-lanes" if mode == 3 else "lanes"):
                     (self.global_state, self.server_state,
                      info) = runner.run_round(
                         self.global_state, self.server_state,

@@ -28,7 +28,6 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
-from fedml_tpu.core.sharding import shard_map
 from fedml_tpu.parallel.engine import ClientUpdateConfig, make_optimizer
 from fedml_tpu.parallel.mesh import MODEL_AXIS
 from fedml_tpu.parallel.packing import pack_cohort, pack_eval
@@ -263,7 +262,7 @@ class FedGKTAPI:
         # axis 2; model/optimizer state replicated; logits return sharded
         # on their B axis and reassemble transparently
         data_spec = P(None, None, MODEL_AXIS)
-        return shard_map(
+        return jax.shard_map(
             server_round, mesh=mesh,
             in_specs=(P(), P(), data_spec, data_spec, data_spec, data_spec),
             out_specs=(P(), P(), data_spec),

@@ -77,4 +77,5 @@ class FedOptAPI(FedAvgAPI):
         super().__init__(dataset, spec, args, mesh=mesh,
                          payload_fn=payload_fn, server_fn=server_fn,
                          metrics_logger=metrics_logger, compressor=compressor)
-        self.server_state = server_tx.init(self.global_state["params"])
+        self.server_state = self.place_state(
+            server_tx.init(self.global_state["params"]))

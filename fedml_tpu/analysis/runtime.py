@@ -40,8 +40,8 @@ import threading
 
 from fedml_tpu.core.locks import creation_site as _creation_site
 
-#: jax.monitoring event names (stable strings from jax._src.dispatch;
-#: hardcoded so the auditor never imports private modules at import time).
+#: jax.monitoring event names (the strings jax's dispatch path records;
+#: hardcoded so the auditor never imports private modules).
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -193,7 +193,7 @@ def audit(metrics_logger=None, enabled=True, transfer_guard="device_to_host"):
     finally:
         _current = prev
         auditor._active = False
-        _unregister(auditor._on_event)
+        monitoring.unregister_event_duration_listener(auditor._on_event)
         report = auditor.report()
         logging.info("runtime audit: %s", report)
         if metrics_logger is not None:
@@ -386,20 +386,6 @@ def _report_to_registry(report):
             continue
         name = "audit_" + key.split("/", 1)[-1]
         reg.set_gauge(name, val, help="runtime auditor total")
-
-
-def _unregister(callback):
-    """Best-effort listener removal: jax only exposes clear-all publicly,
-    so reach for the testing hook and fall back to leaving the (inert)
-    listener registered on API drift."""
-    try:
-        from jax._src import monitoring as _mon
-        _mon._unregister_event_duration_listener_by_callback(callback)
-    # private-module drift shows up as the import failing or the hook
-    # being gone; a callback that is already unregistered trips the
-    # helper's own `assert callback in listeners` precondition
-    except (ImportError, AttributeError, AssertionError):
-        logging.debug("audit: could not unregister monitoring listener")
 
 
 __all__ = ["RuntimeAuditor", "audit", "current_auditor",

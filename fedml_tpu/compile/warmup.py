@@ -6,7 +6,7 @@ Design constraints that shaped this module:
   model tests), so warming cannot perturb ``compiled_shapes()`` or the
   zero-steady-state-retrace gates -- the dispatch path's own compile
   becomes a persistent-cache HIT whose ``backend_compile`` event carries
-  the cache-load time, not an XLA compile (measured, jax 0.4.37; see
+  the cache-load time, not an XLA compile (see
   ``jaxmon.CACHE_HIT_EVENT``).
 - **Shapes come from the same host code the round uses.** Where the
   round path builds host-side inputs (``pack_schedule``, ``pack_lanes``),

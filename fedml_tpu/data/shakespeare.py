@@ -128,3 +128,32 @@ def _load_leaf_shakespeare(data_dir, client_num=None):
     return [len(y_train), len(y_test),
             {"x": x_train, "y": y_train}, {"x": x_test, "y": y_test},
             train_num, train_local, test_local, VOCAB_SIZE]
+
+
+def synthetic_shakespeare_clients(clients, seq_len=SEQUENCE_LENGTH,
+                                  vocab=VOCAB_SIZE, seed=0):
+    """LEAF-Shakespeare-shaped synthetic population (zero-egress
+    environment): ragged per-client snippet counts (lognormal -- the
+    role-size skew of the real split), x int32 ``[n, T]`` token ids in
+    the real vocab range, y the shifted next-token targets. Identical
+    compute/communication profile to the real LEAF data. Returns the
+    8-tuple dataset contract."""
+    rng = np.random.default_rng(seed)
+    ns = np.clip(rng.lognormal(mean=2.5, sigma=1.0, size=clients),
+                 2, 400).astype(np.int64)
+    total = int(ns.sum())
+    seqs = rng.integers(1, vocab, (total, seq_len + 1))
+    x_all = seqs[:, :-1].astype(np.int32)
+    y_all = seqs[:, 1:].astype(np.int32)
+    local, local_num, test_local = {}, {}, {}
+    off = 0
+    for c in range(clients):
+        n = int(ns[c])
+        local[c] = {"x": x_all[off:off + n], "y": y_all[off:off + n]}
+        local_num[c] = n
+        test_local[c] = {"x": x_all[off:off + 1], "y": y_all[off:off + 1]}
+        off += n
+    n_test = min(64, total)
+    test = {"x": x_all[:n_test], "y": y_all[:n_test]}
+    return [total, n_test, {"x": x_all, "y": y_all}, test, local_num,
+            local, test_local, vocab]

@@ -26,7 +26,7 @@ repo's FLOPs source of record:
   ``enable()`` scope pushes the per-program catalog to the metrics
   sink on exit. Disabled cost: one module-global read per round.
 
-Dynamic-trip caveat (measured, jax 0.4.37 / XLA CPU+TPU): cost analysis
+Dynamic-trip caveat (measured on XLA CPU and TPU): cost analysis
 of a ``while``/``fori_loop`` with a traced trip count charges the loop
 body ONCE. For the bucket chunk programs that is exactly the useful
 number -- the cost of one step across all ``client_chunk`` lanes (plus
@@ -60,14 +60,11 @@ class ProgramCost:
 
 def compiled_cost(compiled) -> Optional[ProgramCost]:
     """``ProgramCost`` from a ``jax.stages.Compiled``, or None when the
-    backend exposes no usable cost analysis (older jax returns a list of
-    per-executable dicts, newer a dict; both are handled)."""
+    backend exposes no usable cost analysis."""
     try:
         ca = compiled.cost_analysis()
     except _COST_ERRORS:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     flops = float(ca.get("flops", -1.0) or -1.0)

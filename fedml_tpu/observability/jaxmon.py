@@ -26,11 +26,12 @@ import threading
 #: pins; see fedml_tpu.analysis.runtime).
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-#: persistent-compilation-cache outcomes. Measured (jax 0.4.37): a cache
-#: HIT still fires COMPILE_EVENT -- its duration is the cache-load time,
-#: not an XLA compile -- so the warm-restart gate is "zero cache MISSES"
-#: (every compile served from the warmed cache), not "zero compile
-#: events" (docs/OBSERVABILITY.md, fedwarm).
+#: persistent-compilation-cache outcomes. A cache HIT still fires
+#: COMPILE_EVENT -- its duration is the cache-load time, not an XLA
+#: compile -- so the warm-restart gate is "zero cache MISSES" (every
+#: compile served from the warmed cache), not "zero compile events"
+#: (docs/OBSERVABILITY.md, fedwarm). A MISS is recorded when an entry is
+#: WRITTEN: a compile under the persistence thresholds is neither.
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -145,27 +146,14 @@ class CompileWatcher:
         from jax import monitoring
         self._active = True
         monitoring.register_event_duration_secs_listener(self._on_event)
-        try:  # plain-event listener: the cache-outcome feed (older jax
-            # may lack it; durations still work without)
-            monitoring.register_event_listener(self._on_plain_event)
-            self._plain_registered = True
-        except AttributeError:
-            self._plain_registered = False
+        monitoring.register_event_listener(self._on_plain_event)
         return self
 
     def stop(self):
+        from jax import monitoring
         self._active = False
-        # jax only exposes clear-all publicly; reuse the auditor's
-        # best-effort dereg (leaving the inert listener on API drift)
-        from fedml_tpu.analysis.runtime import _unregister
-        _unregister(self._on_event)
-        if getattr(self, "_plain_registered", False):
-            try:
-                from jax._src import monitoring as _mon
-                _mon._unregister_event_listener_by_callback(
-                    self._on_plain_event)
-            except (ImportError, AttributeError, AssertionError):
-                pass  # inert listener stays registered on API drift
+        monitoring.unregister_event_duration_listener(self._on_event)
+        monitoring.unregister_event_listener(self._on_plain_event)
 
 
 @contextlib.contextmanager

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from fedml_tpu.core.sharding import shard_map
 from fedml_tpu.ops.attention import (NEG_INF, _finalize, _online_step,
                                      blockwise_attention)
 
@@ -112,7 +111,7 @@ def make_ring_attention(mesh, axis_name: str = SEQ_AXIS,
     body = partial(_ring_body, axis_name=axis_name, causal=causal,
                    scale=scale, block_size=block_size)
     spec = P(batch_axis, axis_name, None, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)
 
 

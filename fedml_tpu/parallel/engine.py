@@ -44,7 +44,6 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fedml_tpu.core import pytree
-from fedml_tpu.core.sharding import shard_map
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.costmodel import get_cost_model, program_cost
 from fedml_tpu.observability.tracing import get_tracer
@@ -476,10 +475,7 @@ class BucketedStreamRunner:
     def compiled_shapes(self) -> int:
         """Distinct compiled chunk programs (should equal the number of
         non-empty buckets ever dispatched -- the retrace-audit anchor)."""
-        try:
-            return int(self._chunk_fn._cache_size())
-        except AttributeError:  # older jax: no cache introspection
-            return -1
+        return int(self._chunk_fn._cache_size())
 
     def run_round(self, global_state, server_state, datasets, rng,
                   data_rng=None, aggregator=None, async_window=4,
@@ -1330,7 +1326,7 @@ class ShardedLaneRunner:
                                                 server_state, rng)
             return new_global, new_server, metrics
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), P(), P(CLIENT_AXIS), P(CLIENT_AXIS),
                       P(CLIENT_AXIS), P(CLIENT_AXIS), P(CLIENT_AXIS),
@@ -1595,7 +1591,7 @@ def make_sharded_round(spec: TrainSpec, cfg: ClientUpdateConfig, mesh,
             global_state, avg_payload, server_state, rng)
         return new_global, new_server_state, {"aux": aux, "metrics": metrics}
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(), P(CLIENT_AXIS), P()),
         out_specs=(P(), P(), P(CLIENT_AXIS)),

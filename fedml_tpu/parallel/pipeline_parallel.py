@@ -42,7 +42,6 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fedml_tpu.core.sharding import shard_map
 from fedml_tpu.models.transformer import TransformerLM, _Block, lm_loss
 
 STAGE_AXIS = "stage"
@@ -222,7 +221,7 @@ def make_pp_lm_step(model: TransformerLM, mesh, tx: Optional[Any] = None,
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, idx_m, tgt_m):
         def lf(p):
-            sm = shard_map(
+            sm = jax.shard_map(
                 _body, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(STAGE_AXIS),
                                        p["stages"]),

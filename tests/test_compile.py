@@ -5,7 +5,7 @@ The headline test mirrors a production restart: run k rounds, "kill"
 the server, resume a FRESH process-equivalent (new FedAvgAPI, new jit
 caches) via ``RoundRecovery`` over the SAME ``--compile_cache_dir`` --
 the resumed run must see ZERO persistent-cache misses (every compile is
-a cache load; measured on jax 0.4.37 a hit still fires the
+a cache load; a hit still fires the
 backend-compile event with the deserialization time, so the honest gate
 is misses == 0, not compile events == 0), zero steady-state compiles,
 and a bitwise-identical trajectory vs an uninterrupted run.

@@ -50,7 +50,6 @@ class TestPytree:
 
     def test_weighted_psum_mean_under_shard_map(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        from fedml_tpu.core.sharding import shard_map
 
         devs = np.array(jax.devices()[:8])
         mesh = Mesh(devs, ("clients",))
@@ -60,7 +59,7 @@ class TestPytree:
         def f(x, w):
             return pytree.tree_weighted_psum_mean(x[0], w[0, 0], "clients")[None]
 
-        out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("clients"), P("clients")),
+        out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("clients"), P("clients")),
                                 out_specs=P("clients")))(local, weights)
         expect = float(np.sum(np.arange(8) * np.arange(1, 9)) / 36.0)
         np.testing.assert_allclose(np.asarray(out)[0], expect, rtol=1e-6)

@@ -570,6 +570,71 @@ class TestFedAvgAPI:
             last = api.train_one_round()
         assert last["Train/Acc"] > first["Train/Acc"]
 
+    @pytest.mark.parametrize("mode", [1, 2])
+    def test_mesh_round_compiles_once(self, mode):
+        """The initial state must already live where the sharded rounds
+        return it (replicated over the mesh): a state left on device 0
+        hands round 1 a new input sharding and the whole round program
+        compiles a second time -- seconds here, most of a minute for
+        ResNet-56 on four chips."""
+        from fedml_tpu.observability.jaxmon import watch_compiles
+
+        dataset = load_synthetic_federated(client_num=8, n_train=640,
+                                           n_test=160, seed=0)
+        args = _args(client_num_per_round=8, comm_round=3, wave_mode=mode,
+                     client_chunk=2, device_resident="auto")
+        api = FedAvgAPI(dataset, _lr_spec(), args, mesh=make_client_mesh(8))
+        with watch_compiles() as watch:
+            for _ in range(3):
+                api.train_one_round()
+        assert watch.compiles_per_round[1:] == [0, 0], \
+            watch.compiles_per_round
+
+    def test_wave_mode_3_without_packed_lowering_is_an_error(self):
+        # no silent mode 2: the LR spec has no lane_loss_builder, so
+        # asking for MXU-packed lanes fails at construction, on one chip
+        # and on a mesh alike
+        dataset = load_synthetic_federated(client_num=8, n_train=640,
+                                           n_test=160, seed=0)
+        spec = _lr_spec()
+        assert spec.lane_loss_builder is None
+        args = _args(client_num_per_round=8, wave_mode=3, client_chunk=2,
+                     device_resident="auto")
+        for mesh in (None, make_client_mesh(8)):
+            with pytest.raises(ValueError, match="lane-packed lowering"):
+                FedAvgAPI(dataset, spec, args, mesh=mesh)
+
+    def test_lanes_over_the_data_cap_is_an_error(self):
+        # lanes run over device-resident data only: over the cap the API
+        # says so (size and cap) instead of switching to the host-packed
+        # round; wave_mode 1 keeps its documented auto behaviour
+        dataset = load_synthetic_federated(client_num=8, n_train=640,
+                                           n_test=160, seed=0)
+        spec = _lr_spec()
+        lanes = _args(client_num_per_round=8, wave_mode=2, client_chunk=2,
+                      device_resident="auto", device_data_cap_gb=1e-6)
+        with pytest.raises(ValueError, match=r"GB and "
+                                             r"--device_data_cap_gb is 1e-06"):
+            FedAvgAPI(dataset, spec, lanes)
+        waves = _args(client_num_per_round=8, wave_mode=1, client_chunk=2,
+                      device_resident="auto", device_data_cap_gb=1e-6)
+        assert FedAvgAPI(dataset, spec, waves).device_data is None
+
+    @pytest.mark.parametrize("bypass, match", [
+        (dict(device_resident="0"), "--device_resident 0"),
+        (dict(compressor="topk"), "--compressor"),
+        (dict(bucket_edges="geometric"), "--bucket_edges"),
+    ])
+    def test_lanes_with_residency_bypassed_is_an_error(self, bypass, match):
+        # an option that bypasses residency would run the host-packed,
+        # compressed or bucketed round under the lane mode's name
+        dataset = load_synthetic_federated(client_num=8, n_train=640,
+                                           n_test=160, seed=0)
+        args = _args(client_num_per_round=8, wave_mode=2, client_chunk=2,
+                     **{"device_resident": "auto", **bypass})
+        with pytest.raises(ValueError, match=match):
+            FedAvgAPI(dataset, _lr_spec(), args)
+
     def test_partial_participation(self):
         dataset = load_synthetic_federated(client_num=10, n_train=500,
                                            n_test=100, seed=0)

@@ -60,8 +60,8 @@ def test_merge_unmerge_roundtrip():
 class TestPallasGroupedConvDw:
     """The Pallas grouped-conv dW kernel (ops/pallas_grouped_conv.py):
     interpret-mode numerics gate vs the XLA reference lowering -- the
-    CPU half of the --lane_lowering pallas A/B the r8 TPU watch run
-    measures for speed."""
+    CPU half of the check; chip_smoke.py Leg B runs the compiled kernel
+    against XLA's dW at the flagship's stage shapes."""
 
     @pytest.mark.parametrize("s,p,k", [(1, 1, 3), (1, 0, 3), (1, 2, 5),
                                        (2, 1, 3), (2, 0, 1)])

@@ -410,8 +410,77 @@ def test_compilation_cache_persists_entries(tmp_path, monkeypatch):
     del t1, t2  # timings printed for debugging only
 
 
-def test_compilation_cache_opt_out(monkeypatch):
-    from fedml_tpu.utils.compile_cache import enable_compilation_cache
+def test_compilation_cache_env_var_wins_and_is_left_to_jax(
+        tmp_path, monkeypatch, restore_cache_config):
+    # JAX_COMPILATION_CACHE_DIR set: jax reads the variable itself (at
+    # import) and our code makes NO jax_compilation_cache_dir update --
+    # not even when an explicit directory is given; only the thresholds
+    import jax
 
-    monkeypatch.setenv("FEDML_TPU_COMPILE_CACHE", "0")
-    assert enable_compilation_cache() is None
+    from fedml_tpu.utils import compile_cache
+
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "outside"))
+    for explicit in (None, str(tmp_path / "flag")):
+        used = compile_cache.enable_compilation_cache(
+            explicit, min_compile_time_secs=0.0)
+        assert used == str(tmp_path / "outside")
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == before
+    assert set(updates) == {"jax_persistent_cache_min_entry_size_bytes",
+                            "jax_persistent_cache_min_compile_time_secs"}
+    assert not (tmp_path / "flag").exists()
+
+
+def test_compilation_cache_default_is_fixed_inside_the_checkout(
+        tmp_path, monkeypatch, restore_cache_config):
+    import jax
+
+    from fedml_tpu.utils import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+    # unset: the fixed in-checkout path, the same on every call
+    assert compile_cache.enable_compilation_cache() \
+        == compile_cache.enable_compilation_cache() \
+        == compile_cache.DEFAULT_DIR
+    assert jax.config.jax_compilation_cache_dir == compile_cache.DEFAULT_DIR
+    # an explicit directory still works when the variable is unset
+    flag = str(tmp_path / "flag")
+    assert compile_cache.enable_compilation_cache(flag) == flag
+    assert jax.config.jax_compilation_cache_dir == flag
+
+
+def test_compilation_cache_env_var_places_the_entries(tmp_path):
+    # end to end in a fresh process: with the variable set, the entries
+    # appear under it and nowhere else
+    import subprocess
+    import sys
+
+    cache = tmp_path / "some" / "dir"
+    prog = (
+        "import jax, jax.numpy as jnp, numpy as np\n"
+        "from fedml_tpu.utils.compile_cache import "
+        "enable_compilation_cache\n"
+        "print('USED', enable_compilation_cache("
+        "min_compile_time_secs=0.0))\n"
+        "np.asarray(jax.jit(lambda x: jnp.tanh(x @ x) + x)"
+        "(jnp.ones((64, 64))))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(cache))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    default = os.path.join(repo, ".jax_cache")
+    before = set(os.listdir(default)) if os.path.isdir(default) else set()
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, env=env, cwd=repo, timeout=300)
+    assert r.returncode == 0, r.stderr[-1500:]
+    assert f"USED {cache}" in r.stdout
+    assert [p for p in cache.iterdir() if not p.name.endswith("-atime")]
+    after = set(os.listdir(default)) if os.path.isdir(default) else set()
+    assert after == before
