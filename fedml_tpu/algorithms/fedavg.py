@@ -424,11 +424,14 @@ class FedAvgAPI:
         return client_indexes, packed
 
     def train_one_round(self):
-        # span model (docs/OBSERVABILITY.md): the jitted round fn is
-        # dispatched asynchronously, so "local-train" measures dispatch
-        # (plus any inline host compute) and the device time lands in
-        # "aggregate" -- the end-of-round sync is where the host actually
-        # waits for the round's outputs (exactly the FL114 lesson)
+        # span model (docs/OBSERVABILITY.md): where one jitted round fn is
+        # dispatched asynchronously, "local-train" measures dispatch and
+        # the device time lands in "aggregate" -- the end-of-round sync is
+        # where the host waits for the round's outputs (the FL114 lesson).
+        # The bucketed stream is the other case: its host fold touches
+        # every chunk's outputs inside run_round, so "local-train" holds
+        # the device wait and the fold (its fold.* children say which)
+        # and "aggregate" is about 0
         tracer = get_tracer()
         mon = get_perf_monitor()  # one global read when monitoring is off
         t0 = time.time()

@@ -21,11 +21,18 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 
 #: jax.monitoring event names (same stable strings the runtime auditor
 #: pins; see fedml_tpu.analysis.runtime).
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the other seconds a first call pays before its program runs (names as
+#: jax 0.9.0's dispatch.py and compiler.py record them): lowering the
+#: jaxpr to an MLIR module, and reading an executable back from the
+#: persistent cache (that read is also inside COMPILE_EVENT's duration)
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 #: persistent-compilation-cache outcomes. A cache HIT still fires
 #: COMPILE_EVENT -- its duration is the cache-load time, not an XLA
 #: compile -- so the warm-restart gate is "zero cache MISSES" (every
@@ -36,6 +43,16 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 _current = None
+
+
+def _union_seconds(intervals):
+    """Length of the union of ``[(start, end)]``."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
 
 
 def current_watcher():
@@ -59,6 +76,12 @@ class CompileWatcher:
         self.total_compiles = 0
         self.total_compile_seconds = 0.0
         self.total_traces = 0
+        # (start, end) of every tracing, lowering and cache-read event.
+        # An event arrives as its block ends, so its interval is known; a
+        # jit traced inside another's trace fires inside its parent's
+        # interval, and the union counts those seconds once
+        self._phases = {TRACE_EVENT: [], LOWER_EVENT: [],
+                        CACHE_LOAD_EVENT: []}
         # persistent-compilation-cache outcomes (plain jax.monitoring
         # events): a warmed cache turns every compile into a HIT whose
         # COMPILE_EVENT duration is the deserialization time -- the
@@ -73,6 +96,9 @@ class CompileWatcher:
         from fedml_tpu.observability.registry import get_registry
         reg = get_registry()
         with self._lock:
+            if event in self._phases:
+                now = time.perf_counter()
+                self._phases[event].append((now - float(duration_secs), now))
             if event == COMPILE_EVENT:
                 self._compiles += 1
                 self._compile_s += float(duration_secs)
@@ -126,6 +152,12 @@ class CompileWatcher:
                 "compile/total_seconds":
                     round(self.total_compile_seconds, 4),
                 "compile/total_traces": self.total_traces,
+                "compile/trace_seconds": round(
+                    _union_seconds(self._phases[TRACE_EVENT]), 4),
+                "compile/lower_seconds": round(
+                    _union_seconds(self._phases[LOWER_EVENT]), 4),
+                "compile/cache_load_seconds": round(
+                    _union_seconds(self._phases[CACHE_LOAD_EVENT]), 4),
                 "compile/cache_hits": self.cache_hits,
                 "compile/cache_misses": self.cache_misses,
             }
@@ -172,5 +204,5 @@ def watch_compiles():
 
 
 __all__ = ["CompileWatcher", "watch_compiles", "current_watcher",
-           "TRACE_EVENT", "COMPILE_EVENT", "CACHE_HIT_EVENT",
-           "CACHE_MISS_EVENT"]
+           "TRACE_EVENT", "COMPILE_EVENT", "LOWER_EVENT",
+           "CACHE_LOAD_EVENT", "CACHE_HIT_EVENT", "CACHE_MISS_EVENT"]

@@ -18,14 +18,23 @@ manager and whose ``inject`` leaves messages untouched -- a run without
 ``--trace`` sends bit-identical frames and executes no tracing code
 beyond one global read per instrumentation point.
 
+One clock with the profiler: a context-managed span of a real
+:class:`Tracer` also holds a ``jax.profiler.TraceAnnotation`` of its name
+for its lifetime, so inside a profiler session (``--xprof_round``, the
+benchmark's ``--trace 1``) the same spans stand on the host plane of the
+xplane beside the device's events. With no session open the annotation is
+a flag check; the no-op tracer never makes one.
+
 Stdlib-only at import time (the transports must stay importable without
-jax); ``jax.profiler`` integration is opt-in and imported lazily.
+jax): ``jax.profiler`` is taken from ``sys.modules`` and only when ``jax``
+is already there.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -58,10 +67,25 @@ class SpanContext:
             return None
 
 
+def _annotation(span):
+    """The profiler's annotation for ``span`` (scalar attrs ride along as
+    the event's stats), or None in a process that has not imported jax."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(
+        span.name, **{k: v for k, v in span.attrs.items()
+                      if isinstance(v, (int, float, str))})
+
+
 class Span:
     """One timed phase. Created by :meth:`Tracer.start_span` (detached --
-    for cross-thread begin/end like the server's per-attempt round span)
-    or :meth:`Tracer.span` (context manager, thread-local parentage)."""
+    for cross-thread begin/end like the server's per-attempt round span;
+    a span that may end on another thread cannot be a profiler
+    annotation, so these stay in the :class:`Tracer`'s record only) or
+    :meth:`Tracer.span` (context manager, thread-local parentage, also on
+    the profiler's timeline)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
                  "t0", "t1", "thread", "_tracer")
@@ -99,21 +123,27 @@ class Span:
 
 
 class _SpanScope:
-    """Context manager pairing a span with the thread-local stack."""
+    """Context manager pairing a span with the thread-local stack and
+    with its ``jax.profiler.TraceAnnotation``."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
     def __init__(self, tracer, span):
         self._tracer = tracer
         self.span = span
+        self._annotation = _annotation(span)
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._tracer._push(self.span.context)
         return self.span
 
     def __exit__(self, *exc):
         self._tracer._pop()
         self.span.end()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -187,7 +217,9 @@ class Tracer:
 
     def span(self, name, parent=None, root=False, **attrs):
         """Context-managed span parented on this thread's current context
-        (or ``parent`` when given); children opened inside see it."""
+        (or ``parent`` when given); children opened inside see it. It is a
+        ``jax.profiler.TraceAnnotation`` as well, so a profiler session
+        shows it under its own name."""
         return _SpanScope(self, self.start_span(name, parent=parent,
                                                 root=root, **attrs))
 

@@ -139,6 +139,7 @@ def _fwd_one_head(q, k, v, *, scale, causal, block_q, block_k, k_len,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -244,6 +245,7 @@ def _bwd_one_head(q, k, v, do, lse, dl, *, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, dl)
     # kv-outer grid: index maps see (kj, qi)
     qk_spec = pl.BlockSpec((block_q, D), lambda j, i: (i, 0))
@@ -261,6 +263,7 @@ def _bwd_one_head(q, k, v, do, lse, dl, *, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, dl)
     return dq, dk, dv
 

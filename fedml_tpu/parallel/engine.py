@@ -548,18 +548,29 @@ class BucketedStreamRunner:
         inflight = deque()
         exec_steps = 0
         per_bucket = []
+        # spans by the job done, one per step per chunk (never per leaf);
+        # PERF.md section 3 says which metric reads each
         tracer = get_tracer()
+
+        def note_bytes(sp, arrays):
+            # a walk over every leaf: traced rounds only
+            if tracer.enabled:
+                leaves = jax.tree.leaves(arrays)
+                sp.set(bytes=sum(int(a.nbytes) for a in leaves),
+                       arrays=len(leaves))
 
         def apply_avg(avg, f):
             # avg: f32 numpy pytree from the canonical fold; cast through
             # the payload dtype template (accumulators run f32/f64, the
             # model may not) and run the donated server step
             nonlocal gs, ss
-            avg_dev = jax.tree.map(
-                lambda a, d: jnp.asarray(np.asarray(a), d.dtype), avg,
-                dtypes)
-            gs, ss = self._advance_fn(gs, ss, avg_dev,
-                                      jax.random.fold_in(flush_rng, f))
+            with tracer.span("fold.apply") as sp:
+                avg_dev = jax.tree.map(
+                    lambda a, d: jnp.asarray(np.asarray(a), d.dtype), avg,
+                    dtypes)
+                note_bytes(sp, avg_dev)
+                gs, ss = self._advance_fn(gs, ss, avg_dev,
+                                          jax.random.fold_in(flush_rng, f))
 
         def fold_oldest():
             nonlocal flushes, metrics_acc
@@ -577,22 +588,35 @@ class BucketedStreamRunner:
             # point. Everything stays a device handle until here, so up
             # to async_window chunks genuinely overlap host packing/H2D
             # staging with device compute.
-            pay = jax.tree.map(np.asarray, handles[0])
-            w = float(np.asarray(handles[1]))
-            m_host = jax.tree.map(
-                lambda m: np.asarray(m, np.float64), handles[2])
-            metrics_acc = m_host if metrics_acc is None else \
-                jax.tree.map(np.add, metrics_acc, m_host)
+            if tracer.enabled:
+                # the wait apart from the copy. Every output of a chunk
+                # comes from one program, so waiting for the weight is
+                # waiting for them all, wait + copy is what the copy
+                # alone costs untraced, and the untraced path stays as is
+                with tracer.span("fold.wait", ordinal=ordinal):
+                    handles[1].block_until_ready()
+            with tracer.span("fold.d2h") as sp:
+                pay = jax.tree.map(np.asarray, handles[0])
+                w = float(np.asarray(handles[1]))
+                m_host = jax.tree.map(
+                    lambda m: np.asarray(m, np.float64), handles[2])
+                metrics_acc = m_host if metrics_acc is None else \
+                    jax.tree.map(np.add, metrics_acc, m_host)
+                note_bytes(sp, handles)
             staleness = (aggregator.version - born) if aggregator else 0
             if aggregator is None:
-                contrib = jax.tree.map(
-                    lambda x: np.asarray(x, np.float64), pay)
-                sync_acc["num"] = contrib if sync_acc["num"] is None \
-                    else jax.tree.map(np.add, sync_acc["num"], contrib)
-                sync_acc["w"] += w
+                with tracer.span("fold.convert") as sp:
+                    contrib = jax.tree.map(
+                        lambda x: np.asarray(x, np.float64), pay)
+                    note_bytes(sp, contrib)
+                with tracer.span("fold.add"):
+                    sync_acc["num"] = contrib if sync_acc["num"] is None \
+                        else jax.tree.map(np.add, sync_acc["num"], contrib)
+                    sync_acc["w"] += w
                 return
-            aggregator.fold(ordinal, w, pay, staleness=staleness,
-                            clients=k_real, preweighted=True)
+            with tracer.span("fold.add"):  # parent of buffer-fold
+                aggregator.fold(ordinal, w, pay, staleness=staleness,
+                                clients=k_real, preweighted=True)
             if aggregator.ready():
                 res = aggregator.flush("buffer_k")
                 apply_avg(res.params, flushes)
@@ -615,10 +639,12 @@ class BucketedStreamRunner:
             k = len(chunk)
             trip = int(steps_pc[chunk].max())
             edge = int(bucket_edge_for(trip, self.edges))
-            sched = pack_schedule([ns[i] for i in chunk], bs, self.epochs,
-                                  rng=data_rng, s_max=edge,
-                                  step_bucket=self.step_bucket)
-            xb, yb = gather_batches(datasets, sched, chunk)
+            with tracer.span("pack", clients=int(k),
+                             rows=sum(ns[i] for i in chunk)):
+                sched = pack_schedule([ns[i] for i in chunk], bs,
+                                      self.epochs, rng=data_rng, s_max=edge,
+                                      step_bucket=self.step_bucket)
+                xb, yb = gather_batches(datasets, sched, chunk)
             maskb = sched["mask"]
             n_arr = sched["n"]
             rngs = client_keys[chunk]
@@ -629,9 +655,11 @@ class BucketedStreamRunner:
                     (xb, yb, maskb, n_arr), pad)
                 rngs = np.concatenate([rngs, rngs[:1].repeat(pad, 0)])
             born = aggregator.version if aggregator else 0
-            batches_dev = {"x": jnp.asarray(xb), "y": jnp.asarray(yb),
-                           "mask": jnp.asarray(maskb)}
-            ns_dev, rngs_dev = jnp.asarray(n_arr), jnp.asarray(rngs)
+            with tracer.span("h2d") as sp:
+                batches_dev = {"x": jnp.asarray(xb), "y": jnp.asarray(yb),
+                               "mask": jnp.asarray(maskb)}
+                ns_dev, rngs_dev = jnp.asarray(n_arr), jnp.asarray(rngs)
+                note_bytes(sp, (batches_dev, ns_dev, rngs_dev))
             args = (gs, batches_dev, ns_dev, jnp.int32(trip), rngs_dev)
             ids = None
             if self.compressor is not None:
@@ -728,8 +756,10 @@ class BucketedStreamRunner:
             if sync_acc["num"] is None or total <= 0:
                 raise ValueError("bucketed round folded zero weight "
                                  "(every cohort shard empty?)")
-            avg = jax.tree.map(
-                lambda x: (x / total).astype(np.float32), sync_acc["num"])
+            with tracer.span("fold.finalize"):
+                avg = jax.tree.map(
+                    lambda x: (x / total).astype(np.float32),
+                    sync_acc["num"])
             apply_avg(avg, 0)
             flushes = 1
             async_info = None

@@ -1,0 +1,291 @@
+"""Spans inside the bucketed round (ISSUE 25): the fold's
+steps, the feed and the wait each have a span where the work happens, a
+real ``Tracer``'s spans stand on the profiler's timeline too, and the
+no-op tracer leaves the round bitwise what it was."""
+
+import os
+import subprocess
+import sys
+import types
+
+import numpy as np
+import pytest
+
+from fedml_tpu.observability import Tracer, set_tracer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CLIENTS, CHUNK = 10, 4
+CHUNKS = -(-CLIENTS // CHUNK)
+#: span -> how many a synchronous round of CHUNKS chunks holds
+SYNC_SPANS = {"pack": CHUNKS, "h2d": CHUNKS, "bucket-chunk": CHUNKS,
+              "fold.wait": CHUNKS, "fold.d2h": CHUNKS,
+              "fold.convert": CHUNKS, "fold.add": CHUNKS,
+              "fold.finalize": 1, "fold.apply": 1}
+
+
+def _api(**extra):
+    import jax.numpy as jnp
+
+    import bench
+    from fedml_tpu import models
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+    from fedml_tpu.algorithms.specs import make_classification_spec
+
+    spec = make_classification_spec(
+        models.LogisticRegression(num_classes=4, apply_sigmoid=False),
+        jnp.zeros((1, 16)))
+    args = types.SimpleNamespace(
+        client_num_in_total=CLIENTS, client_num_per_round=CLIENTS,
+        comm_round=10 ** 9, epochs=1, batch_size=8, lr=0.05, wd=0.0,
+        client_optimizer="sgd", frequency_of_the_test=10 ** 9, seed=0,
+        client_chunk=CHUNK, bucket_edges="geometric", device_resident="0",
+        **extra)
+    return FedAvgAPI(bench._ragged_lr_clients(CLIENTS), spec, args)
+
+
+def _round(tracer=None, **extra):
+    """One round of a fresh trainer; returns (api, metrics)."""
+    api = _api(**extra)
+    prev = set_tracer(tracer)
+    try:
+        metrics = api.train_one_round()
+    finally:
+        set_tracer(prev)
+    return api, metrics
+
+
+@pytest.fixture(scope="module")
+def traced():
+    """One synchronous round under a real tracer."""
+    tracer = Tracer()
+    api, metrics = _round(tracer)
+    return api, metrics, tracer.finished_spans()
+
+
+def _by_name(spans):
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    return by
+
+
+def _ancestors(span, by_id):
+    while span.parent_id in by_id:
+        span = by_id[span.parent_id]
+        yield span.name
+
+
+def test_round_yields_the_stream_spans_under_local_train(traced):
+    _, _, spans = traced
+    by_id = {s.span_id: s for s in spans}
+    for name, count in SYNC_SPANS.items():
+        mine = [s for s in spans if s.name == name]
+        assert len(mine) == count, name
+        for s in mine:
+            assert "local-train" in _ancestors(s, by_id), name
+    waits = sorted(s.attrs["ordinal"] for s in spans
+                   if s.name == "fold.wait")
+    assert waits == list(range(CHUNKS))
+
+
+def test_span_attributes_say_what_moved(traced):
+    api, _, spans = traced
+    by = _by_name(spans)
+    assert sum(s.attrs["clients"] for s in by["pack"]) == CLIENTS
+    assert sum(s.attrs["rows"] for s in by["pack"]) \
+        == sum(api.train_data_local_num_dict.values())
+    for name in ("h2d", "fold.d2h", "fold.convert", "fold.apply"):
+        assert all(s.attrs["bytes"] > 0 for s in by[name]), name
+    assert not any("bytes" in s.attrs for s in by["fold.add"])
+
+
+def test_byte_attributes_are_what_the_shapes_predict(traced):
+    import jax
+
+    api, _, spans = traced
+    by = _by_name(spans)
+    leaves = jax.tree.leaves(api.global_state)
+    payload = sum(a.nbytes for a in leaves)
+    extras = 4 + sum(np.asarray(v).astype(np.float32).nbytes
+                     for v in jax.tree.leaves(api._last_metrics))
+    assert [s.attrs["bytes"] for s in by["fold.d2h"]] \
+        == [payload + extras] * CHUNKS  # payload, weight and metrics
+    assert [s.attrs["bytes"] for s in by["fold.convert"]] \
+        == [2 * payload] * CHUNKS  # the float64 copy of a float32 payload
+    apply, = by["fold.apply"]
+    assert (apply.attrs["bytes"], apply.attrs["arrays"]) \
+        == (payload, len(leaves))
+    assert all(s.attrs["arrays"] == 5 for s in by["h2d"])
+
+
+def test_non_scalar_attributes_stay_out_of_the_annotation(tmp_path):
+    import jax
+
+    from benchmarks import trace_reader
+
+    tracer = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench_round"):
+            with tracer.span("listed", ranks=[1, 2], edge=8, note=None):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    span, = tracer.finished_spans()
+    assert span.attrs == {"ranks": [1, 2], "edge": 8, "note": None}
+    summary = trace_reader.read(trace_reader.find_xplane(str(tmp_path)))
+    assert "listed" in {n for n, _, _ in summary.host}
+
+
+def test_noop_and_real_tracer_rounds_are_bitwise_equal(traced):
+    import jax
+
+    api_on, m_on, _ = traced
+    api_off, m_off = _round()
+    on = jax.tree.leaves(jax.tree.map(np.asarray, api_on.global_state))
+    off = jax.tree.leaves(jax.tree.map(np.asarray, api_off.global_state))
+    assert len(on) == len(off)
+    for a, b in zip(on, off):
+        assert a.dtype == b.dtype and (a == b).all()
+    drop = {"round_time_s"}
+    assert {k: v for k, v in m_on.items() if k not in drop} \
+        == {k: v for k, v in m_off.items() if k not in drop}
+
+
+def test_buffered_path_folds_under_fold_add():
+    tracer = Tracer()
+    _round(tracer, async_agg=1, buffer_k=2, staleness_decay=0.5,
+           async_window=4)
+    spans = tracer.finished_spans()
+    by_id = {s.span_id: s for s in spans}
+    names = [s.name for s in spans]
+    assert names.count("fold.add") == CHUNKS
+    assert "fold.convert" not in names and "fold.finalize" not in names
+    assert names.count("fold.apply") >= 1
+    folds = [s for s in spans if s.name == "buffer-fold"]
+    assert len(folds) == CHUNKS
+    assert all(by_id[s.parent_id].name == "fold.add" for s in folds)
+
+
+def test_spans_are_host_events_of_the_profilers_trace(tmp_path):
+    import jax
+
+    from benchmarks import trace_reader
+
+    api = _api()
+    api.train_one_round()  # compile outside the traced round
+    tracer = Tracer()
+    prev = set_tracer(tracer)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench_round", round=0):
+            api.train_one_round()
+    finally:
+        jax.profiler.stop_trace()
+        set_tracer(prev)
+    summary = trace_reader.read(trace_reader.find_xplane(str(tmp_path)))
+    names = [n for n, _, _ in summary.host]
+    for name, count in SYNC_SPANS.items():
+        assert names.count(name) == count, name
+    assert names.count("round") == 1 and names.count("local-train") == 1
+    # the profiler's clock and the tracer's agree on every span's length
+    for name in ("local-train", "fold.finalize"):
+        span = next(s for s in tracer.finished_spans() if s.name == name)
+        start, end = next((a, b) for n, a, b in summary.host if n == name)
+        assert end - start == pytest.approx((span.t1 - span.t0) / 1e6,
+                                            abs=2e-3)
+
+
+def test_detached_spans_stay_off_the_profilers_timeline(tmp_path):
+    import jax
+
+    from benchmarks import trace_reader
+
+    tracer = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench_round"):
+            detached = tracer.start_span("attempt", root=True)
+            with tracer.span("managed", rank=3):
+                pass
+            detached.end()
+    finally:
+        jax.profiler.stop_trace()
+    summary = trace_reader.read(trace_reader.find_xplane(str(tmp_path)))
+    names = {n for n, _, _ in summary.host}
+    assert "managed" in names and "attempt" not in names
+    assert {s.name for s in tracer.finished_spans()} \
+        == {"managed", "attempt"}
+
+
+def test_observability_imports_and_traces_without_jax():
+    """``tracing.py`` is stdlib-only at import and a span of a process
+    that never imported jax is no annotation (``import jax`` is made to
+    fail there, so nothing can bring it in behind the test's back)."""
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "import fedml_tpu.observability as obs\n"
+        "from fedml_tpu.observability import flightrec, registry, tracing\n"
+        "t = obs.Tracer()\n"
+        "with t.span('round', round=1):\n"
+        "    with t.span('fold.d2h'):\n"
+        "        pass\n"
+        "assert [s.name for s in t.finished_spans()] == ['fold.d2h', "
+        "'round']\n"
+        "assert sys.modules['jax'] is None\n"
+        "assert not any(m.startswith('jax.') for m in sys.modules)\n")
+    done = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+def test_compile_watcher_reports_trace_lower_and_cache_load_seconds(
+        tmp_path, restore_cache_config):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from fedml_tpu.observability.jaxmon import watch_compiles
+
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compilation_cache.reset_cache()
+
+    def fresh():  # a new function each time: traced and lowered again
+        def body(x):
+            return jnp.tanh(x @ x.T).sum() * 25.0
+        return jax.jit(body)
+
+    x = jnp.ones((32, 32))
+    with watch_compiles() as cold:
+        fresh()(x).block_until_ready()
+    with watch_compiles() as warm:
+        fresh()(x).block_until_ready()
+    rc, rw = cold.report(), warm.report()
+    for r in (rc, rw):
+        assert r["compile/trace_seconds"] > 0
+        assert r["compile/lower_seconds"] > 0
+    assert rc["compile/cache_load_seconds"] == 0 and cold.cache_hits == 0
+    assert warm.cache_hits >= 1
+    assert 0 < rw["compile/cache_load_seconds"] <= rw["compile/total_seconds"]
+    # what the benchmark's harness reads keeps its meaning: backend
+    # compile seconds alone
+    assert rc["compile/total_seconds"] == round(
+        cold.total_compile_seconds, 4)
+
+
+def test_flash_kernels_carry_their_names():
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops.pallas_attention import flash_attention
+
+    x = jnp.zeros((1, 128, 1, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(x, x, x)
+    assert set(re.findall(r"flash_\w+", str(jaxpr))) \
+        == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
