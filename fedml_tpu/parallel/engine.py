@@ -48,6 +48,7 @@ from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.costmodel import get_cost_model, program_cost
 from fedml_tpu.observability.tracing import get_tracer
 from fedml_tpu.parallel.mesh import CLIENT_AXIS, zero_pad_leading
+from fedml_tpu.program.aggregation import Float64Accumulator
 
 
 @dataclasses.dataclass(frozen=True)
@@ -353,8 +354,9 @@ class BucketedStreamRunner:
       the chunk's weighted payload SUM -- O(client_chunk) data and O(1)
       model state on device, regardless of cohort size. The per-chunk
       partials fold on host in float64 (the
-      ``resilience.policy.fold_entries_fp64`` canonical fold) and one
-      jitted ``advance_fn`` applies the server update.
+      ``program.aggregation.fold_entries_fp64`` canonical fold, into
+      one standing ``Float64Accumulator``, in place) and one jitted
+      ``advance_fn`` applies the server update.
     - **One compiled program per bucket shape**, pinned: ``trip`` is
       traced and every chunk of a bucket shares the edge-padded shape, so
       steady-state retraces are zero and ``compiled_shapes()`` equals the
@@ -459,6 +461,11 @@ class BucketedStreamRunner:
         self._chunk_fn = chunk_fn
         self._advance_fn = advance_fn
         self._dtypes = None
+        # the synchronous fold's float64 numerator, kept across rounds:
+        # from this runner's second round on, the first chunk's payload
+        # is converted into standing memory (host-only; 8 bytes a
+        # payload element held between rounds)
+        self._sync_acc = Float64Accumulator()
         # per-bucket-edge ProgramCost (or None for "probed, no cost
         # analysis"), populated lazily ONLY while a CostModel is armed;
         # the AOT probe compiles once per edge (warm-up round) and never
@@ -543,8 +550,11 @@ class BucketedStreamRunner:
         # sync path: incremental canonical fold. Entries are consumed in
         # ordinal (= sorted-key) order, so accumulating here is bitwise
         # fold_entries_fp64 over the same entries -- with O(1 model) host
-        # memory instead of retaining every chunk payload to round end
-        sync_acc = {"num": None, "w": 0.0}
+        # memory, the float64 copies included: one standing accumulator
+        # takes every chunk's payload in place, and no payload is
+        # retained to round end
+        sync_acc = self._sync_acc
+        sync_w, sync_folds = 0.0, 0
         inflight = deque()
         exec_steps = 0
         per_bucket = []
@@ -573,7 +583,7 @@ class BucketedStreamRunner:
                                           jax.random.fold_in(flush_rng, f))
 
         def fold_oldest():
-            nonlocal flushes, metrics_acc
+            nonlocal flushes, metrics_acc, sync_w, sync_folds
             ordinal, born, k_real, handles, scatter = inflight.popleft()
             if scatter is not None:
                 # EF residual write-back, deferred to the fold point (the
@@ -605,14 +615,18 @@ class BucketedStreamRunner:
                 note_bytes(sp, handles)
             staleness = (aggregator.version - born) if aggregator else 0
             if aggregator is None:
-                with tracer.span("fold.convert") as sp:
-                    contrib = jax.tree.map(
-                        lambda x: np.asarray(x, np.float64), pay)
-                    note_bytes(sp, contrib)
-                with tracer.span("fold.add"):
-                    sync_acc["num"] = contrib if sync_acc["num"] is None \
-                        else jax.tree.map(np.add, sync_acc["num"], contrib)
-                    sync_acc["w"] += w
+                if sync_folds == 0:
+                    # the round's one conversion: the first chunk's
+                    # payload written into the accumulator's own arrays
+                    with tracer.span("fold.convert") as sp:
+                        reused = sync_acc.start(pay)
+                        sp.set(bytes=sync_acc.nbytes,
+                               arrays=sync_acc.arrays, reused=int(reused))
+                else:
+                    with tracer.span("fold.add"):
+                        sync_acc.add(pay)
+                sync_w += w
+                sync_folds += 1
                 return
             with tracer.span("fold.add"):  # parent of buffer-fold
                 aggregator.fold(ordinal, w, pay, staleness=staleness,
@@ -752,14 +766,14 @@ class BucketedStreamRunner:
             async_info = aggregator.record()
             async_info["async/flushes_this_round"] = flushes
         else:
-            total = sync_acc["w"]
-            if sync_acc["num"] is None or total <= 0:
+            if sync_folds == 0 or sync_w <= 0:
                 raise ValueError("bucketed round folded zero weight "
                                  "(every cohort shard empty?)")
             with tracer.span("fold.finalize"):
-                avg = jax.tree.map(
-                    lambda x: (x / total).astype(np.float32),
-                    sync_acc["num"])
+                # a fresh float32 tree every round: apply_avg hands it
+                # to the device, which may alias it (CPU) or still be
+                # reading it (TPU) when the next round folds
+                avg = sync_acc.finish(sync_w)
             apply_avg(avg, 0)
             flushes = 1
             async_info = None

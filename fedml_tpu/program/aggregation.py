@@ -18,7 +18,7 @@ streaming, both distributed servers, the fan-in edges) folds through
 THIS module; fedlint FL130 flags new out-of-band folds.
 
 Host-importable without jax at module scope (the fold imports jax
-lazily -- its ``jax.tree.map`` over numpy leaves never touches a
+lazily -- its ``jax.tree.flatten`` over numpy leaves never touches a
 device), which is what keeps ``RoundProgram.host_view()`` jax-free.
 """
 
@@ -107,6 +107,108 @@ def staleness_weight(staleness, decay) -> float:
     return float((1.0 + s) ** -float(decay))
 
 
+def _addend(x):
+    """``x`` as a numpy array that a ufunc can widen to float64 as it
+    reads it (no float64 copy of the leaf). A dtype numpy cannot promote
+    against float64 inside a ufunc, or would promote beyond it, is
+    converted first -- the values are then what ``np.asarray(x,
+    np.float64)`` gives, as they always were."""
+    x = np.asarray(x)
+    try:
+        direct = np.result_type(x.dtype, np.float64) == np.float64
+    except TypeError:
+        direct = False
+    return x if direct else x.astype(np.float64)
+
+
+class Float64Accumulator:
+    """The float64 numerator of THE fold, written where it stands.
+
+    One float64 array per payload leaf, allocated by the accumulator and
+    kept from one fold to the next while the payload's tree and shapes
+    stay what they were: :meth:`start` writes ``float64(payload) *
+    scale`` into those arrays, :meth:`add` adds ``float64(payload) *
+    scale`` to them in place, :meth:`finish` divides and rounds to a
+    fresh float32 tree in one pass. The sums and their order are exactly
+    those of the out-of-place formula (``acc = f64(p0) * s0``; ``acc =
+    acc + f64(p) * s``; ``(acc / total).astype(float32)``): a float32
+    leaf is widened as it is read, a scale of exactly 1.0 skips the
+    multiply (``x * 1.0 == x``), any other scale goes through ONE
+    float64 scratch array (never a fused multiply-add), and the quotient
+    is computed in float64 and rounded once.
+
+    No payload is ever written to or kept, and the accumulator's arrays
+    are never anyone else's memory (``np.asarray`` of a jax array is the
+    runtime's read-only host copy; of a float64 array it is the array
+    itself). Host-only: nothing here is handed to a device.
+    """
+
+    def __init__(self):
+        self._treedef = None
+        self._acc = None      # [float64 ndarray], one per payload leaf
+        self._scratch = None  # flat float64, the largest leaf's size
+        self.started = False  # between start() and finish()
+
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in self._acc or ())
+
+    @property
+    def arrays(self) -> int:
+        return len(self._acc or ())
+
+    def start(self, payload, scale=1.0) -> bool:
+        """Begin a fold from ``float64(payload) * scale``. True when the
+        standing arrays took it, False when they had to be allocated
+        (the first fold, or a payload of another tree or other shapes)."""
+        import jax
+
+        leaves, treedef = jax.tree.flatten(payload)
+        leaves = [_addend(x) for x in leaves]
+        reused = (self._acc is not None and treedef == self._treedef
+                  and [a.shape for a in self._acc]
+                  == [x.shape for x in leaves])
+        if not reused:
+            self._treedef = treedef
+            self._acc = [np.empty(x.shape, np.float64) for x in leaves]
+            self._scratch = None
+        scale = float(scale)
+        for a, x in zip(self._acc, leaves):
+            if scale == 1.0:
+                np.copyto(a, x)
+            else:
+                np.multiply(x, scale, out=a, dtype=np.float64)
+        self.started = True
+        return reused
+
+    def add(self, payload, scale=1.0) -> None:
+        """``acc += float64(payload) * scale``, in place."""
+        if not self.started:
+            raise ValueError("add() to an accumulator that was not started")
+        scale = float(scale)
+        leaves = [_addend(x) for x in self._treedef.flatten_up_to(payload)]
+        if scale != 1.0 and self._scratch is None:
+            self._scratch = np.empty(
+                max((a.size for a in self._acc), default=0), np.float64)
+        for a, x in zip(self._acc, leaves):
+            if scale != 1.0:
+                x = np.multiply(
+                    x, scale, dtype=np.float64,
+                    out=self._scratch[:a.size].reshape(a.shape))
+            np.add(a, x, out=a)
+
+    def finish(self, total):
+        """``float32(acc / total)`` as a fresh tree; ends the fold."""
+        if not self.started:
+            raise ValueError("finish() of an accumulator that was not "
+                             "started")
+        self.started = False
+        return self._treedef.unflatten([
+            np.divide(a, total, out=np.empty(a.shape, np.float32),
+                      casting="same_kind")
+            for a in self._acc])
+
+
 def fold_entries_fp64(entries) -> tuple:
     """THE canonical weighted fold: sorted-key, float64, normalize-late.
 
@@ -139,9 +241,13 @@ def fold_entries_fp64(entries) -> tuple:
     the async path with staleness weight 1 and one flush reproduces
     :func:`aggregate_reports` bit-for-bit no matter which order the
     reports raced in.
-    """
-    import jax
 
+    The numerator lives in one :class:`Float64Accumulator` for the call
+    (the bucketed stream's synchronous fold keeps one across rounds):
+    every dense entry, base and the delta accumulator is added to it in
+    place, so a fold allocates one float64 tree and at most one scratch
+    array, whatever the number of entries. No payload is written to.
+    """
     from fedml_tpu.compression.wire import CompressedUpdate
 
     entries = sorted(entries, key=lambda e: e[0])
@@ -149,7 +255,11 @@ def fold_entries_fp64(entries) -> tuple:
         raise ValueError("weighted fold over an empty entry set "
                          "(abandon/skip instead)")
     total = 0.0
-    acc = None          # dense contributions (f64 pytree)
+    acc = Float64Accumulator()  # dense contributions
+
+    def fold_in(payload, scale):
+        (acc.add if acc.started else acc.start)(payload, scale)
+
     cacc = None         # compressed-delta contributions ({name: f64})
     base_acc = {}       # base_key -> [scale_sum, base params]
     for _key, weight, payload, scale in entries:
@@ -160,22 +270,17 @@ def fold_entries_fp64(entries) -> tuple:
                                        [0.0, payload.base])
             slot[0] += float(scale)
             continue
-        contrib = jax.tree.map(
-            lambda x: np.asarray(x, np.float64) * float(scale), payload)
-        acc = contrib if acc is None else jax.tree.map(np.add, acc, contrib)
+        fold_in(payload, scale)
     # canonical combine order: dense entries (sorted), then each distinct
     # base (sorted by key), then the sparse delta accumulator
     for bk in sorted(base_acc):
         scale_sum, base = base_acc[bk]
-        bcontrib = jax.tree.map(
-            lambda x: np.asarray(x, np.float64) * float(scale_sum), base)
-        acc = bcontrib if acc is None else jax.tree.map(np.add, acc,
-                                                        bcontrib)
+        fold_in(base, scale_sum)
     if cacc is not None:
-        acc = cacc if acc is None else jax.tree.map(np.add, acc, cacc)
+        fold_in(cacc, 1.0)
     if total <= 0:
         raise ValueError("weighted fold has zero total weight")
-    return jax.tree.map(lambda x: (x / total).astype(np.float32), acc), total
+    return acc.finish(total), total
 
 
 def aggregate_reports(reports) -> tuple:
@@ -405,5 +510,6 @@ class BufferedAggregator:
 
 
 __all__ = ["AGG_SYNC", "AGG_ASYNC", "AggregationPolicy",
-           "staleness_weight", "fold_entries_fp64", "aggregate_reports",
+           "staleness_weight", "Float64Accumulator", "fold_entries_fp64",
+           "aggregate_reports",
            "FlushResult", "BufferedAggregator"]

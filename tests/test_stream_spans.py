@@ -16,10 +16,12 @@ from fedml_tpu.observability import Tracer, set_tracer
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CLIENTS, CHUNK = 10, 4
 CHUNKS = -(-CLIENTS // CHUNK)
-#: span -> how many a synchronous round of CHUNKS chunks holds
+#: span -> how many a synchronous round of CHUNKS chunks holds: the first
+#: chunk's payload starts the float64 accumulator (``fold.convert``),
+#: every later chunk is added to it in place (``fold.add``)
 SYNC_SPANS = {"pack": CHUNKS, "h2d": CHUNKS, "bucket-chunk": CHUNKS,
               "fold.wait": CHUNKS, "fold.d2h": CHUNKS,
-              "fold.convert": CHUNKS, "fold.add": CHUNKS,
+              "fold.convert": 1, "fold.add": CHUNKS - 1,
               "fold.finalize": 1, "fold.apply": 1}
 
 
@@ -110,8 +112,10 @@ def test_byte_attributes_are_what_the_shapes_predict(traced):
                      for v in jax.tree.leaves(api._last_metrics))
     assert [s.attrs["bytes"] for s in by["fold.d2h"]] \
         == [payload + extras] * CHUNKS  # payload, weight and metrics
-    assert [s.attrs["bytes"] for s in by["fold.convert"]] \
-        == [2 * payload] * CHUNKS  # the float64 copy of a float32 payload
+    convert, = by["fold.convert"]  # the float64 accumulator, once a round
+    assert (convert.attrs["bytes"], convert.attrs["arrays"]) \
+        == (2 * payload, len(leaves))
+    assert convert.attrs["reused"] == 0  # a trainer's first round allocates
     apply, = by["fold.apply"]
     assert (apply.attrs["bytes"], apply.attrs["arrays"]) \
         == (payload, len(leaves))
