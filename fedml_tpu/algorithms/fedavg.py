@@ -18,6 +18,7 @@ import numpy as np
 
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.perfmon import get_perf_monitor
+from fedml_tpu.observability.routing import note_routing, routing_counters
 from fedml_tpu.observability.tracing import get_tracer
 from fedml_tpu.utils.profiling import end_of_round_sync
 from fedml_tpu.parallel.engine import (
@@ -461,7 +462,7 @@ class FedAvgAPI:
                 raise ValueError(f"round {self.round_idx}: every sampled "
                                  f"client has an empty shard")
             with tracer.span("local-train", mode="bucketed",
-                             clients=len(client_indexes)):
+                             clients=len(client_indexes)) as sp:
                 (self.global_state, self.server_state,
                  info) = self.bucket_runner.run_round(
                     self.global_state, self.server_state, datasets,
@@ -472,6 +473,8 @@ class FedAvgAPI:
                     residual_store=(self._ef_store
                                     if self.compressor is not None
                                     else None))
+                # the stream's metric sums are on the host already
+                sp.set(**routing_counters(info["metrics"]))
             self._last_bucket_info = info
             self._last_cohort_size = len(client_indexes)
         elif self.device_data is not None:
@@ -548,6 +551,7 @@ class FedAvgAPI:
             "Train/Acc": float(m["correct"].sum() / max(m["count"].sum(), 1)),
             "round_time_s": dt,
         }
+        train_metrics.update(note_routing(m))  # {} unless experts routed
         if self._last_res_record is not None:
             train_metrics.update(self._last_res_record)
         if self.bucket_runner is not None:

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from fedml_tpu.core.trainer import TrainSpec
 
 
-def _apply_model(model, state, x, rng, train, with_sown=False):
+def _apply_model(model, state, x, rng, train, with_sown=False,
+                 with_metrics=False):
     """Apply with train-time collection handling.
 
     ``with_sown=True`` (the loss_fn path in every spec) also collects
@@ -22,11 +23,16 @@ def _apply_model(model, state, x, rng, train, with_sown=False):
     and returns ``(out, new_state, aux_scalar)``; aux is 0.0 for models
     that sow nothing, so non-MoE behavior is unchanged. ``with_sown=
     False`` (eval/metrics path) returns ``(out, new_state)`` -- sow is a
-    no-op when the collection is not mutable."""
+    no-op when the collection is not mutable. ``with_metrics=True`` (with
+    ``with_sown``) appends the counters the model sows into ``metrics``
+    (the routing counters of ``models/deepseek_v3.py``), summed by name
+    over the modules that sowed them: ``{}`` for a model that sows none.
+    """
     variables = dict(state)
     rngs = ({"dropout": rng, "droppath": jax.random.fold_in(rng, 7)}
             if (train and rng is not None) else None)
     mutable = ((["losses"] if with_sown else [])
+               + (["metrics"] if with_metrics else [])
                + (["batch_stats"]
                   if ("batch_stats" in state and train) else []))
     if not mutable:
@@ -41,7 +47,14 @@ def _apply_model(model, state, x, rng, train, with_sown=False):
     if not with_sown:
         return out, new_state
     aux = sum(jax.tree.leaves(mutated.get("losses", {})), 0.0)
-    return out, new_state, aux
+    if not with_metrics:
+        return out, new_state, aux
+    sown = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            mutated.get("metrics", {}))[0]:
+        name = str(getattr(path[-1], "key", path[-1]))
+        sown[name] = sown.get(name, 0.0) + leaf
+    return out, new_state, aux, sown
 
 
 def _init_state(model, example_x, rng):
@@ -50,6 +63,7 @@ def _init_state(model, example_x, rng):
     aggregated pytree."""
     variables = dict(model.init(rng, example_x, train=False))
     variables.pop("losses", None)
+    variables.pop("metrics", None)
     return variables
 
 
@@ -119,6 +133,8 @@ def make_seq_classification_spec(model, example_x, ignore_index=0,
     Losses the model sows (the MoE load-balancing aux,
     ``models/moe.py``) are added at ``aux_loss_weight`` during training
     -- federated MoE trains with balanced routing out of the box.
+    Counters the model sows into ``metrics`` (``models/deepseek_v3.py``'s
+    routing counters) join the step's metric sums under their own names.
     """
 
     def init_fn(rng):
@@ -136,9 +152,13 @@ def make_seq_classification_spec(model, example_x, ignore_index=0,
                       "correct": correct, "count": count}
 
     def loss_fn(state, batch, rng, train):
-        logits, new_state, aux = _apply_model(model, state, batch["x"],
-                                              rng, train, with_sown=True)
+        logits, new_state, aux, sown = _apply_model(
+            model, state, batch["x"], rng, train, with_sown=True,
+            with_metrics=True)
         loss, metrics = _loss_and_metrics(logits, batch["y"], batch["mask"])
+        # a step of padding only (a ragged lane's tail) counts nothing
+        live = (jnp.sum(batch["mask"]) > 0).astype(jnp.float32)
+        metrics.update({k: v * live for k, v in sown.items()})
         return loss + aux_loss_weight * aux, (new_state, metrics)
 
     def metrics_fn(state, batch):

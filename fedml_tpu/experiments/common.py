@@ -93,6 +93,9 @@ def add_base_args(parser: argparse.ArgumentParser):
                         "compression_ratio per round; default off")
     p.add_argument("--moe_experts", type=int, default=8,
                    help="expert count for --model moe_transformer")
+    p.add_argument("--model_config", type=str, default=None,
+                   help="JSON file of --model deepseek_v3: the family's "
+                        "config.json keys (models/deepseek_v3.py)")
     p.add_argument("--model_dtype", type=str, default=None,
                    choices=("bf16", "bfloat16"),
                    help="compute-dtype for the model zoo: bf16 runs convs/"

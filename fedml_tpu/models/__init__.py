@@ -15,6 +15,8 @@ from fedml_tpu.models.vgg import VGG, vgg11, vgg13, vgg16, vgg19  # noqa: F401
 from fedml_tpu.models.rnn import RNNOriginalFedAvg, RNNStackOverflow  # noqa: F401
 from fedml_tpu.models.transformer import TransformerLM, transformer_nwp  # noqa: F401
 from fedml_tpu.models.moe import MoEBlock, MoEMLP, MoETransformerLM  # noqa: F401
+from fedml_tpu.models import deepseek_v3  # noqa: F401
+from fedml_tpu.models.deepseek_v3 import DecoderConfig, DeepseekV3LM  # noqa: F401
 from fedml_tpu.models.gkt import (  # noqa: F401
     GKTClientResNet, GKTServerResNet, resnet5_56, resnet8_56, resnet56_server)
 from fedml_tpu.models.linear import DenseModel, LocalModel  # noqa: F401

@@ -14,7 +14,11 @@ def create_model(args, model_name, output_dim):
     Accepted names (reference ``main_fedavg.py:217-252`` plus aliases):
     lr, cnn, cnn_dropout, resnet56, resnet110, resnet18_gn, resnet34_gn,
     resnet50_gn, mobilenet, mobilenet_v3, efficientnet[-b0..b7],
-    vgg11/13/16/19, rnn (shakespeare LSTM), rnn_stackoverflow.
+    vgg11/13/16/19, rnn (shakespeare LSTM), rnn_stackoverflow,
+    transformer, moe_transformer, deepseek_v3 (a decoder read from
+    ``--model_config``, a JSON file with the family's ``config.json``
+    keys: latent attention, routed experts of which ``experts_held`` are
+    here, ``models/deepseek_v3.py``).
     """
     from fedml_tpu import models
 
@@ -77,4 +81,14 @@ def create_model(args, model_name, output_dim):
         experts = getattr(args, "moe_experts", 8) if args else 8
         return models.MoETransformerLM(vocab_size=output_dim,
                                        n_experts=experts, **dt)
+    if model_name == "deepseek_v3":
+        path = getattr(args, "model_config", None) if args else None
+        if not path:
+            raise ValueError("--model deepseek_v3 is built from a "
+                             "configuration file: pass --model_config")
+        # the data's vocabulary is the model's (a sliced one is a smaller
+        # vocabulary: ids, logits and loss are over it)
+        return models.DeepseekV3LM(
+            models.deepseek_v3.load_config(path, vocab_size=output_dim),
+            **dt)
     raise ValueError(f"unknown model: {model_name}")

@@ -1,0 +1,54 @@
+"""Counters of expert routing, per round.
+
+A routed-expert layer (``fedml_tpu.models.deepseek_v3.RoutedExperts``)
+sows four sums a step into the client-update program's metrics; the round
+sums them over steps, layers and clients. ``routing_counters`` makes the
+round's three series of them:
+
+- ``moe_rows_held``: assignments (token x chosen expert) that landed on
+  the experts held here: the rows the grouped products computed;
+- ``moe_load_max_over_mean``: the fullest held expert's rows over the
+  mean held expert's, both summed over the round's layer-steps (1.0 is a
+  perfectly even load);
+- ``moe_dropped``: assignments to a held expert that got no row. The
+  layer has a row for every assignment, so this stays 0.
+
+``note_routing`` also sets the registry's gauges of the same names; the
+round (``algorithms/fedavg.py``) puts the three into its record
+(``metrics.jsonl``) and, on the bucketed stream, whose metrics reach the
+host inside it, onto the ``local-train`` span.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from fedml_tpu.observability.registry import get_registry
+
+SERIES = ("moe_rows_held", "moe_load_max_over_mean", "moe_dropped")
+
+
+def routing_counters(metrics) -> dict:
+    """``{}`` for a model that routes nothing."""
+    if not metrics or "moe_rows_held" not in metrics:
+        return {}
+    total = {k: float(np.sum(np.asarray(metrics[k])))
+             for k in ("moe_rows_held", "moe_load_max", "moe_load_mean",
+                       "moe_dropped")}
+    return {"moe_rows_held": total["moe_rows_held"],
+            "moe_load_max_over_mean":
+                total["moe_load_max"] / max(total["moe_load_mean"], 1e-30),
+            "moe_dropped": total["moe_dropped"]}
+
+
+def note_routing(metrics) -> dict:
+    counters = routing_counters(metrics)
+    reg = get_registry()
+    if counters and reg is not None:
+        for name, value in counters.items():
+            reg.set_gauge(name, value, help="expert routing, last round "
+                                            "(observability/routing.py)")
+    return counters
+
+
+__all__ = ["SERIES", "routing_counters", "note_routing"]

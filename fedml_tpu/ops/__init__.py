@@ -13,6 +13,9 @@ net-new long-context layer the TPU rebuild makes first-class:
   ``ppermute`` over ICI while every shard keeps only its own Q.
 - :mod:`fedml_tpu.ops.pallas_attention` -- fused flash-attention forward as a
   Pallas TPU kernel (VMEM-blocked, MXU matmuls), with a recompute backward.
+- :mod:`fedml_tpu.ops.grouped_matmul` -- the grouped matrix product over the
+  experts a chip holds (rows sorted by expert, no drop), forward and backward
+  (imported as a module: its function has the module's name).
 """
 
 from fedml_tpu.ops.attention import blockwise_attention, mha

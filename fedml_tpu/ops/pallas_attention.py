@@ -111,8 +111,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
 def _fwd_one_head(q, k, v, *, scale, causal, block_q, block_k, k_len,
                   interpret):
-    Tq, D = q.shape
-    Tk = k.shape[0]
+    Tq, D = q.shape          # the score width (q and k)
+    Tk, Dv = v.shape         # the value width (v and o)
     grid = (pl.cdiv(Tq, block_q), pl.cdiv(Tk, block_k))
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -123,18 +123,18 @@ def _fwd_one_head(q, k, v, *, scale, causal, block_q, block_k, k_len,
         in_specs=[
             pl.BlockSpec((block_q, D), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, D), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_k, D), lambda i, j: (j, 0)),
+            pl.BlockSpec((block_k, Dv), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, D), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_q, Dv), lambda i, j: (i, 0)),
             pl.BlockSpec((block_q, _LANES), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((Tq, Dv), q.dtype),
             jax.ShapeDtypeStruct((Tq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -230,17 +230,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
 
 def _bwd_one_head(q, k, v, do, lse, dl, *, scale, causal, block_q, block_k,
                   k_len, interpret):
-    Tq, D = q.shape
-    Tk = k.shape[0]
+    Tq, D = q.shape          # the score width (q, k, dq, dk)
+    Tk, Dv = v.shape         # the value width (v, dO, dv)
     nq, nk = pl.cdiv(Tq, block_q), pl.cdiv(Tk, block_k)
     q_spec = pl.BlockSpec((block_q, D), lambda i, j: (i, 0))
     k_spec = pl.BlockSpec((block_k, D), lambda i, j: (j, 0))
+    v_spec = pl.BlockSpec((block_k, Dv), lambda i, j: (j, 0))
+    do_spec = pl.BlockSpec((block_q, Dv), lambda i, j: (i, 0))
     r_spec = pl.BlockSpec((block_q, _LANES), lambda i, j: (i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=k_len),
         grid=(nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, r_spec, r_spec],
         out_specs=pl.BlockSpec((block_q, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -250,18 +252,20 @@ def _bwd_one_head(q, k, v, do, lse, dl, *, scale, causal, block_q, block_k,
     # kv-outer grid: index maps see (kj, qi)
     qk_spec = pl.BlockSpec((block_q, D), lambda j, i: (i, 0))
     kk_spec = pl.BlockSpec((block_k, D), lambda j, i: (j, 0))
+    vk_spec = pl.BlockSpec((block_k, Dv), lambda j, i: (j, 0))
+    dok_spec = pl.BlockSpec((block_q, Dv), lambda j, i: (i, 0))
     rk_spec = pl.BlockSpec((block_q, _LANES), lambda j, i: (i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=k_len),
         grid=(nk, nq),
-        in_specs=[qk_spec, kk_spec, kk_spec, qk_spec, rk_spec, rk_spec],
+        in_specs=[qk_spec, kk_spec, vk_spec, dok_spec, rk_spec, rk_spec],
         out_specs=[pl.BlockSpec((block_k, D), lambda j, i: (j, 0)),
-                   pl.BlockSpec((block_k, D), lambda j, i: (j, 0))],
+                   pl.BlockSpec((block_k, Dv), lambda j, i: (j, 0))],
         out_shape=[jax.ShapeDtypeStruct((Tk, D), k.dtype),
-                   jax.ShapeDtypeStruct((Tk, D), v.dtype)],
+                   jax.ShapeDtypeStruct((Tk, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse, dl)
@@ -271,12 +275,17 @@ def _bwd_one_head(q, k, v, do, lse, dl, *, scale, causal, block_q, block_k,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                     block_k=128):
-    """Fused attention ``[B, T, H, D] -> [B, T, H, D]``.
+    """Fused attention: q, k ``[B, T, H, Dqk]``, v ``[B, T, H, Dv]`` ->
+    ``[B, T, H, Dv]``.
 
     Forward and backward are Pallas kernels (per ``(batch, head)`` via a
     double vmap -- each kernel grid covers query x kv tiles). Ragged
-    sequence lengths are padded here and masked in-kernel; D should be a
-    multiple of 128 for MXU alignment (typical head dims 128/256).
+    sequence lengths are padded here and masked in-kernel. The score width
+    ``Dqk`` may differ from the value width ``Dv`` (latent attention:
+    keys wider than values). On hardware ``Dv`` must fill 128-wide tiles;
+    a ``Dqk`` that does not is zero-padded to the next multiple of 128
+    here (exact: the extra columns add 0 to every score), and ``scale``
+    defaults to ``Dqk ** -0.5`` of the width given, never the padded one.
     """
     return _fa_fwd(q, k, v, causal, scale, block_q, block_k)[0]
 
@@ -302,16 +311,27 @@ def _double_vmap(fn):
 
 
 def _require_hw_head_dim(D, interpret):
-    """On real TPU hardware the kernel's lane layout requires the head dim
-    to fill 128-wide tiles; interpret mode (CPU tests) takes any D. Fail
-    loudly up front instead of leaving a Mosaic layout error to decipher
-    (ADVICE r3)."""
+    """On real TPU hardware the kernel's lane layout requires the value
+    head dim to fill 128-wide tiles; interpret mode (CPU tests) takes any
+    D. Fail loudly up front instead of leaving a Mosaic layout error to
+    decipher (ADVICE r3)."""
     if not interpret and D % 128:
         raise ValueError(
             f"flash_attention on TPU hardware requires head_dim D to be a "
             f"multiple of 128 (got D={D}); use "
             "fedml_tpu.ops.attention.blockwise_attention for small head "
             "dims (same flash semantics, XLA-scheduled)")
+
+
+def _score_pad(Dqk, Dv, interpret):
+    """Zero columns to add to q and k: none where the score width is the
+    value width (which ``_require_hw_head_dim`` has checked) or in
+    interpret mode, else up to the next 128-wide tile (192 -> 256)."""
+    return 0 if interpret or Dqk == Dv else (-Dqk) % _LANES
+
+
+def _pad_d(x, pad):
+    return jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) if pad else x
 
 
 def _block_sizes(block_q, block_k, Tq, Tk):
@@ -326,13 +346,14 @@ def _block_sizes(block_q, block_k, Tq, Tk):
 
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     scale_ = scale if scale is not None else D ** -0.5
     interpret = _use_interpret()
-    _require_hw_head_dim(D, interpret)
+    _require_hw_head_dim(Dv, interpret)
+    pad_d = _score_pad(D, Dv, interpret)
     bq, bk = _block_sizes(block_q, block_k, Tq, Tk)
-    qp = _swap_th(_pad_t(q, (-Tq) % bq))
-    kp = _swap_th(_pad_t(k, (-Tk) % bk))
+    qp = _swap_th(_pad_d(_pad_t(q, (-Tq) % bq), pad_d))
+    kp = _swap_th(_pad_d(_pad_t(k, (-Tk) % bk), pad_d))
     vp = _swap_th(_pad_t(v, (-Tk) % bk))
     fn = functools.partial(_fwd_one_head, scale=scale_, causal=causal,
                            block_q=bq, block_k=bk, k_len=Tk,
@@ -349,15 +370,17 @@ def _fa_bwd(causal, scale, block_q, block_k, res, g):
     Tk = k.shape[1]
     scale_ = scale if scale is not None else D ** -0.5
     interpret = _use_interpret()
+    pad_d = _score_pad(D, v.shape[-1], interpret)
     bq, bk = _block_sizes(block_q, block_k, Tq, Tk)
     pad_q, pad_k = (-Tq) % bq, (-Tk) % bk
     # delta_i = dO_i . O_i (the -sum_j ds_ij term of the softmax backward)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     rep = lambda x: jnp.broadcast_to(  # [B, T, H] -> lane-replicated
         x[..., None], x.shape + (_LANES,))
-    qp = _swap_th(_pad_t(q, pad_q))
+    qp = _swap_th(_pad_d(_pad_t(q, pad_q), pad_d))
     dop = _swap_th(_pad_t(g.astype(q.dtype), pad_q))
-    kp, vp = _swap_th(_pad_t(k, pad_k)), _swap_th(_pad_t(v, pad_k))
+    kp = _swap_th(_pad_d(_pad_t(k, pad_k), pad_d))
+    vp = _swap_th(_pad_t(v, pad_k))
     # padded q rows: dO rows are zero => ds rows are zero => no dk/dv
     # contribution; their dq rows are sliced off below
     lse_p = _swap_th(_pad_t(rep(lse), pad_q))
@@ -366,7 +389,8 @@ def _fa_bwd(causal, scale, block_q, block_k, res, g):
                            block_q=bq, block_k=bk, k_len=Tk,
                            interpret=interpret)
     dq, dk, dv = _double_vmap(fn)(qp, kp, vp, dop, lse_p, dl_p)
-    return (_swap_th(dq)[:, :Tq], _swap_th(dk)[:, :Tk],
+    # the padded score columns' gradients are sliced off with the padded rows
+    return (_swap_th(dq)[:, :Tq, :, :D], _swap_th(dk)[:, :Tk, :, :D],
             _swap_th(dv)[:, :Tk])
 
 

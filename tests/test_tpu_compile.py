@@ -1,0 +1,103 @@
+"""The main path's Pallas kernels compiled at their real widths for a
+TPU v5e that is described and not attached: what interpret mode cannot
+show (tilings, lane layouts, fast-memory limits). Nothing runs, so these
+say nothing of results or times; a compile that passes is not a chip run.
+
+All such compiles live in this one file: the topology is described
+inside a fixture, by the one worker that is given the file."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from fedml_tpu.ops import grouped_matmul as gm
+from fedml_tpu.ops import pallas_attention as pa
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def hardware_path(monkeypatch):
+    """The kernels ask the default backend whether to interpret; here it
+    is the CPU's, so the test steers them onto the compiled path."""
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+
+
+def _custom_calls(compiled):
+    return re.findall(r"%(\S+) = (\S+)[^\n]*custom_call_target="
+                      r"\"tpu_custom_call\"", compiled.as_text())
+
+
+@pytest.mark.parametrize("heads,dqk,dv", [(16, 128, 128), (32, 192, 128)],
+                         ids=["gpt2_128", "latent_192_128"])
+def test_flash_kernels_compile_at_real_widths(one_chip, hardware_path,
+                                              heads, dqk, dv):
+    b, t = 2, 2048
+    shape = lambda d: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
+                                           sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pa.flash_attention(q, k, v, True, dqk ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(dqk), shape(dqk), shape(dv)).compile()
+    calls = _custom_calls(compiled)
+    names = " ".join(n for n, _ in calls)
+    assert "flash_fwd" in names and "flash_bwd_dq" in names \
+        and "flash_bwd_dkv" in names
+    # scores of 192 reach the kernel as 256 columns, values stay 128
+    width = 256 if dqk == 192 else dqk
+    dq = next(sig for n, sig in calls if "flash_bwd_dq" in n)
+    assert f"bf16[{b},{heads},{t},{width}]" in dq
+
+
+def test_flash_refuses_a_value_width_the_hardware_cannot_run(hardware_path):
+    q = jnp.zeros((1, 128, 2, 192), jnp.bfloat16)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        pa.flash_attention(q, q, jnp.zeros((1, 128, 2, 64), jnp.bfloat16))
+
+
+def test_grouped_products_compile_under_the_lane_vmap(one_chip,
+                                                      hardware_path):
+    """One expert layer's three products and their six backward products
+    at ``kanana-2-30b-a3b-ep8``'s shapes (a row for each of 4,096 x 6
+    assignments, 16 experts of 2048 x 768), under ``jax.vmap`` over one
+    lane as the client-update program has them; the device events'
+    names are what the benchmark's metric files match."""
+    m, d, width, held = 24576, 2048, 768, 16
+    s = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (1,) + shape, dtype, sharding=one_chip)
+
+    def loss(x, wg, wu, wd, sizes):
+        h = jax.nn.silu(gm.grouped_matmul(x, wg, sizes)) \
+            * gm.grouped_matmul(x, wu, sizes)
+        y = gm.grouped_matmul(h, wd, sizes).astype(jnp.float32)
+        return jnp.sum(y * y)   # so that the last product's value is needed
+
+    compiled = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 1, 2, 3)))).lower(
+        s((m, d)), s((held, d, width)), s((held, d, width)),
+        s((held, width, d)), s((held,), jnp.int32)).compile()
+    names = [n for n, _ in _custom_calls(compiled)]
+    count = lambda part: sum(part in n for n in names)
+    assert count("moe_gmm_fwd") == 3
+    assert count("moe_gmm_dlhs") == 3 and count("moe_gmm_drhs") == 3
