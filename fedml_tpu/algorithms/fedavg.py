@@ -429,10 +429,10 @@ class FedAvgAPI:
         # dispatched asynchronously, "local-train" measures dispatch and
         # the device time lands in "aggregate" -- the end-of-round sync is
         # where the host waits for the round's outputs (the FL114 lesson).
-        # The bucketed stream is the other case: its host fold touches
-        # every chunk's outputs inside run_round, so "local-train" holds
-        # the device wait and the fold (its fold.* children say which)
-        # and "aggregate" is about 0
+        # The bucketed stream is the other case: run_round fetches every
+        # chunk's weight and returns when the new state is ready, so
+        # "local-train" holds the device wait and the fold (its fold.*
+        # children say which) and "aggregate" is about 0
         tracer = get_tracer()
         mon = get_perf_monitor()  # one global read when monitoring is off
         t0 = time.time()
@@ -473,8 +473,10 @@ class FedAvgAPI:
                     residual_store=(self._ef_store
                                     if self.compressor is not None
                                     else None))
-                # the stream's metric sums are on the host already
-                sp.set(**routing_counters(info["metrics"]))
+                # the stream's metric sums are on the host already;
+                # "fold" says where the payload sums were combined
+                sp.set(fold=info["fold"],
+                       **routing_counters(info["metrics"]))
             self._last_bucket_info = info
             self._last_cohort_size = len(client_indexes)
         elif self.device_data is not None:

@@ -48,7 +48,8 @@ from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.costmodel import get_cost_model, program_cost
 from fedml_tpu.observability.tracing import get_tracer
 from fedml_tpu.parallel.mesh import CLIENT_AXIS, zero_pad_leading
-from fedml_tpu.program.aggregation import Float64Accumulator
+from fedml_tpu.program.aggregation import (split_total, two_word_add,
+                                           two_word_quotient)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -352,11 +353,31 @@ class BucketedStreamRunner:
     - **Streaming bounds memory.** Each dispatch stages one chunk's
       batches host->device (``packing.gather_batches``) and returns only
       the chunk's weighted payload SUM -- O(client_chunk) data and O(1)
-      model state on device, regardless of cohort size. The per-chunk
-      partials fold on host in float64 (the
-      ``program.aggregation.fold_entries_fp64`` canonical fold, into
-      one standing ``Float64Accumulator``, in place) and one jitted
-      ``advance_fn`` applies the server update.
+      model state on device, regardless of cohort size.
+    - **The synchronous fold lives on the device.** The chunks' payload
+      sums are combined where they are made, in a two-word float32
+      accumulator (``program.aggregation.two_word_add``: a sum and its
+      running error, every add error-free; no ``x64``): chunk 0's sum
+      becomes the high word, the first add makes the low one in the
+      second payload's buffer, every later chunk is one small program
+      dispatched right after its chunk's, in ordinal order -- the
+      canonical fold's order. ``two_word_quotient`` divides by the total
+      weight to two-word precision, rounds ONCE to float32, casts through
+      the payload dtype template and hands the average to the jitted
+      ``advance_fn``: no payload sum crosses to the host, no average
+      crosses back, and the host fetches a chunk's weight and metric sums
+      alone. **The contract:** every element of the new state is within
+      one float32 ulp of ``fold_entries_fp64`` over the same entries,
+      whatever the number of chunks, and equal to it on all but 1e-5 of
+      the elements (none in a million differ on the CPU; on a TPU
+      v5e, whose division is not correctly rounded, exact ties of the
+      one final rounding go to the other neighbour: one to four in a
+      million of a real round); a
+      non-finite sum comes out non-finite (NaN where float64 gives an
+      infinity). ``fold_entries_fp64`` stays the canonical fold of the
+      buffered path below and of the server paths, and this fold's
+      oracle (``tests/test_device_fold.py``). ``run_round`` returns when
+      the new state is ready.
     - **One compiled program per bucket shape**, pinned: ``trip`` is
       traced and every chunk of a bucket shares the edge-padded shape, so
       steady-state retraces are zero and ``compiled_shapes()`` equals the
@@ -367,8 +388,12 @@ class BucketedStreamRunner:
     ``async_window`` chunks stay in flight (the simulated client
     concurrency), every ``buffer_k`` folded clients flush a server update
     MID-ROUND, and chunks dispatched before a flush fold in staleness-
-    discounted. With an unbounded buffer and decay 0 this reduces to the
-    synchronous fold bit-for-bit (the CI oracle).
+    discounted. That path is the host's, whole: each payload sum is
+    copied out and folded through ``fold_entries_fp64`` (arrival order
+    unknown, staleness scales, flushes at any chunk). With an unbounded
+    buffer and decay 0 it IS ``fold_entries_fp64`` over the round's
+    chunks, byte for byte, and the synchronous fold equals it within the
+    one-ulp contract above (the CI oracle, both halves).
 
     Streaming-EF (``compressor=``): the chunk program additionally runs
     the client->server half of the wire per lane -- compress the local
@@ -458,14 +483,33 @@ class BucketedStreamRunner:
         def advance_fn(global_state, server_state, avg_payload, rng):
             return server_fn_(global_state, avg_payload, server_state, rng)
 
+        # the synchronous fold's programs, apart from chunk_fn (whose
+        # device time is read by its name as the client update's alone).
+        # What a program can write its outputs into is donated: the low
+        # word takes the second payload's buffer, the average the high
+        # word's. A later payload, and the low word at the quotient, have
+        # no output to become, so they are not donated (that would only
+        # warn); run_round drops its one reference at the dispatch and
+        # the runtime frees the buffer when the program has read it
+        @partial(jax.jit, donate_argnums=(0, 1))
+        def fold_first(acc_hi, payload):
+            return two_word_add(acc_hi, None, payload)
+
+        @partial(jax.jit, donate_argnums=(0, 1))
+        def fold_next(acc_hi, acc_lo, payload):
+            return two_word_add(acc_hi, acc_lo, payload)
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def fold_quotient(acc_hi, acc_lo, total_hi, total_lo, dtypes):
+            return two_word_quotient(acc_hi, acc_lo, total_hi, total_lo,
+                                     dtypes)
+
         self._chunk_fn = chunk_fn
         self._advance_fn = advance_fn
+        self._fold_first = fold_first
+        self._fold_next = fold_next
+        self._fold_quotient = fold_quotient
         self._dtypes = None
-        # the synchronous fold's float64 numerator, kept across rounds:
-        # from this runner's second round on, the first chunk's payload
-        # is converted into standing memory (host-only; 8 bytes a
-        # payload element held between rounds)
-        self._sync_acc = Float64Accumulator()
         # per-bucket-edge ProgramCost (or None for "probed, no cost
         # analysis"), populated lazily ONLY while a CostModel is armed;
         # the AOT probe compiles once per edge (warm-up round) and never
@@ -491,9 +535,11 @@ class BucketedStreamRunner:
         shards, list of ``{"x", "y"}``), streamed bucket by bucket.
 
         ``aggregator`` (optional ``BufferedAggregator``) switches the
-        host-side fold to buffered-async; otherwise the partials fold
-        synchronously. With a ``compressor`` armed, ``residual_store``
-        (a ``compression.ResidualStore``) carries each client's EF
+        fold to the host's buffered-async one; otherwise the partials
+        fold synchronously, on the device (``info["fold"]`` says which:
+        ``"device"`` or ``"host"``), and ``async_window`` (the buffered
+        path's chunks in flight) is not read. With a ``compressor`` armed,
+        ``residual_store`` (a ``compression.ResidualStore``) carries each client's EF
         residual across the rounds it is sampled into, keyed by
         ``client_ids`` (stable ids aligned with ``datasets``; defaults
         to cohort ordinals for store-owning callers like the direct
@@ -547,14 +593,24 @@ class BucketedStreamRunner:
         cm = get_cost_model()  # one global read when attribution is off
         flushes = 0
         metrics_acc = None
-        # sync path: incremental canonical fold. Entries are consumed in
-        # ordinal (= sorted-key) order, so accumulating here is bitwise
-        # fold_entries_fp64 over the same entries -- with O(1 model) host
-        # memory, the float64 copies included: one standing accumulator
-        # takes every chunk's payload in place, and no payload is
-        # retained to round end
-        sync_acc = self._sync_acc
-        sync_w, sync_folds = 0.0, 0
+        # sync path: the two-word float32 sum of the chunks' payload sums,
+        # on the device (None until chunk 0's payload sum becomes the high
+        # word; the low word is made by the first add). Combined in
+        # ordinal (= sorted-key) order, each chunk right after its
+        # program, so the order is fold_entries_fp64's over the same
+        # entries; O(1 model) device memory and no payload on the host
+        on_device = aggregator is None
+        acc_hi = acc_lo = None
+        # chunks in flight before the oldest one's weight is fetched. The
+        # device fold keeps ONE: the runtime allocates a chunk's output
+        # at its dispatch and frees a folded payload only when its fold
+        # program has run, so every chunk the host runs ahead is one more
+        # payload sum alive (at 411 M parameters and six chunks: 11.58 GB
+        # at 4 or 2 in flight, 9.94 GB at 1, the round equally long: the
+        # next chunk is still fed while this one runs). The buffered
+        # path's window is its policy's (the simulated concurrency)
+        depth = 1 if on_device else max(1, int(async_window))
+        sync_w = 0.0
         inflight = deque()
         exec_steps = 0
         per_bucket = []
@@ -570,20 +626,31 @@ class BucketedStreamRunner:
                        arrays=len(leaves))
 
         def apply_avg(avg, f):
-            # avg: f32 numpy pytree from the canonical fold; cast through
-            # the payload dtype template (accumulators run f32/f64, the
-            # model may not) and run the donated server step
+            # avg: the fold's average. From the host fold an f32 numpy
+            # pytree, cast through the payload dtype template on its way
+            # to the device (accumulators run f32/f64, the model may
+            # not); from the device fold it is there, and cast, already.
+            # Then the donated server step
             nonlocal gs, ss
             with tracer.span("fold.apply") as sp:
-                avg_dev = jax.tree.map(
-                    lambda a, d: jnp.asarray(np.asarray(a), d.dtype), avg,
-                    dtypes)
-                note_bytes(sp, avg_dev)
-                gs, ss = self._advance_fn(gs, ss, avg_dev,
+                if on_device:
+                    sp.set(bytes=0, arrays=0)  # nothing leaves the host
+                else:
+                    avg = jax.tree.map(
+                        lambda a, d: jnp.asarray(np.asarray(a), d.dtype),
+                        avg, dtypes)
+                    note_bytes(sp, avg)
+                gs, ss = self._advance_fn(gs, ss, avg,
                                           jax.random.fold_in(flush_rng, f))
+                if on_device:
+                    # the round's work ends inside the round: nothing
+                    # the host did before waited for the last programs
+                    # (a mid-round flush of the buffered path does not
+                    # wait: chunks are still in flight behind it)
+                    jax.block_until_ready((gs, ss))
 
         def fold_oldest():
-            nonlocal flushes, metrics_acc, sync_w, sync_folds
+            nonlocal flushes, metrics_acc, sync_w
             ordinal, born, k_real, handles, scatter = inflight.popleft()
             if scatter is not None:
                 # EF residual write-back, deferred to the fold point (the
@@ -595,9 +662,12 @@ class BucketedStreamRunner:
                 residual_store.scatter(
                     ids, jax.tree.map(lambda x: x[:len(ids)], new_res))
             # FIRST host touch of this chunk's outputs: the device sync
-            # point. Everything stays a device handle until here, so up
-            # to async_window chunks genuinely overlap host packing/H2D
-            # staging with device compute.
+            # point. Everything stays a device handle until here, so the
+            # chunks in flight (``depth``) genuinely overlap host
+            # packing/H2D staging with device compute. The device fold
+            # fetches the weight and the metric sums alone (handles[0] is
+            # None: the payload sum went into the accumulator at the
+            # dispatch)
             if tracer.enabled:
                 # the wait apart from the copy. Every output of a chunk
                 # comes from one program, so waiting for the weight is
@@ -613,22 +683,11 @@ class BucketedStreamRunner:
                 metrics_acc = m_host if metrics_acc is None else \
                     jax.tree.map(np.add, metrics_acc, m_host)
                 note_bytes(sp, handles)
-            staleness = (aggregator.version - born) if aggregator else 0
-            if aggregator is None:
-                if sync_folds == 0:
-                    # the round's one conversion: the first chunk's
-                    # payload written into the accumulator's own arrays
-                    with tracer.span("fold.convert") as sp:
-                        reused = sync_acc.start(pay)
-                        sp.set(bytes=sync_acc.nbytes,
-                               arrays=sync_acc.arrays, reused=int(reused))
-                else:
-                    with tracer.span("fold.add"):
-                        sync_acc.add(pay)
+            if on_device:
                 sync_w += w
-                sync_folds += 1
                 return
-            with tracer.span("fold.add"):  # parent of buffer-fold
+            staleness = aggregator.version - born
+            with tracer.span("fold.add", on="host"):  # parent of buffer-fold
                 aggregator.fold(ordinal, w, pay, staleness=staleness,
                                 clients=k_real, preweighted=True)
             if aggregator.ready():
@@ -702,6 +761,17 @@ class BucketedStreamRunner:
             else:
                 pay_sum, w_sum, msum, new_res = out
                 scatter = (ids, new_res)
+            del out
+            if on_device:
+                if acc_hi is None:
+                    acc_hi = pay_sum  # chunk 0's sum IS the high word
+                else:
+                    with tracer.span("fold.add", on="device"):
+                        acc_hi, acc_lo = (
+                            self._fold_first(acc_hi, pay_sum)
+                            if acc_lo is None else
+                            self._fold_next(acc_hi, acc_lo, pay_sum))
+                pay_sum = None  # the accumulator's, or the runtime's to free
             if cm is not None:
                 if edge not in self._edge_costs:
                     # abstract AOT probe of this bucket shape's program
@@ -731,7 +801,7 @@ class BucketedStreamRunner:
             st["executed_steps"] += trip * self.client_chunk
             st["true_steps"] += int(steps_pc[chunk].sum())
             exec_steps += trip * self.client_chunk
-            while len(inflight) > max(1, int(async_window)):
+            while len(inflight) > depth:
                 fold_oldest()
         flops_exec, flops_true, have_cost = 0.0, 0.0, False
         for e in self.edges:
@@ -766,14 +836,15 @@ class BucketedStreamRunner:
             async_info = aggregator.record()
             async_info["async/flushes_this_round"] = flushes
         else:
-            if sync_folds == 0 or sync_w <= 0:
+            # before the quotient and the server step, which donate the
+            # sum and the state
+            if acc_hi is None or sync_w <= 0:
                 raise ValueError("bucketed round folded zero weight "
                                  "(every cohort shard empty?)")
             with tracer.span("fold.finalize"):
-                # a fresh float32 tree every round: apply_avg hands it
-                # to the device, which may alias it (CPU) or still be
-                # reading it (TPU) when the next round folds
-                avg = sync_acc.finish(sync_w)
+                avg = self._fold_quotient(acc_hi, acc_lo,
+                                          *split_total(sync_w), dtypes)
+            acc_hi = acc_lo = None
             apply_avg(avg, 0)
             flushes = 1
             async_info = None
@@ -783,6 +854,7 @@ class BucketedStreamRunner:
             "aux": {"n": np.asarray(ns, np.float32),
                     "steps": steps_pc.astype(np.int64)},
             "metrics": metrics_acc,
+            "fold": "device" if on_device else "host",
             "bucket": {
                 "edges": list(self.edges),
                 "buckets_used": sum(1 for b in per_bucket

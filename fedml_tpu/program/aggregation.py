@@ -209,6 +209,134 @@ class Float64Accumulator:
             for a in self._acc])
 
 
+# ---------------------------------------------------------------------------
+# The two-word float32 fold: the same numerator where float64 is not to be
+# had (a TPU, ``jax_enable_x64`` off). A sum is held as two float32 words,
+# ``hi + lo`` with ``|lo| <= ulp(hi) / 2``; every step below is either
+# error-free or rounds at the second word's scale (2^-48 of the sum), so
+# the error does not grow with the number of addends as a one-word float32
+# sum's does. Traced under ``jax.jit`` by ``BucketedStreamRunner`` (jax is
+# imported where a function needs it, so the module stays host-importable
+# without it).
+# ---------------------------------------------------------------------------
+def _two_sum(a, b):
+    """``a + b`` as ``(rounded sum, its rounding error)``, exactly."""
+    t = a + b
+    bb = t - a
+    return t, (a - (t - bb)) + (b - bb)
+
+
+def _split12(a):
+    """A float32 as two floats of at most 12 significant bits each (the
+    low 12 bits of the significand masked off, and what they held), so
+    that a product of two halves is exact in float32."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    hi = lax.bitcast_convert_type(
+        lax.bitcast_convert_type(a, jnp.uint32) & jnp.uint32(0xFFFFF000),
+        jnp.float32)
+    return hi, a - hi
+
+
+def _by_leaf(acc_hi, acc_lo, other):
+    """``(treedef, [(hi, lo, other)])`` leaf by leaf; ``lo`` is None in
+    every triple while the sum is one word (``acc_lo`` None)."""
+    import jax
+
+    flat_hi, treedef = jax.tree.flatten(acc_hi)
+    flat_lo = ([None] * len(flat_hi) if acc_lo is None
+               else treedef.flatten_up_to(acc_lo))
+    return treedef, list(zip(flat_hi, flat_lo,
+                             treedef.flatten_up_to(other)))
+
+
+def two_word_add(acc_hi, acc_lo, payload):
+    """``(hi, lo) + payload`` leaf by leaf, as ``(hi, lo)`` again.
+
+    All three are pytrees of float32 leaves of one structure; ``acc_lo``
+    is None for a sum that is still one word (then it is the first add
+    that makes the second). The addend's rounding error is caught whole
+    (TwoSum, six additions), joins the low word, and the pair is
+    renormalised, so the low word stays under half an ulp of the high
+    one and what is lost a step is of the order of 2^-48 of the sum.
+    """
+    def leaf(hi, lo, p):
+        t, err = _two_sum(hi, p)
+        if lo is not None:
+            err = lo + err
+        new_hi = t + err
+        return new_hi, err - (new_hi - t)
+
+    treedef, triples = _by_leaf(acc_hi, acc_lo, payload)
+    pairs = [leaf(*x) for x in triples]
+    return (treedef.unflatten([h for h, _ in pairs]),
+            treedef.unflatten([l for _, l in pairs]))
+
+
+def two_word_quotient(acc_hi, acc_lo, total_hi, total_lo, dtypes):
+    """``(hi + lo) / (total_hi + total_lo)`` rounded ONCE to float32,
+    then cast leaf by leaf to the dtypes of the template ``dtypes``.
+
+    The divisor is the float64 total weight as two float32 scalars
+    (``float32(total)`` and ``float32(total - that)``). A float32
+    division alone is good to an ulp; here the quotient of the high
+    words is corrected by its exact remainder -- the product
+    ``q * total_hi`` taken without error from halves of 12 bits (Dekker),
+    the low words brought in -- divided once more. With IEEE float32
+    arithmetic what is rounded at the end is within about 2^-46 of the
+    true quotient and exact where the correction is representable, so
+    the result is float64's: 0 of a million elements differed on the
+    CPU, exact ties (which go to even) included. A TPU v5e divides by
+    multiplying with a rounded reciprocal, so its correction can be an
+    ulp off where the exact one is representable; that tips an EXACT tie
+    of the final rounding to the other neighbour. Real payloads (sums of
+    small-integer multiples of float32 values) hit exact ties a few
+    times in a million: 1.2e-6 to 3.6e-6 of the elements of a round read
+    one ulp off there (the 479 of one reading were brought back: every
+    one such a tie), from accumulator words that were bit for bit IEEE's
+    (PERF.md section 6, PR 28). The
+    contract is one ulp everywhere and equality on all but 1e-5 of the
+    elements. ``acc_lo`` may be
+    None (a one-word sum).
+    """
+    t_h, t_l = _split12(total_hi)
+
+    def leaf(hi, lo, d):
+        if lo is not None:
+            hi, lo = _two_sum(hi, lo)
+        q = hi / total_hi
+        p = q * total_hi
+        q_h, q_l = _split12(q)
+        p_err = ((q_h * t_h - p) + q_h * t_l + q_l * t_h) + q_l * t_l
+        rem = (hi - p) - p_err
+        if lo is not None:
+            rem = rem + lo
+        rem = rem - q * total_lo
+        return (q + rem / total_hi).astype(d.dtype)
+
+    treedef, triples = _by_leaf(acc_hi, acc_lo, dtypes)
+    return treedef.unflatten([leaf(*x) for x in triples])
+
+
+def split_total(total):
+    """The float64 total weight as the two float32 scalars
+    :func:`two_word_quotient` divides by."""
+    hi = np.float32(total)
+    return hi, np.float32(float(total) - float(hi))
+
+
+def float32_ulps(a, b):
+    """How many floats apart two float32 arrays are, element by element
+    (int64; ``-0.0`` and ``0.0`` are one float). The unit the two-word
+    fold's contract is stated in; host-only."""
+    def ordered(x):
+        i = np.ascontiguousarray(x, np.float32).view(np.int32) \
+            .astype(np.int64)
+        return np.where(i < 0, -(i & 0x7FFFFFFF), i)
+    return np.abs(ordered(a) - ordered(b))
+
+
 def fold_entries_fp64(entries) -> tuple:
     """THE canonical weighted fold: sorted-key, float64, normalize-late.
 

@@ -447,8 +447,10 @@ EOF
 echo "== massive-cohort smoke (bucketed ragged streaming + buffered async"
 echo "   aggregation): one chip runs 2 rounds of 50,000 ragged simulated"
 echo "   clients (honest per-client n_i weighting); the async path under"
-echo "   the oracle settings (unbounded buffer, staleness decay 0) must"
-echo "   equal the synchronous fp64 fold BITWISE; the retrace audit must"
+echo "   the oracle settings (unbounded buffer, staleness decay 0) is the"
+echo "   canonical fp64 fold, and the synchronous stream's device fold"
+echo "   (two float32 words) must equal it within ONE float32 ulp after a"
+echo "   round from the same state; the retrace audit must"
 echo "   report zero steady-state retraces and the compiled chunk-program"
 echo "   count must equal the number of bucket shapes; async round records"
 echo "   must carry the buffer-depth/staleness series. fedlint must stay"
@@ -492,8 +494,8 @@ report = {}
 with audit(metrics_logger=report.update):
     api = build(0)
     api.train_one_round()
+    sync_params = jax.tree.map(np.asarray, api.global_state)
     m = api.train_one_round()
-sync_params = jax.tree.map(np.asarray, api.global_state)
 assert report["audit/rounds"] == 2, report
 assert report["audit/steady_state_retraces"] == 0, (
     "bucketed streaming retraced after round 1", report)
@@ -503,15 +505,17 @@ assert shapes == m["bucket/shapes"] > 0, (shapes, m)
 
 api2 = build(1)
 a1 = api2.train_one_round()
-a2 = api2.train_one_round()
+# one round from the same state: the same chunk payload sums through the
+# host's float64 fold and through the device's two-word float32 fold
 async_params = jax.tree.map(np.asarray, api2.global_state)
 for s, a in zip(jax.tree.leaves(sync_params), jax.tree.leaves(async_params)):
-    assert (s == a).all(), "async oracle != sync fold (bitwise)"
+    np.testing.assert_array_max_ulp(s, a, maxulp=1)
+a2 = api2.train_one_round()
 for rec in (a1, a2):  # buffer-depth/staleness series on async records
     assert "async/depth_peak" in rec and "async/max_staleness" in rec, rec
 print("massive-cohort smoke:", C, "clients/round, bucket shapes =", shapes,
       "waste_frac =", m["bucket/waste_frac"],
-      "| async bitwise oracle OK | retrace audit clean")
+      "| device fold within 1 ulp of the fp64 oracle | retrace audit clean")
 EOF
 
 echo "== massive-cohort bench record (clients/sec JSON line, XLA"

@@ -13,7 +13,16 @@
 - one round under the profiler and a ``Tracer``, as ``harness.run`` makes
   it with ``--trace 1``: every span of the round against the host-plane
   event of its name (the offset reckoned as the harness reckons it for
-  its gap labels), and ``local-train``'s self time.
+  its gap labels), and ``local-train``'s self time;
+- the fold's contract on this device (``--fold_contract 1``, last, since
+  the host fold raises the process's peak memory): one real round of the
+  cell from its current state folded both ways -- the synchronous
+  stream's two-word float32 fold on the device against the canonical
+  float64 host fold of the same payload sums (the buffered path with an
+  unbounded buffer and decay 0) -- with the share of elements that
+  differ, the largest distance in float32 ulps, and the device's peak
+  memory before the host fold ran. A compiler that simplified the
+  error-free sums away would show here, not in a CPU test.
 
     python3 scripts/span_probe.py --workload <cell> --seed <n>
 
@@ -129,6 +138,62 @@ def _profiled_round(api, trace_dir):
             "spans": _by_name(spans)}
 
 
+def _peak_bytes():
+    import jax
+
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in jax.devices())
+
+
+def _fold_both_ways(api, seed):
+    """One round of ``api``'s cohort from a copy of its current state,
+    through ``run_round`` twice: the device fold, then the host fold."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.program import AggregationPolicy
+    from fedml_tpu.program.aggregation import float32_ulps
+
+    # the canonical host fold, built as the program builds it: an
+    # unbounded buffer with decay 0 flushes once, through the float64 fold
+    oracle = api.program.replace(aggregation=AggregationPolicy(
+        buffer_k=10 ** 9, staleness_decay=0.0)).host_view()
+    ids = api._sample_cohort(api.round_idx)
+    datasets = [api.train_data_local_dict[i] for i in ids]
+    out = {"peak_bytes_device_fold": _peak_bytes()}
+    states = {}
+    for way in ("device", "host"):
+        agg = None if way == "device" else oracle.make_aggregator()
+        a = time.perf_counter()
+        gs, _, info = api.bucket_runner.run_round(
+            jax.tree.map(jnp.copy, api.global_state),
+            jax.tree.map(jnp.copy, api.server_state), datasets,
+            jax.random.PRNGKey(seed % (2 ** 31)),
+            data_rng=np.random.default_rng(seed), aggregator=agg,
+            client_ids=ids)
+        jax.block_until_ready(gs)
+        out[way + "_round_s"] = time.perf_counter() - a
+        if info["fold"] != way:
+            raise RuntimeError(f"asked for the {way} fold, the runner "
+                               f"said {info['fold']}")
+        states[way] = jax.device_get(jax.tree.leaves(gs))
+        del gs
+    out["peak_bytes_after_host_fold"] = _peak_bytes()
+    elements = differing = worst = 0
+    for d, h in zip(states["device"], states["host"]):
+        if d.dtype != np.float32 or not np.isfinite(h).all():
+            raise RuntimeError("the probe compares finite float32 states")
+        dist = float32_ulps(d, h)
+        elements += dist.size
+        differing += int((dist > 0).sum())
+        worst = max(worst, int(dist.max(initial=0)))
+    out.update(elements=elements, differing=differing,
+               differing_share=differing / max(elements, 1),
+               max_ulps=worst, chunks=info["bucket"]["chunks"])
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workload", required=True)
@@ -137,6 +202,7 @@ def main(argv=None):
     ap.add_argument("--rounds", type=int, default=1,
                     help="rounds to a window (a 45 s window's count)")
     ap.add_argument("--extra", type=int, default=0)
+    ap.add_argument("--fold_contract", type=int, default=0)
     ap.add_argument("--cpu_root", default=None)
     args = ap.parse_args(argv)
 
@@ -174,6 +240,8 @@ def main(argv=None):
     out["traced_extra"] = _window(cell.api, args.extra, tracer)
     with tempfile.TemporaryDirectory() as trace_dir:
         out["profiled"] = _profiled_round(cell.api, trace_dir)
+    if args.fold_contract:
+        out["fold_contract"] = _fold_both_ways(cell.api, args.seed)
     path = os.path.join(ROOT, "chiprun_out", "span_probe")
     os.makedirs(path, exist_ok=True)
     with open(os.path.join(path, args.workload + ".json"), "w") as f:

@@ -28,6 +28,7 @@ from fedml_tpu.algorithms.specs import make_classification_spec
 from fedml_tpu.parallel.engine import BucketedStreamRunner, ClientUpdateConfig
 from fedml_tpu.parallel.packing import (_steps_for, bucket_edge_for,
                                         pack_schedule, parse_bucket_edges)
+from fedml_tpu.program.aggregation import fold_entries_fp64
 from fedml_tpu.core.comm.base import MSG_TYPE_PEER_LOST
 from fedml_tpu.core.message import Message
 from fedml_tpu.resilience import (AsyncAggPolicy,
@@ -370,23 +371,54 @@ class TestBucketedStreamRunner:
         gs0 = spec.init_fn(jax.random.PRNGKey(1))
         return runner, datasets, gs0
 
-    def test_async_oracle_bitwise_vs_sync_stream(self):
-        """Unbounded buffer + decay 0 (one drain flush) == the
-        synchronous fp64 stream fold, bit for bit."""
+    @pytest.mark.parametrize("path", ["buffered", "sync_stream"])
+    def test_async_oracle_bitwise_vs_sync_stream(self, path):
+        """One round's chunk payload sums through the canonical fold
+        (``fold_entries_fp64`` over the ordinals, what the server paths
+        run) against the runner's two folds of the same sums: the
+        buffered path (unbounded buffer + decay 0: one drain flush)
+        byte for byte; the synchronous stream, which folds on the device
+        in two float32 words, within its one-ulp contract."""
         runner, datasets, gs0 = self._build()
         rng = jax.random.PRNGKey(7)
-        gs_s, _, _ = runner.run_round(
-            jax.tree.map(jnp.copy, gs0), (), datasets, rng,
-            data_rng=np.random.default_rng(3))
-        agg = BufferedAggregator(
+        # the oracle's entries: the chunk program's own outputs, taken
+        # by a buffer that never flushes through the runner's fold
+        entries = {}
+
+        class Recorder(BufferedAggregator):
+            def fold(self, key, weight, payload, **kw):
+                entries[key] = (key, float(weight), payload, 1.0)
+                return super().fold(key, weight, payload, **kw)
+
+        oracle = Recorder(
             AsyncAggPolicy(buffer_k=10 ** 9, staleness_decay=0.0))
-        gs_a, _, info = runner.run_round(
+        runner.run_round(
+            jax.tree.map(jnp.copy, gs0), (), datasets, rng,
+            data_rng=np.random.default_rng(3), aggregator=oracle)
+        want, _ = fold_entries_fp64(entries.values())
+        assert len(entries) == -(-len(datasets) // runner.client_chunk)
+
+        agg = None
+        if path == "buffered":
+            agg = BufferedAggregator(
+                AsyncAggPolicy(buffer_k=10 ** 9, staleness_decay=0.0))
+        gs, _, info = runner.run_round(
             jax.tree.map(jnp.copy, gs0), (), datasets, rng,
             data_rng=np.random.default_rng(3), aggregator=agg)
-        for a, b in zip(jax.tree.leaves(gs_s), jax.tree.leaves(gs_a)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert info["async"]["async/flushes"] == 1
-        assert info["async"]["async/max_staleness"] == 0
+        # FedAvg's server step hands the average on as the new state
+        got, want = jax.tree.leaves(gs), jax.tree.leaves(want)
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            if path == "buffered":
+                np.testing.assert_array_equal(np.asarray(a), b)
+            else:
+                np.testing.assert_array_max_ulp(np.asarray(a), b, maxulp=1)
+        if path == "buffered":
+            assert info["fold"] == "host"
+            assert info["async"]["async/flushes"] == 1
+            assert info["async"]["async/max_staleness"] == 0
+        else:
+            assert info["fold"] == "device" and "async" not in info
 
     def test_matches_flat_round_numerically(self):
         """Full-batch single-step cohort: the streamed result equals the

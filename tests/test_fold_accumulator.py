@@ -1,7 +1,8 @@
 """The in-place float64 fold (ISSUE 26): ``Float64Accumulator`` gives,
-byte for byte, what the plain out-of-place formula gives -- through
-``fold_entries_fp64`` and through the bucketed stream's standing
-accumulator -- and writes to no payload."""
+byte for byte, what the plain out-of-place formula gives through
+``fold_entries_fp64``, and writes to no payload. The bucketed stream's
+synchronous fold left it for the device (ISSUE 28): its runner-level
+tests are at the end."""
 
 import jax
 import jax.numpy as jnp
@@ -169,7 +170,10 @@ def test_accumulator_refuses_a_fold_that_was_not_started(call):
 
 
 # ---------------------------------------------------------------------------
-# the bucketed stream's standing accumulator
+# the bucketed stream's accumulator: on the device since ISSUE 28, made
+# anew every round (two float32 words; tests/test_device_fold.py holds
+# its arithmetic to the float64 fold), so a runner carries nothing of one
+# round's fold into the next
 # ---------------------------------------------------------------------------
 CLIENTS, CHUNK, BATCH = 11, 3, 4
 
@@ -194,20 +198,22 @@ def _runner(dim=6):
         edges=parse_bucket_edges("geometric", _steps_for(40, BATCH, 1)))
 
 
-def _round(runner, gs, r, dim=6):
+def _round(runner, gs, r, dim=6, aggregator=None):
     """Round ``r`` from ``gs`` under a real tracer; returns the new
-    state on the host and ``fold.convert``'s ``reused``."""
+    state on the host and where ``fold.add`` said the sums were added."""
     tracer = Tracer()
     prev = set_tracer(tracer)
     try:
-        gs, _, _ = runner.run_round(
+        gs, _, info = runner.run_round(
             jax.tree.map(jnp.copy, gs), (), _datasets(dim, seed=r),
-            jax.random.PRNGKey(r), data_rng=np.random.default_rng(r))
+            jax.random.PRNGKey(r), data_rng=np.random.default_rng(r),
+            aggregator=aggregator)
     finally:
         set_tracer(prev)
-    convert, = [s for s in tracer.finished_spans()
-                if s.name == "fold.convert"]
-    return jax.tree.map(np.asarray, gs), convert.attrs["reused"]
+    on = {s.attrs["on"] for s in tracer.finished_spans()
+          if s.name == "fold.add"}
+    assert on == {info["fold"]}
+    return jax.tree.map(np.asarray, gs), info["fold"]
 
 
 def _assert_states_equal(a, b):
@@ -217,29 +223,54 @@ def _assert_states_equal(a, b):
         assert x.dtype == y.dtype and x.tobytes() == y.tobytes()
 
 
+def _host_fold_round(gs, r, dim=6):
+    """The same round through the canonical host fold: an unbounded
+    buffer with decay 0 flushes once, through ``fold_entries_fp64``."""
+    from fedml_tpu.resilience.async_agg import (AsyncAggPolicy,
+                                                BufferedAggregator)
+
+    agg = BufferedAggregator(
+        AsyncAggPolicy(buffer_k=10 ** 9, staleness_decay=0.0))
+    got, fold = _round(_runner(dim), gs, r, dim, aggregator=agg)
+    assert fold == "host"
+    return got
+
+
+def _assert_within_an_ulp(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype == np.float32
+        np.testing.assert_array_max_ulp(g, w, maxulp=1)
+
+
 def test_two_rounds_of_one_runner_equal_a_fresh_runner_each():
     gs0 = _spec(6).init_fn(jax.random.PRNGKey(1))
     one = _runner()
-    gs1, reused1 = _round(one, gs0, 1)
-    gs2, reused2 = _round(one, gs1, 2)
-    assert (reused1, reused2) == (0, 1)
-    fresh1, r1 = _round(_runner(), gs0, 1)
-    fresh2, r2 = _round(_runner(), gs1, 2)
-    assert (r1, r2) == (0, 0)
+    gs1, fold1 = _round(one, gs0, 1)
+    gs2, fold2 = _round(one, gs1, 2)
+    assert (fold1, fold2) == ("device", "device")
+    assert not hasattr(one, "_sync_acc")  # nothing stands between rounds
+    fresh1, _ = _round(_runner(), gs0, 1)
+    fresh2, _ = _round(_runner(), gs1, 2)
     _assert_states_equal(gs1, fresh1)
     _assert_states_equal(gs2, fresh2)
+    # and the same round again from the same runner: the same bytes
+    again2, _ = _round(one, gs1, 2)
+    _assert_states_equal(gs2, again2)
     assert any((a != b).any() for a, b in zip(jax.tree.leaves(gs1),
                                               jax.tree.leaves(gs2)))
+    _assert_within_an_ulp(gs2, _host_fold_round(gs1, 2))
 
 
 def test_round_after_the_payload_changed_shape_reallocates_and_is_right():
     runner = _runner()
     _round(runner, _spec(6).init_fn(jax.random.PRNGKey(1)), 1)
-    held = runner._sync_acc.nbytes
     wide0 = _spec(9).init_fn(jax.random.PRNGKey(2))
-    got, reused = _round(runner, wide0, 2, dim=9)
-    assert reused == 0 and runner._sync_acc.nbytes > held
+    got, fold = _round(runner, wide0, 2, dim=9)
+    assert fold == "device"
+    assert [a.shape for a in jax.tree.leaves(got)] \
+        == [a.shape for a in jax.tree.leaves(wide0)]
     want, _ = _round(_runner(dim=9), wide0, 2, dim=9)
     _assert_states_equal(got, want)
-    _, reused = _round(runner, got, 3, dim=9)
-    assert reused == 1
+    _assert_within_an_ulp(got, _host_fold_round(wide0, 2, dim=9))
+    again, _ = _round(runner, got, 3, dim=9)
+    _assert_states_equal(again, _round(_runner(dim=9), got, 3, dim=9)[0])

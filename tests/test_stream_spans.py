@@ -17,11 +17,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CLIENTS, CHUNK = 10, 4
 CHUNKS = -(-CLIENTS // CHUNK)
 #: span -> how many a synchronous round of CHUNKS chunks holds: the first
-#: chunk's payload starts the float64 accumulator (``fold.convert``),
-#: every later chunk is added to it in place (``fold.add``)
+#: chunk's payload sum becomes the device accumulator's high word (no
+#: span: nothing is done), every later chunk's is added to it by a
+#: program of its own (``fold.add``, the dispatch)
 SYNC_SPANS = {"pack": CHUNKS, "h2d": CHUNKS, "bucket-chunk": CHUNKS,
               "fold.wait": CHUNKS, "fold.d2h": CHUNKS,
-              "fold.convert": 1, "fold.add": CHUNKS - 1,
+              "fold.add": CHUNKS - 1,
               "fold.finalize": 1, "fold.apply": 1}
 
 
@@ -88,6 +89,23 @@ def test_round_yields_the_stream_spans_under_local_train(traced):
     waits = sorted(s.attrs["ordinal"] for s in spans
                    if s.name == "fold.wait")
     assert waits == list(range(CHUNKS))
+    assert "fold.convert" not in {s.name for s in spans}
+
+
+def test_attributes_say_where_the_payload_sums_were_combined(traced):
+    _, _, spans = traced
+    by = _by_name(spans)
+    local, = by["local-train"]
+    assert local.attrs["fold"] == "device"
+    assert [s.attrs["on"] for s in by["fold.add"]] \
+        == ["device"] * (CHUNKS - 1)
+    # each add is dispatched right after its chunk's program, before the
+    # next chunk is packed: the combine order is the ordinal order
+    order = sorted(by["bucket-chunk"] + by["fold.add"] + by["pack"],
+                   key=lambda s: s.t0)
+    assert [s.name for s in order] == \
+        ["pack", "bucket-chunk"] \
+        + ["pack", "bucket-chunk", "fold.add"] * (CHUNKS - 1)
 
 
 def test_span_attributes_say_what_moved(traced):
@@ -96,7 +114,7 @@ def test_span_attributes_say_what_moved(traced):
     assert sum(s.attrs["clients"] for s in by["pack"]) == CLIENTS
     assert sum(s.attrs["rows"] for s in by["pack"]) \
         == sum(api.train_data_local_num_dict.values())
-    for name in ("h2d", "fold.d2h", "fold.convert", "fold.apply"):
+    for name in ("h2d", "fold.d2h"):
         assert all(s.attrs["bytes"] > 0 for s in by[name]), name
     assert not any("bytes" in s.attrs for s in by["fold.add"])
 
@@ -106,19 +124,14 @@ def test_byte_attributes_are_what_the_shapes_predict(traced):
 
     api, _, spans = traced
     by = _by_name(spans)
-    leaves = jax.tree.leaves(api.global_state)
-    payload = sum(a.nbytes for a in leaves)
+    metrics = jax.tree.leaves(api._last_metrics)
     extras = 4 + sum(np.asarray(v).astype(np.float32).nbytes
-                     for v in jax.tree.leaves(api._last_metrics))
-    assert [s.attrs["bytes"] for s in by["fold.d2h"]] \
-        == [payload + extras] * CHUNKS  # payload, weight and metrics
-    convert, = by["fold.convert"]  # the float64 accumulator, once a round
-    assert (convert.attrs["bytes"], convert.attrs["arrays"]) \
-        == (2 * payload, len(leaves))
-    assert convert.attrs["reused"] == 0  # a trainer's first round allocates
-    apply, = by["fold.apply"]
-    assert (apply.attrs["bytes"], apply.attrs["arrays"]) \
-        == (payload, len(leaves))
+                     for v in metrics)
+    # the weight and the metric sums: no payload sum crosses to the host
+    assert [(s.attrs["bytes"], s.attrs["arrays"])
+            for s in by["fold.d2h"]] == [(extras, 1 + len(metrics))] * CHUNKS
+    apply, = by["fold.apply"]  # and no average crosses back
+    assert (apply.attrs["bytes"], apply.attrs["arrays"]) == (0, 0)
     assert all(s.attrs["arrays"] == 5 for s in by["h2d"])
 
 
@@ -157,15 +170,25 @@ def test_noop_and_real_tracer_rounds_are_bitwise_equal(traced):
 
 
 def test_buffered_path_folds_under_fold_add():
+    import jax
+
     tracer = Tracer()
-    _round(tracer, async_agg=1, buffer_k=2, staleness_decay=0.5,
-           async_window=4)
+    api, _ = _round(tracer, async_agg=1, buffer_k=2, staleness_decay=0.5,
+                    async_window=4)
     spans = tracer.finished_spans()
     by_id = {s.span_id: s for s in spans}
     names = [s.name for s in spans]
     assert names.count("fold.add") == CHUNKS
-    assert "fold.convert" not in names and "fold.finalize" not in names
-    assert names.count("fold.apply") >= 1
+    assert all(s.attrs["on"] == "host" for s in spans
+               if s.name == "fold.add")
+    local, = [s for s in spans if s.name == "local-train"]
+    assert local.attrs["fold"] == "host"
+    assert "fold.finalize" not in names
+    applies = [s for s in spans if s.name == "fold.apply"]
+    assert applies and all(s.attrs["bytes"] > 0 for s in applies)
+    payload = sum(a.nbytes for a in jax.tree.leaves(api.global_state))
+    assert all(s.attrs["bytes"] > payload for s in spans
+               if s.name == "fold.d2h")  # the host fold takes the payload
     folds = [s for s in spans if s.name == "buffer-fold"]
     assert len(folds) == CHUNKS
     assert all(by_id[s.parent_id].name == "fold.add" for s in folds)

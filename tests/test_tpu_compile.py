@@ -101,3 +101,49 @@ def test_grouped_products_compile_under_the_lane_vmap(one_chip,
     count = lambda part: sum(part in n for n in names)
     assert count("moe_gmm_fwd") == 3
     assert count("moe_gmm_dlhs") == 3 and count("moe_gmm_drhs") == 3
+
+
+@pytest.mark.parametrize("program", ["fold_first", "fold_next",
+                                     "fold_quotient"])
+def test_fold_programs_keep_their_arithmetic_and_allocate_nothing(
+        one_chip, program):
+    """The bucketed stream's device fold at a GPT-2 embedding's size:
+    the v5e compiler keeps every operation of the error-free sums (an
+    algebraic simplifier that cancelled ``(a + b) - a`` would leave a
+    plain float32 sum), needs no scratch memory, and writes every output
+    over a donated input."""
+    from fedml_tpu import models
+    from fedml_tpu.algorithms.specs import make_classification_spec
+    from fedml_tpu.parallel.engine import (BucketedStreamRunner,
+                                           ClientUpdateConfig)
+
+    spec = make_classification_spec(
+        models.LogisticRegression(num_classes=4, apply_sigmoid=False),
+        jnp.zeros((1, 6)))
+    runner = BucketedStreamRunner(spec, ClientUpdateConfig(lr=0.1),
+                                  client_chunk=2, batch_size=4, edges=(8,))
+    word = lambda: {"wte": jax.ShapeDtypeStruct((50257, 2048), jnp.float32,
+                                                sharding=one_chip)}
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    args, adds, subtracts, outputs = {
+        "fold_first": ((word(), word()), 3, 6, 2),
+        "fold_next": ((word(), word(), word()), 4, 6, 2),
+        "fold_quotient": ((word(), word(), scalar, scalar,
+                           {"wte": scalar}), None, None, 1),
+    }[program]
+    compiled = getattr(runner, "_" + program).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    leaf = 50257 * 2048 * 4
+    assert mem.temp_size_in_bytes == 0
+    # (the chip pads a leaf to its tiles; the output's tuple is its own)
+    assert mem.alias_size_in_bytes >= outputs * leaf
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 4096
+    text = compiled.as_text()
+    count = lambda op: len(re.findall(rf"\b{op}\(", text))
+    if program == "fold_quotient":
+        # TwoSum of the words, the division and its correction's, the
+        # masks of the three splits, Dekker's products
+        assert count("divide") == 2 and count("and") >= 2
+        assert count("subtract") >= 9 and count("multiply") >= 6
+    else:
+        assert (count("add"), count("subtract")) == (adds, subtracts)
