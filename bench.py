@@ -207,7 +207,7 @@ def build_api(args, epochs, client_chunk, wave_mode):
         api = FedOptAPI(dataset, spec, run_args)
     else:
         api = FedAvgAPI(dataset, spec, run_args)
-    if api.device_data is None:
+    if api.runner.mode == "packed":
         raise RuntimeError("device-resident path required for the bench")
     return api
 
@@ -387,14 +387,14 @@ def run_massive_cohort(args):
         "warmup_compiles": watcher.total_compiles,
         "warmup_compile_s": round(watcher.total_compile_seconds, 2),
         "steady_compiles": steady_watcher.total_compiles,
-        "bucket_shapes": api.bucket_runner.compiled_shapes(),
+        "bucket_shapes": api.runner.compiled_shapes(),
         "bucket_waste_frac": metrics.get("bucket/waste_frac"),
         "executed_steps": metrics.get("bucket/executed_steps"),
         "true_steps": metrics.get("bucket/true_steps"),
         "train_loss": round(float(metrics["Train/Loss"]), 4),
         "device": str(jax.devices()[0]),
     }
-    binfo = api._last_bucket_info["bucket"]
+    binfo = api._last_info["bucket"]
     # per-bucket-shape attribution: step counts always, FLOPs when the
     # backend exposes cost analysis (flops_source tells which)
     out["per_bucket"] = [b for b in binfo["per_bucket"] if not b["skipped"]]
@@ -512,7 +512,7 @@ def run_lm_bench(args):
         set_cost_model(prev_cm)
     round_s = float(np.median(times))
     rph = 3600.0 / round_s
-    binfo = api._last_bucket_info["bucket"]
+    binfo = api._last_info["bucket"]
     tokens_round = binfo["true_steps"] * bs * T
     analytic = _lm_analytic_flops_per_token(d, L_layers, T, V)
     # MFU from the XLA cost model of the compiled bucket programs
@@ -553,7 +553,7 @@ def run_lm_bench(args):
         "warmup_cache_hits": warm_watch.cache_hits,
         "warmup_cache_misses": warm_watch.cache_misses,
         "steady_compiles": steady_watch.total_compiles,
-        "bucket_shapes": api.bucket_runner.compiled_shapes(),
+        "bucket_shapes": api.runner.compiled_shapes(),
         "bucket_waste_frac": metrics.get("bucket/waste_frac"),
         "train_loss": round(float(metrics["Train/Loss"]), 4),
         "n_params": sum(int(np.prod(x.shape)) for x in
@@ -1125,17 +1125,13 @@ def main():
     p.add_argument("--no_augment", action="store_true",
                    help="drop the recipe's crop/flip/Cutout augmentation")
     p.add_argument("--lane_lowering", default=None,
-                   choices=("auto", "blockdiag", "bgc", "pallas"),
+                   choices=("auto", "blockdiag", "bgc"),
                    help="mode-3 per-lane conv strategy "
                         "(models/lane_packed.py): blockdiag (default, "
                         "behind the committed 114.5 rph number); "
                         "bgc = zero-redundancy batch-group convs "
                         "everywhere; auto = bgc for Ci<=32 stages, "
-                        "block-diagonal for Ci=64; pallas = bgc forward "
-                        "with the Pallas grouped-conv dW kernel on the "
-                        "backward (ops/pallas_grouped_conv.py -- the "
-                        "measured lane-penalty cost center; the r8 "
-                        "watch-run A/B candidate)")
+                        "block-diagonal for Ci=64")
     p.add_argument("--device_dtype", type=str, default=None,
                    choices=("bf16", "bfloat16"),
                    help="halve the HBM residency of the data")

@@ -14,11 +14,10 @@ compile-cache directory (``JAX_COMPILATION_CACHE_DIR`` when set, else
   LDA 0.5 over 50,000 synthetic samples, batch 64, bf16, MXU-packed
   lanes) through ``fedml_tpu.experiments.main_fedavg``. Only local epochs
   (1) and rounds (4) are cut; every shape is the flagship's.
-- Leg B: both Pallas kernels compiled (flash attention fwd+bwd against
-  ``mha`` at T=512 and T=80; the grouped-conv dW kernel against XLA's dW
-  at ResNet-56's three stride-1 stage shapes) and two rounds of the
-  federated LM (``TransformerLM`` d512 / 4 heads of 128 / T=80 through
-  ``FedAvgAPI`` + ``BucketedStreamRunner``).
+- Leg B: the Pallas flash attention compiled (fwd+bwd against ``mha`` at
+  T=512 and T=80) and two rounds of the federated LM (``TransformerLM``
+  d512 / 4 heads of 128 / T=80 through ``FedAvgAPI`` +
+  ``BucketedStreamRunner``).
 - Leg C, when ``jax.device_count() >= 4``: Leg A's command with
   ``--mesh 4`` as given (``ShardedLaneRunner``) and with ``--wave_mode 1``
   (``make_sharded_round``); the cohort and the state must occupy all four
@@ -93,9 +92,6 @@ class Sizes:
     attn_batch: int = 2
     attn_heads: int = 4
     head_dim: int = 128
-    conv_lanes: int = 8
-    conv_batch: int = 64
-    conv_stages: tuple = ((32, 16), (16, 32), (8, 64))  # (H = W, C)
     # Leg B: federated LM (bench.py --lm's construction)
     lm_d_model: int = 512
     lm_layers: int = 4
@@ -248,8 +244,9 @@ def fedavg_leg(sz: Sizes, run_dir, *, mesh=0, wave_mode=3, rounds=None):
         # the cohort's data must be spread over the mesh, not parked on
         # device 0: the resident stack on the lane path, one round's
         # packed cohort on the wave_mode 1 path
-        cohort = (api.device_data if api.device_data is not None
-                  else api._cohort(0)[1])
+        runner = api.runner
+        cohort = (runner.device_data if runner.mode == "sharded-lanes"
+                  else runner.pack(api._sample_cohort(0)))
         sh = cohort["x"].sharding
         check(len(sh.device_set) == mesh and not sh.is_fully_replicated,
               f"cohort x sharding {sh} does not span {mesh} devices")
@@ -352,40 +349,6 @@ def check_flash_attention(sz: Sizes):
     return out
 
 
-def check_grouped_conv_dw(sz: Sizes):
-    """The Pallas per-lane dW kernel against XLA's dW (``jax.vjp`` of
-    ``lane_conv_bgc``) at each stride-1 stage shape, 3x3, bf16."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from fedml_tpu.models.lane_packed import lane_conv_bgc, lane_unmerge
-    from fedml_tpu.ops.pallas_grouped_conv import grouped_conv_dw
-
-    out = {}
-    L, B = sz.conv_lanes, sz.conv_batch
-    pad = ((1, 1), (1, 1))
-    for hw, c in sz.conv_stages:
-        ks = jax.random.split(jax.random.PRNGKey(hw), 3)
-        x = jax.random.normal(ks[0], (L * B, hw, hw, c), jnp.bfloat16)
-        w = jax.random.normal(ks[1], (L, 3, 3, c, c), jnp.bfloat16)
-        g = jax.random.normal(ks[2], (B, hw, hw, L * c), jnp.bfloat16)
-        _, vjp_w = jax.vjp(lambda ww: lane_conv_bgc(x, ww, L, padding=pad),
-                           w)
-        (ref,) = vjp_w(g)
-        got = grouped_conv_dw(x.reshape((L, B) + x.shape[1:]),
-                              lane_unmerge(g, L), 3, 3, pad)
-        check(got.shape == ref.shape, f"dW shape {got.shape}/{ref.shape}")
-        scale = float(np.max(np.abs(np.asarray(ref, np.float32))))
-        rel = _max_abs_diff(got, ref) / max(scale, 1e-30)
-        print(f"grouped_conv_dw {hw}x{hw}x{c}: rel_err={rel:.2e}",
-              flush=True)
-        # the XLA reference is rounded to bf16 (the weight dtype)
-        check(rel < 2e-2, f"grouped_conv_dw {hw}x{hw}x{c}: rel {rel}")
-        out[f"{hw}x{hw}x{c}"] = {"rel_err": rel}
-    return out
-
-
 def check_federated_lm(sz: Sizes):
     """Rounds of the federated LM as ``bench.py --lm`` builds it:
     ``TransformerLM`` with heads of ``head_dim``, ragged synthetic
@@ -423,7 +386,7 @@ def check_federated_lm(sz: Sizes):
         for _ in range(sz.lm_rounds):
             m = api.train_one_round()
             check(_finite(m["Train/Loss"]), f"LM round: {m}")
-            shapes = api.bucket_runner.compiled_shapes()
+            shapes = api.runner.compiled_shapes()
             check(shapes == m["bucket/shapes"],
                   f"compiled_shapes() {shapes} != buckets_used "
                   f"{m['bucket/shapes']}")
@@ -438,7 +401,6 @@ def check_federated_lm(sz: Sizes):
 
 def leg_b(sz: Sizes):
     return {"flash_attention": check_flash_attention(sz),
-            "grouped_conv_dw": check_grouped_conv_dw(sz),
             "federated_lm": check_federated_lm(sz)}
 
 

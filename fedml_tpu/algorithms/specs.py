@@ -113,10 +113,10 @@ def make_classification_spec(model, example_x, num_classes=None,
     # model-agnostic
     from fedml_tpu.models.lane_packed import builder_for
 
-    if lane_lowering not in (None, "blockdiag", "bgc", "auto", "pallas"):
+    if lane_lowering not in (None, "blockdiag", "bgc", "auto"):
         # fail at the API boundary, not hours later at lane setup
         raise ValueError(f"unknown lane_lowering {lane_lowering!r}; "
-                         "choose blockdiag, bgc, auto or pallas")
+                         "choose blockdiag, bgc or auto")
     return TrainSpec(init_fn=init_fn, loss_fn=loss_fn, metrics_fn=metrics_fn,
                      name=name, augment_fn=augment_fn,
                      lane_loss_builder=builder_for(

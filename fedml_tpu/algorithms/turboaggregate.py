@@ -20,6 +20,7 @@ import numpy as np
 from fedml_tpu.algorithms.fedavg import FedAvgAPI
 from fedml_tpu.core import mpc
 from fedml_tpu.parallel.engine import make_client_update
+from fedml_tpu.parallel.packing import pack_cohort
 
 
 class TurboAggregateAPI(FedAvgAPI):
@@ -38,7 +39,10 @@ class TurboAggregateAPI(FedAvgAPI):
 
     def train_one_round(self):
         t0 = time.time()
-        _, packed = self._cohort(self.round_idx)
+        packed = pack_cohort(
+            [self.train_data_local_dict[i]
+             for i in self._sample_cohort(self.round_idx)],
+            self.args.batch_size, self.args.epochs, rng=self._data_rng)
         self.rng, round_rng = jax.random.split(self.rng)
         C = packed["mask"].shape[0]
         rngs = jax.random.split(round_rng, C)

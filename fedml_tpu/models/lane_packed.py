@@ -121,18 +121,8 @@ def lane_conv(x, w, L, strides=(1, 1), padding=((1, 1), (1, 1)),
     and run the zero-redundancy ``batch_group_count`` conv
     (:func:`lane_conv_bgc`) -- measured faster at Ci<=32 where
     block-diag redundancy is 8x/4x (r5 shoot-out).
-
-    ``strategy="pallas"``: the bgc forward (bitwise-identical program)
-    with the backward dW -- the measured lane-penalty cost center --
-    computed by the Pallas grouped-conv dW kernel
-    (:mod:`fedml_tpu.ops.pallas_grouped_conv`); strided convs use XLA's
-    dW inside the custom vjp.
     """
     _, kh, kw, ci, co = w.shape
-    if strategy == "pallas":
-        from fedml_tpu.ops.pallas_grouped_conv import lane_conv_pallas
-        return lane_conv_pallas(merged_to_stacked(x, L), w, L, strides,
-                                padding)
     if strategy == "bgc":
         return lane_conv_bgc(merged_to_stacked(x, L), w, L,
                              strides=strides, padding=padding)
@@ -195,10 +185,8 @@ def make_lane_packed_apply(model, L: int, lowering: str = "blockdiag"):
     stat-free families).
 
     ``lowering`` selects the per-lane conv strategy (CifarResNet only):
-    ``"blockdiag"`` everywhere, ``"bgc"`` everywhere, ``"pallas"``
-    (bgc forward + the Pallas grouped-conv dW kernel on every stride-1
-    conv -- the backward-dW cost-center candidate of the
-    ``--lane_lowering`` A/B, ROADMAP S6), or ``"auto"`` -- per conv by
+    ``"blockdiag"`` everywhere, ``"bgc"`` everywhere, or ``"auto"`` --
+    per conv by
     input channel count (:data:`BGC_MAX_CI`): batch-group convs for the
     narrow stages (Ci<=32) and the block-diagonal embedding for the wide
     one (Ci=64).
@@ -215,7 +203,7 @@ def make_lane_packed_apply(model, L: int, lowering: str = "blockdiag"):
             f"lane-packed apply supports "
             f"{', '.join(c.__name__ for c in PACKED_FAMILIES)}, "
             f"got {type(model).__name__}")
-    if lowering not in ("blockdiag", "bgc", "auto", "pallas"):
+    if lowering not in ("blockdiag", "bgc", "auto"):
         raise ValueError(f"unknown lane lowering {lowering!r}")
     n = (model.depth - 2) // 6
     dtype = model.dtype
@@ -230,15 +218,9 @@ def make_lane_packed_apply(model, L: int, lowering: str = "blockdiag"):
             s = (strides, strides)
             pad = ((padding, padding), (padding, padding))
             ci = w.shape[-2]
-            if lowering == "pallas":
-                # every conv routes through the custom-vjp bgc forward;
-                # the vjp itself uses XLA's dW on the strided ones (4 of
-                # 57 in ResNet-56)
-                strat = "pallas"
-            else:
-                strat = ("bgc" if lowering == "bgc"
-                         or (lowering == "auto" and ci <= BGC_MAX_CI)
-                         else "blockdiag")
+            strat = ("bgc" if lowering == "bgc"
+                     or (lowering == "auto" and ci <= BGC_MAX_CI)
+                     else "blockdiag")
             return lane_conv(xin, w.astype(dtype), L, strides=s, padding=pad,
                              strategy=strat)
 

@@ -35,6 +35,7 @@ the distributed control plane lowers the SAME program host-side via
 from __future__ import annotations
 
 import dataclasses
+import logging
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -95,6 +96,27 @@ def _tree_select(pred, new, old):
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, old)
 
 
+def _make_grad_at(spec: TrainSpec):
+    """``grad_at(params, rest, batch, step_rng)``: one step's
+    augmentation, loss and gradient, as every client-update variant
+    takes them."""
+
+    def grad_at(params, rest, batch, step_rng):
+        if spec.augment_fn is not None:
+            batch = dict(batch)
+            batch["x"] = spec.augment_fn(
+                batch["x"], jax.random.fold_in(step_rng, 13))
+
+        def loss_wrapper(p):
+            state = dict(rest)
+            state["params"] = p
+            return spec.loss_fn(state, batch, step_rng, True)
+
+        return jax.value_and_grad(loss_wrapper, has_aux=True)(params)
+
+    return grad_at
+
+
 def make_client_update(spec: TrainSpec, cfg: ClientUpdateConfig):
     """Build the jittable per-client local-training function.
 
@@ -105,6 +127,7 @@ def make_client_update(spec: TrainSpec, cfg: ClientUpdateConfig):
     Fully-masked (padded) steps leave all carried state untouched.
     """
     optimizer = make_optimizer(cfg)
+    grad_at = _make_grad_at(spec)
 
     def client_update(global_state, client_data, rng):
         params, rest = _split_state(global_state)
@@ -114,19 +137,8 @@ def make_client_update(spec: TrainSpec, cfg: ClientUpdateConfig):
         def step(carry, xs):
             params, rest, opt_state = carry
             batch, step_idx = xs
-            step_rng = jax.random.fold_in(rng, step_idx)
-            if spec.augment_fn is not None:
-                batch = dict(batch)
-                batch["x"] = spec.augment_fn(
-                    batch["x"], jax.random.fold_in(step_rng, 13))
-
-            def loss_wrapper(p):
-                state = dict(rest)
-                state["params"] = p
-                return spec.loss_fn(state, batch, step_rng, True)
-
-            (loss, (new_state, metrics)), grads = jax.value_and_grad(
-                loss_wrapper, has_aux=True)(params)
+            (_, (new_state, metrics)), grads = grad_at(
+                params, rest, batch, jax.random.fold_in(rng, step_idx))
             updates, new_opt = optimizer.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
             new_rest = {k: new_state[k] for k in rest}
@@ -161,6 +173,7 @@ def make_indexed_client_update(spec: TrainSpec, cfg: ClientUpdateConfig):
     hard part #2 (client-state swap without stalling).
     """
     optimizer = make_optimizer(cfg)
+    grad_at = _make_grad_at(spec)
 
     def client_update(global_state, data, sched, rng):
         params, rest = _split_state(global_state)
@@ -173,18 +186,8 @@ def make_indexed_client_update(spec: TrainSpec, cfg: ClientUpdateConfig):
             batch = {"x": jnp.take(data["x"], idx_b, axis=0),
                      "y": jnp.take(data["y"], idx_b, axis=0),
                      "mask": mask_b}
-            step_rng = jax.random.fold_in(rng, step_idx)
-            if spec.augment_fn is not None:
-                batch["x"] = spec.augment_fn(
-                    batch["x"], jax.random.fold_in(step_rng, 13))
-
-            def loss_wrapper(p):
-                state = dict(rest)
-                state["params"] = p
-                return spec.loss_fn(state, batch, step_rng, True)
-
-            (loss, (new_state, metrics)), grads = jax.value_and_grad(
-                loss_wrapper, has_aux=True)(params)
+            (_, (new_state, metrics)), grads = grad_at(
+                params, rest, batch, jax.random.fold_in(rng, step_idx))
             updates, new_opt = optimizer.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
             new_rest = {k: new_state[k] for k in rest}
@@ -219,23 +222,11 @@ def _make_trip_loop_core(spec: TrainSpec, cfg: ClientUpdateConfig):
     (params, rest, metrics_sum)``.
     """
     optimizer = make_optimizer(cfg)
+    grad_at = _make_grad_at(spec)
 
     def run(global_state, batch_at, trip, rng):
         params, rest = _split_state(global_state)
         opt_state = optimizer.init(params)
-
-        def grad_at(params, rest, batch, step_rng):
-            if spec.augment_fn is not None:
-                batch = dict(batch)
-                batch["x"] = spec.augment_fn(
-                    batch["x"], jax.random.fold_in(step_rng, 13))
-
-            def loss_wrapper(p):
-                state = dict(rest)
-                state["params"] = p
-                return spec.loss_fn(state, batch, step_rng, True)
-
-            return jax.value_and_grad(loss_wrapper, has_aux=True)(params)
 
         # metric-structure discovery: abstract-eval one step, carry zeros
         metrics0 = jax.tree.map(
@@ -383,8 +374,9 @@ class BucketedStreamRunner:
       steady-state retraces are zero and ``compiled_shapes()`` equals the
       number of non-empty buckets (asserted in CI).
 
-    Async composition: pass a ``resilience.async_agg.BufferedAggregator``
-    and the stream folds chunk partials through it instead -- up to
+    Async composition: build the runner with ``aggregator=`` (a
+    ``resilience.async_agg.BufferedAggregator``) and the stream folds
+    chunk partials through it instead -- up to
     ``async_window`` chunks stay in flight (the simulated client
     concurrency), every ``buffer_k`` folded clients flush a server update
     MID-ROUND, and chunks dispatched before a flush fold in staleness-
@@ -401,7 +393,7 @@ class BucketedStreamRunner:
     the server's view, and aggregate the RECONSTRUCTED states -- so the
     payload partial sums are exactly what a real compressed transport
     would deliver. Residuals are gathered/scattered by STABLE client id
-    through a ``compression.ResidualStore`` handed to :meth:`run_round`
+    through the ``compression.ResidualStore`` the runner was built with
     (dense device rows when the population fits, lazy host spill
     beyond), the residual arrays share the chunk's ONE compiled shape
     per bucket edge (``[client_chunk, ...]`` rows -- the compressor
@@ -411,10 +403,16 @@ class BucketedStreamRunner:
     buckets_used`` hold exactly as in the plain path (CI-gated).
     """
 
+    mode = "bucketed"
+
     def __init__(self, spec: TrainSpec, cfg: ClientUpdateConfig,
                  payload_fn=None, server_fn=None, client_chunk=256,
                  batch_size=32, epochs=1, edges=(8,), step_bucket=8,
-                 compressor=None):
+                 compressor=None, shards=None, data_rng=None,
+                 aggregator=None, async_window=4, residual_store=None,
+                 wire_bytes=None):
+        import numpy as np
+
         self.payload_fn = payload_fn or _default_payload
         self.server_fn = server_fn or _default_server
         self.client_chunk = max(1, int(client_chunk))
@@ -423,6 +421,16 @@ class BucketedStreamRunner:
         self.edges = sorted(int(e) for e in edges)
         self.step_bucket = int(step_bucket)
         self.compressor = compressor
+        # the feed (``shards``: stable client id -> raw ``{"x", "y"}``;
+        # ``data_rng``: the host stream the schedules draw from) and the
+        # fold's policy (class docstring); ``wire_bytes``: one client
+        # update's static (encoded, raw) bytes
+        self.shards = shards
+        self.data_rng = data_rng or np.random.default_rng(0)
+        self.aggregator = aggregator
+        self.async_window = int(async_window)
+        self.residual_store = residual_store
+        self.wire_bytes = wire_bytes
         client_update = make_streamed_client_update(spec, cfg)
         payload_fn_ = self.payload_fn
         server_fn_ = self.server_fn
@@ -431,13 +439,10 @@ class BucketedStreamRunner:
             payloads = jax.vmap(payload_fn_, in_axes=(0, None, 0))(
                 local_states, global_state, aux)
             w = aux["n"].astype(jnp.float32)
-            pay_sum = jax.tree.map(
-                lambda x: jnp.tensordot(w, x.astype(jnp.float32),
-                                        axes=(0, 0)),
-                payloads)
             metrics_sum = jax.tree.map(lambda m: jnp.sum(m, axis=0),
                                        metrics)
-            return pay_sum, jnp.sum(w), metrics_sum
+            return (_weighted_payload_sum(payloads, w), jnp.sum(w),
+                    metrics_sum)
 
         if compressor is None:
             @jax.jit
@@ -517,35 +522,26 @@ class BucketedStreamRunner:
         # zero-steady-state-retrace gates stay honest
         self._edge_costs = {}
 
-    def _payload_dtypes(self, global_state):
-        if self._dtypes is None:
-            self._dtypes = payload_dtype_template(self.payload_fn,
-                                                  global_state)
-        return self._dtypes
-
     def compiled_shapes(self) -> int:
         """Distinct compiled chunk programs (should equal the number of
         non-empty buckets ever dispatched -- the retrace-audit anchor)."""
         return int(self._chunk_fn._cache_size())
 
-    def run_round(self, global_state, server_state, datasets, rng,
-                  data_rng=None, aggregator=None, async_window=4,
-                  client_ids=None, residual_store=None):
-        """One federated round over ``datasets`` (the cohort's raw client
-        shards, list of ``{"x", "y"}``), streamed bucket by bucket.
+    def run_round(self, global_state, server_state, client_indexes, rng):
+        """One federated round over the cohort ``client_indexes`` (stable
+        client ids into ``self.shards``), streamed bucket by bucket.
 
-        ``aggregator`` (optional ``BufferedAggregator``) switches the
-        fold to the host's buffered-async one; otherwise the partials
-        fold synchronously, on the device (``info["fold"]`` says which:
-        ``"device"`` or ``"host"``), and ``async_window`` (the buffered
-        path's chunks in flight) is not read. With a ``compressor`` armed,
-        ``residual_store`` (a ``compression.ResidualStore``) carries each client's EF
-        residual across the rounds it is sampled into, keyed by
-        ``client_ids`` (stable ids aligned with ``datasets``; defaults
-        to cohort ordinals for store-owning callers like the direct
-        tests). Returns ``(new_global, new_server_state, info)`` with
-        ``info["bucket"]`` (waste accounting) and ``info["async"]``
-        (buffer counters) next to the usual ``aux``/``metrics``.
+        With ``self.aggregator`` set the fold is the host's
+        buffered-async one; otherwise the partials fold synchronously, on
+        the device (``info["fold"]`` says which: ``"device"`` or
+        ``"host"``), and ``async_window`` (the buffered path's chunks in
+        flight) is not read. With a ``compressor`` armed,
+        ``self.residual_store`` carries each client's EF residual across
+        the rounds it is sampled into, keyed by its id. Returns
+        ``(new_global, new_server_state, info)`` with ``info["bucket"]``
+        (waste accounting), ``info["async"]`` (buffer counters) and
+        ``info["wire"]`` (uplink bytes, compressed runs) next to the
+        usual ``aux``/``metrics``.
         """
         import numpy as np
         from collections import deque
@@ -553,7 +549,10 @@ class BucketedStreamRunner:
         from fedml_tpu.parallel.packing import (
             _steps_for, bucket_edge_for, gather_batches, pack_schedule)
 
-        data_rng = data_rng or np.random.default_rng(0)
+        data_rng, aggregator = self.data_rng, self.aggregator
+        residual_store = self.residual_store
+        client_ids = [int(i) for i in client_indexes]
+        datasets = [self.shards[i] for i in client_ids]
         C = len(datasets)
         if C == 0:
             raise ValueError("bucketed round over an empty cohort")
@@ -561,7 +560,7 @@ class BucketedStreamRunner:
             raise ValueError(
                 "streaming-EF needs a residual_store: the error-feedback "
                 "accumulator is keyed by stable client id ACROSS rounds "
-                "(compression.ResidualStore; FedAvgAPI owns one)")
+                "(compression.ResidualStore; select_runner builds one)")
         ns = [len(d["y"]) for d in datasets]
         if sum(ns) == 0:
             raise ValueError("bucketed round: every client shard is empty")
@@ -578,7 +577,7 @@ class BucketedStreamRunner:
         bucket_edge_for(steps_pc.max(), self.edges)  # top-edge guard
         client_keys = np.asarray(
             jax.random.split(jax.random.fold_in(rng, 1), C))
-        dtypes = self._payload_dtypes(global_state)
+        dtypes = _payload_dtypes(self, global_state)
         flush_rng = jax.random.fold_in(rng, 2)
         comp_keys = None
         if self.compressor is not None:
@@ -586,8 +585,6 @@ class BucketedStreamRunner:
             # rule as make_compressed_sim_round, per stable cohort slot
             comp_keys = np.asarray(
                 jax.random.split(jax.random.fold_in(rng, 3), C))
-            if client_ids is None:
-                client_ids = list(range(C))
 
         gs, ss = global_state, server_state
         cm = get_cost_model()  # one global read when attribution is off
@@ -609,7 +606,7 @@ class BucketedStreamRunner:
         # at 4 or 2 in flight, 9.94 GB at 1, the round equally long: the
         # next chunk is still fed while this one runs). The buffered
         # path's window is its policy's (the simulated concurrency)
-        depth = 1 if on_device else max(1, int(async_window))
+        depth = 1 if on_device else max(1, self.async_window)
         sync_w = 0.0
         inflight = deque()
         exec_steps = 0
@@ -778,14 +775,8 @@ class BucketedStreamRunner:
                     # (the dispatch above runs async meanwhile):
                     # ShapeDtypeStructs only, so the probe never holds
                     # or syncs device buffers
-                    abst = lambda t: jax.tree.map(
-                        lambda a: jax.ShapeDtypeStruct(
-                            a.shape, a.dtype), t)
                     self._edge_costs[edge] = program_cost(
-                        self._chunk_fn,
-                        *(abst(a) if i != 3
-                          else jax.ShapeDtypeStruct((), jnp.int32)
-                          for i, a in enumerate(args)))
+                        self._chunk_fn, *abstract(args))
                 # note() every time (setdefault-idempotent): a CostModel
                 # armed AFTER the runner warmed its edge cache must
                 # still collect the catalog
@@ -878,7 +869,52 @@ class BucketedStreamRunner:
             info["bucket"]["flops_source"] = "xla"
         if async_info is not None:
             info["async"] = async_info
+        if self.compressor is not None:
+            info["wire"] = wire_record(self.wire_bytes, C)
         return gs, ss, info
+
+    def programs(self, global_state, server_state, client_indexes):
+        """Every program a round over ``client_indexes`` dispatches, as
+        ``(name, jitted function, abstract arguments)``: one chunk
+        program per bucket edge (whichever cohort comes, its chunks land
+        on these), the donated server advance and, on the synchronous
+        path, the device fold's programs as far as the cohort's chunk
+        count reaches them."""
+        shard = next(d for d in self.shards.values() if len(d["y"]))
+        chunk, bs = self.client_chunk, self.batch_size
+        gs, ss = abstract(global_state), abstract(server_state)
+        key = key_abstract()
+        keys = jax.ShapeDtypeStruct((chunk,) + key.shape, key.dtype)
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+        out = []
+        for edge in self.edges:
+            batches = {k: jax.ShapeDtypeStruct(
+                (chunk, edge, bs) + shard[k].shape[1:], shard[k].dtype)
+                for k in ("x", "y")}
+            batches["mask"] = f32(chunk, edge, bs)
+            args = (gs, batches, f32(chunk),
+                    jax.ShapeDtypeStruct((), jnp.int32), keys)
+            if self.compressor is not None:
+                args += (residuals_abstract(gs["params"], chunk), keys)
+            out.append((f"bucket_chunk_s{edge}", self._chunk_fn, args))
+        pay = jax.eval_shape(self._chunk_fn, *args)[0]
+        dtypes = abstract(_payload_dtypes(self, global_state))
+        if self.aggregator is None:
+            n_chunks = -(-len(client_indexes) // chunk)
+            if n_chunks > 1:
+                out.append(("fold_first", self._fold_first, (pay, pay)))
+            if n_chunks > 2:
+                out.append(("fold_next", self._fold_next, (pay, pay, pay)))
+            quotient = (pay, pay if n_chunks > 1 else None, f32(), f32(),
+                        dtypes)
+            out.append(("fold_quotient", self._fold_quotient, quotient))
+            avg = jax.eval_shape(self._fold_quotient, *quotient)
+        else:
+            avg = jax.tree.map(
+                lambda a, d: jax.ShapeDtypeStruct(a.shape, d.dtype),
+                pay, dtypes)
+        out.append(("advance", self._advance_fn, (gs, ss, avg, key)))
+        return out
 
 
 class WaveRunner:
@@ -902,6 +938,8 @@ class WaveRunner:
     checkpoints resume across either.
     """
 
+    mode = "waves"
+
     def __init__(self, spec: TrainSpec, cfg: ClientUpdateConfig,
                  payload_fn=None, server_fn=None, client_chunk=8):
         self.payload_fn = payload_fn or _default_payload
@@ -921,11 +959,9 @@ class WaveRunner:
             payloads = jax.vmap(payload_fn_, in_axes=(0, None, 0))(
                 local_states, global_state, aux)
             w = aux["n"].astype(jnp.float32)
-            pay_sum = jax.tree.map(
-                lambda x: jnp.tensordot(w, x.astype(jnp.float32), axes=(0, 0)),
-                payloads)
             metrics_sum = jax.tree.map(lambda m: jnp.sum(m, axis=0), metrics)
-            return pay_sum, jnp.sum(w), metrics_sum, aux
+            return (_weighted_payload_sum(payloads, w), jnp.sum(w),
+                    metrics_sum, aux)
 
         @jax.jit
         def add_fn(a, b):
@@ -933,29 +969,40 @@ class WaveRunner:
 
         @jax.jit
         def finish_fn(global_state, server_state, pay_sum, w_sum, dtypes, rng):
-            # weighted mean over the accumulated sums. NOTE: unlike
-            # pytree.tree_weighted_mean there is no uniform fallback here --
-            # an all-empty cohort (w_sum == 0) yields a zero payload, so
-            # callers MUST fail fast on empty cohorts before dispatch
-            # (FedAvgAPI.train_one_round raises; direct users take note)
-            avg = jax.tree.map(
-                lambda s, d: (s / jnp.maximum(w_sum, 1e-12)).astype(d.dtype),
-                pay_sum, dtypes)
-            return server_fn_(global_state, avg, server_state, rng)
+            return server_fn_(global_state,
+                              _average_through(pay_sum, w_sum, dtypes),
+                              server_state, rng)
 
         self._wave_fn = wave_fn
         self._add_fn = add_fn
         self._finish_fn = finish_fn
         self._dtypes = None
 
-    def _payload_dtypes(self, global_state):
-        if self._dtypes is None:
-            self._dtypes = payload_dtype_template(self.payload_fn,
-                                                  global_state)
-        return self._dtypes
+    def programs(self, global_state, server_state, device_data, ids, sched):
+        """The per-wave program, its cross-wave add and the finish step
+        (operand shapes from the wave's outputs), at ``sched``'s shapes."""
+        chunk = min(self.client_chunk, len(ids))
+        gs, ss = abstract(global_state), abstract(server_state)
+        key = key_abstract()
+        sds = jax.ShapeDtypeStruct
+        ws = {"idx": sds((chunk,) + sched["idx"].shape[1:], jnp.int32),
+              "mask": sds((chunk,) + sched["mask"].shape[1:], jnp.float32),
+              "n": sds((chunk,), jnp.float32)}
+        wave_args = (gs, abstract(device_data["x"]),
+                     abstract(device_data["y"]), sds((chunk,), jnp.int32),
+                     ws, sds((), jnp.int32),
+                     sds((chunk,) + key.shape, key.dtype))
+        part = jax.eval_shape(self._wave_fn, *wave_args)[:3]
+        return [
+            ("wave", self._wave_fn, wave_args),
+            ("wave_add", self._add_fn, (part, part)),
+            ("wave_finish", self._finish_fn,
+             (gs, ss, part[0], part[1],
+              abstract(_payload_dtypes(self, global_state)), key)),
+        ]
 
-    def run_round(self, global_state, server_state, device_data, ids, sched,
-                  rng):
+    def run_schedule(self, global_state, server_state, device_data, ids,
+                     sched, rng):
         """One federated round.
 
         Args:
@@ -1011,7 +1058,7 @@ class WaveRunner:
         with get_tracer().span("server-update"):
             new_global, new_server_state = self._finish_fn(
                 global_state, server_state, pay_sum, w_sum,
-                self._payload_dtypes(global_state),
+                _payload_dtypes(self, global_state),
                 jax.random.fold_in(rng, 2))
 
         # gather per-client aux back into cohort order (host, post-dispatch)
@@ -1040,6 +1087,7 @@ def make_lane_update(spec: TrainSpec, cfg: ClientUpdateConfig, payload_fn):
     global model, so padded compute never executes.
     """
     optimizer = make_optimizer(cfg)
+    grad_at = _make_grad_at(spec)
 
     def lane_update(global_state, data_x, data_y, n_max, rows, lane,
                     step_keys, trip):
@@ -1058,19 +1106,6 @@ def make_lane_update(spec: TrainSpec, cfg: ClientUpdateConfig, payload_fn):
             return {"x": jnp.take(data_x, flat, axis=0),
                     "y": jnp.take(data_y, flat, axis=0),
                     "mask": mask_b}
-
-        def grad_at(params, rest, batch, step_rng):
-            if spec.augment_fn is not None:
-                batch = dict(batch)
-                batch["x"] = spec.augment_fn(
-                    batch["x"], jax.random.fold_in(step_rng, 13))
-
-            def loss_wrapper(p):
-                state = dict(rest)
-                state["params"] = p
-                return spec.loss_fn(state, batch, step_rng, True)
-
-            return jax.value_and_grad(loss_wrapper, has_aux=True)(params)
 
         metrics0 = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype),
@@ -1127,7 +1162,7 @@ def make_lane_update(spec: TrainSpec, cfg: ClientUpdateConfig, payload_fn):
 
 
 def make_packed_lane_update(spec: TrainSpec, cfg: ClientUpdateConfig,
-                            payload_fn, n_lanes: int):
+                            payload_fn):
     """MXU-shaped variant of :func:`make_lane_update`: ALL lanes advance
     in one program per step, with the model's lane axis folded into
     channels by ``spec.lane_loss_builder`` (``models/lane_packed.py``)
@@ -1147,8 +1182,6 @@ def make_packed_lane_update(spec: TrainSpec, cfg: ClientUpdateConfig,
     ``[L, trip, 2]``, and the returns carry a leading lane axis.
     """
     optimizer = make_optimizer(cfg)
-    del n_lanes  # the REAL lane count comes from the traced arrays:
-    # pack_lanes may return fewer lanes than requested for small cohorts
     if spec.lane_loss_builder is None:
         raise ValueError(
             f"spec '{spec.name}' has no lane_loss_builder: the packed "
@@ -1292,12 +1325,12 @@ class LaneRunner:
         self.server_fn = server_fn or _default_server
         self.n_lanes = int(n_lanes or 8)
         self.packed = bool(packed)
+        self.mode = "mxu-lanes" if self.packed else "lanes"
         if self.packed:
             # MXU-shaped lowering: lane axis folded into channels by the
             # spec's lane_loss_builder (raises if the model family has
             # none) instead of vmap over lane-stacked weights
-            packed_update = make_packed_lane_update(
-                spec, cfg, self.payload_fn, self.n_lanes)
+            packed_update = make_packed_lane_update(spec, cfg, self.payload_fn)
         else:
             lane_update = make_lane_update(spec, cfg, self.payload_fn)
         server_fn_ = self.server_fn
@@ -1321,49 +1354,68 @@ class LaneRunner:
             pay_sum = jax.tree.map(lambda x: jnp.sum(x, axis=0), pay)
             w_sum = jnp.sum(w)
             metrics_sum = jax.tree.map(lambda m: jnp.sum(m, axis=0), msum)
-            avg = jax.tree.map(
-                lambda s, d: (s / jnp.maximum(w_sum, 1e-12)).astype(d.dtype),
-                pay_sum, dtypes)
-            new_global, new_server = server_fn_(global_state, avg,
-                                                server_state, rng)
+            new_global, new_server = server_fn_(
+                global_state, _average_through(pay_sum, w_sum, dtypes),
+                server_state, rng)
             return new_global, new_server, metrics_sum
 
         self._round_fn = round_fn
         self._fold_keys = fold_step_keys
         self._dtypes = None
 
-    def _payload_dtypes(self, global_state):
-        if self._dtypes is None:
-            self._dtypes = payload_dtype_template(self.payload_fn,
-                                                  global_state)
-        return self._dtypes
-
-    def run_round(self, global_state, server_state, device_data, ids, sched,
-                  rng):
-        """Same contract as :meth:`WaveRunner.run_round` (cohort ``ids``
-        into ``device_data``, full ``pack_schedule`` output, round key);
-        executes as one dispatch over ``n_lanes`` packed lanes."""
-        import numpy as np
-
+    def _lanes(self, sched):
+        """``pack_lanes`` of a schedule as the round program takes it:
+        ``(lane arrays, local_step, trip)``, on the host."""
         from fedml_tpu.parallel.packing import pack_lanes
 
-        C = len(np.asarray(sched["n"]))
         lanes = pack_lanes(sched, self.n_lanes)
-        trip = jnp.int32(max(lanes.pop("trip"), 1))
+        trip = max(lanes.pop("trip"), 1)
+        local_step = lanes.pop("local_step")
+        return ({k: lanes[k] for k in ("idx", "mask", "slot", "flush",
+                                       "flush_n", "flush_steps")},
+                local_step, trip)
+
+    def programs(self, global_state, server_state, device_data, ids, sched):
+        """The round's ONE donated program and the per-step PRNG
+        derivation (its own jitted dispatch), at the lane shapes the same
+        ``pack_lanes`` call gives ``run_schedule``."""
+        lanes, local_step, _ = self._lanes(sched)
+        key = key_abstract()
+        sds = jax.ShapeDtypeStruct
+        C, KL = len(ids), local_step.shape
+        return [
+            ("mxu_lane_round" if self.packed else "lane_round",
+             self._round_fn,
+             (abstract(global_state), abstract(server_state),
+              abstract(device_data["x"]), abstract(device_data["y"]),
+              sds((C,), jnp.int32), abstract(lanes),
+              sds(KL + key.shape, key.dtype), sds((), jnp.int32),
+              abstract(_payload_dtypes(self, global_state)), key)),
+            ("fold_step_keys", self._fold_keys,
+             (sds((C,) + key.shape, key.dtype), sds(KL, jnp.int32),
+              sds(KL, jnp.int32))),
+        ]
+
+    def run_schedule(self, global_state, server_state, device_data, ids,
+                     sched, rng):
+        """Same contract as :meth:`WaveRunner.run_schedule` (cohort
+        ``ids`` into ``device_data``, full ``pack_schedule`` output, round
+        key); executes as one dispatch over ``n_lanes`` packed lanes."""
+        import numpy as np
+
+        C = len(np.asarray(sched["n"]))
+        lanes, local_step, trip = self._lanes(sched)
         client_keys = jax.random.split(jax.random.fold_in(rng, 1), C)
-        lane_arrays = {k: jnp.asarray(v) for k, v in lanes.items()
-                       if k in ("idx", "mask", "slot", "flush", "flush_n",
-                                "flush_steps")}
-        step_keys = self._fold_keys(client_keys,
-                                    jnp.asarray(lanes["slot"]),
-                                    jnp.asarray(lanes["local_step"]))
+        lane_arrays = {k: jnp.asarray(v) for k, v in lanes.items()}
+        step_keys = self._fold_keys(client_keys, lane_arrays["slot"],
+                                    jnp.asarray(local_step))
         rows = jnp.asarray(np.asarray(ids, np.int32))
         with get_tracer().span("lanes", clients=int(C),
-                               n_lanes=int(self.n_lanes), trip=int(trip)):
+                               n_lanes=int(self.n_lanes), trip=trip):
             new_global, new_server, metrics = self._round_fn(
                 global_state, server_state, device_data["x"],
-                device_data["y"], rows, lane_arrays, step_keys, trip,
-                self._payload_dtypes(global_state),
+                device_data["y"], rows, lane_arrays, step_keys,
+                jnp.int32(trip), _payload_dtypes(self, global_state),
                 jax.random.fold_in(rng, 2))
         steps_pc = (np.asarray(sched["mask"]).sum(axis=2) > 0).sum(axis=1)
         aux = {"n": np.asarray(sched["n"], np.float32),
@@ -1393,6 +1445,8 @@ class ShardedLaneRunner:
     within a block is absorbed by the in-shard LPT packing).
     """
 
+    mode = "sharded-lanes"
+
     def __init__(self, spec: TrainSpec, cfg: ClientUpdateConfig, mesh,
                  payload_fn=None, server_fn=None, n_lanes=8, packed=False):
         self.payload_fn = payload_fn or _default_payload
@@ -1403,8 +1457,7 @@ class ShardedLaneRunner:
         if self.packed:
             # each shard runs ITS lanes through the MXU-shaped lowering
             # (models/lane_packed.py); the cross-chip psum is unchanged
-            packed_update = make_packed_lane_update(
-                spec, cfg, self.payload_fn, self.n_lanes)
+            packed_update = make_packed_lane_update(spec, cfg, self.payload_fn)
         else:
             lane_update = make_lane_update(spec, cfg, self.payload_fn)
         server_fn_ = self.server_fn
@@ -1435,11 +1488,9 @@ class ShardedLaneRunner:
             metrics = jax.tree.map(
                 lambda m: jax.lax.psum(jnp.sum(m, axis=0), CLIENT_AXIS),
                 msum)
-            avg = jax.tree.map(
-                lambda s, d: (s / jnp.maximum(w_sum, 1e-12)).astype(d.dtype),
-                pay_sum, dtypes)
-            new_global, new_server = server_fn_(global_state, avg,
-                                                server_state, rng)
+            new_global, new_server = server_fn_(
+                global_state, _average_through(pay_sum, w_sum, dtypes),
+                server_state, rng)
             return new_global, new_server, metrics
 
         sharded = jax.shard_map(
@@ -1453,17 +1504,19 @@ class ShardedLaneRunner:
         self._fold_keys = fold_step_keys
         self._dtypes = None
 
-    def _payload_dtypes(self, global_state):
-        if self._dtypes is None:
-            self._dtypes = payload_dtype_template(self.payload_fn,
-                                                  global_state)
-        return self._dtypes
+    def programs(self, global_state, server_state, device_data, ids, sched):
+        """Nothing yet: the SPMD shard shapes are not enumerated
+        (ROADMAP), so a sharded-lane round compiles at its first
+        dispatch."""
+        logging.info("fedwarm: mesh-sharded lane rounds are not warmed "
+                     "yet (SPMD shard shapes; follow-up)")
+        return []
 
-    def run_round(self, global_state, server_state, device_data, ids, sched,
-                  rng):
-        """Same contract as :meth:`LaneRunner.run_round`; ``device_data``
-        is SHARDED over the mesh's client axis (row blocks of size
-        ``R / D``), and ``ids`` are global device rows."""
+    def run_schedule(self, global_state, server_state, device_data, ids,
+                     sched, rng):
+        """Same contract as :meth:`LaneRunner.run_schedule`;
+        ``device_data`` is SHARDED over the mesh's client axis (row
+        blocks of size ``R / D``), and ``ids`` are global device rows."""
         import numpy as np
 
         from fedml_tpu.parallel.packing import pack_lanes
@@ -1544,7 +1597,7 @@ class ShardedLaneRunner:
             new_global, new_server, metrics = self._round_fn(
                 global_state, server_state, device_data["x"],
                 device_data["y"], rows_all, lanes_all, keys_all, trip,
-                self._payload_dtypes(global_state),
+                _payload_dtypes(self, global_state),
                 jax.random.fold_in(rng, 2))
         steps_pc = (mask.sum(axis=2) > 0).sum(axis=1)
         aux = {"n": np.asarray(sched["n"], np.float32),
@@ -1631,6 +1684,60 @@ def payload_dtype_template(payload_fn, global_state):
     return jax.tree.map(lambda s: jnp.zeros((), s.dtype), shapes)
 
 
+def _payload_dtypes(runner, global_state):
+    """``runner``'s payload dtype template, made at its first round."""
+    if runner._dtypes is None:
+        runner._dtypes = payload_dtype_template(runner.payload_fn,
+                                                global_state)
+    return runner._dtypes
+
+
+def _weighted_payload_sum(payloads, w):
+    """``sum_c w[c] * payloads[c]`` over the leading client axis, f32."""
+    return jax.tree.map(
+        lambda x: jnp.tensordot(w, x.astype(jnp.float32), axes=(0, 0)),
+        payloads)
+
+
+def _average_through(pay_sum, w_sum, dtypes):
+    """The weighted mean of accumulated f32 sums, cast back through the
+    payload dtype template. No uniform fallback (unlike
+    ``pytree.tree_weighted_mean``): an all-empty cohort (``w_sum == 0``)
+    yields a zero payload, so callers fail fast on empty cohorts before
+    dispatch (``FedAvgAPI`` raises)."""
+    return jax.tree.map(
+        lambda s, d: (s / jnp.maximum(w_sum, 1e-12)).astype(d.dtype),
+        pay_sum, dtypes)
+
+
+def key_abstract():
+    """The abstract value of a PRNG key as the runners pass it."""
+    return jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+
+def residuals_abstract(params, rows):
+    """``rows`` error-feedback residuals, abstract: a residual has its
+    parameter's shape and dtype."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((rows,) + a.shape, a.dtype), params)
+
+
+def abstract(tree):
+    """Pytree of arrays / ShapeDtypeStructs -> all-ShapeDtypeStructs: the
+    arguments a runner's ``programs()`` lists."""
+    return jax.eval_shape(lambda t: t, tree)
+
+
+def wire_record(wire_bytes, cohort):
+    """Client->server update traffic of one round (uplink; the downlink
+    model broadcast is uncompressed and identical in both regimes, so
+    the ratio isolates what compression buys). ``wire_bytes`` is one
+    client's (encoded, raw) bytes: static given the template, so the
+    packed compressed round and the streaming-EF path account alike."""
+    wire, raw = wire_bytes[0] * cohort, wire_bytes[1] * cohort
+    return {"bytes_on_wire": wire, "compression_ratio": round(raw / wire, 3)}
+
+
 @jax.jit
 def fold_step_keys(client_keys, slot, local_step):
     """Per-step PRNG keys for packed lanes:
@@ -1697,8 +1804,7 @@ def make_sharded_round(spec: TrainSpec, cfg: ClientUpdateConfig, mesh,
         payloads = jax.vmap(payload_fn, in_axes=(0, None, 0))(
             local_states, global_state, aux)
         w = aux["n"].astype(jnp.float32)
-        local_sum = jax.tree.map(
-            lambda x: jnp.tensordot(w, x.astype(jnp.float32), axes=(0, 0)), payloads)
+        local_sum = _weighted_payload_sum(payloads, w)
         total = jnp.maximum(jax.lax.psum(jnp.sum(w), CLIENT_AXIS), 1e-12)
         avg_payload = jax.tree.map(
             lambda x, t: (jax.lax.psum(x, CLIENT_AXIS) / total).astype(t.dtype),

@@ -500,7 +500,7 @@ assert report["audit/rounds"] == 2, report
 assert report["audit/steady_state_retraces"] == 0, (
     "bucketed streaming retraced after round 1", report)
 assert report["audit/transfer_guard_violations"] == 0, report
-shapes = api.bucket_runner.compiled_shapes()
+shapes = api.runner.compiled_shapes()
 assert shapes == m["bucket/shapes"] > 0, (shapes, m)
 
 api2 = build(1)

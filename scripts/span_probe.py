@@ -160,18 +160,19 @@ def _fold_both_ways(api, seed):
     oracle = api.program.replace(aggregation=AggregationPolicy(
         buffer_k=10 ** 9, staleness_decay=0.0)).host_view()
     ids = api._sample_cohort(api.round_idx)
-    datasets = [api.train_data_local_dict[i] for i in ids]
+    runner = api.runner
+    own = runner.aggregator, runner.data_rng
     out = {"peak_bytes_device_fold": _peak_bytes()}
     states = {}
     for way in ("device", "host"):
-        agg = None if way == "device" else oracle.make_aggregator()
+        runner.aggregator = (None if way == "device"
+                             else oracle.make_aggregator())
+        runner.data_rng = np.random.default_rng(seed)
         a = time.perf_counter()
-        gs, _, info = api.bucket_runner.run_round(
+        gs, _, info = runner.run_round(
             jax.tree.map(jnp.copy, api.global_state),
-            jax.tree.map(jnp.copy, api.server_state), datasets,
-            jax.random.PRNGKey(seed % (2 ** 31)),
-            data_rng=np.random.default_rng(seed), aggregator=agg,
-            client_ids=ids)
+            jax.tree.map(jnp.copy, api.server_state), ids,
+            jax.random.PRNGKey(seed % (2 ** 31)))
         jax.block_until_ready(gs)
         out[way + "_round_s"] = time.perf_counter() - a
         if info["fold"] != way:
@@ -179,6 +180,7 @@ def _fold_both_ways(api, seed):
                                f"said {info['fold']}")
         states[way] = jax.device_get(jax.tree.leaves(gs))
         del gs
+    runner.aggregator, runner.data_rng = own
     out["peak_bytes_after_host_fold"] = _peak_bytes()
     elements = differing = worst = 0
     for d, h in zip(states["device"], states["host"]):

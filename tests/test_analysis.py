@@ -1670,7 +1670,7 @@ class TestRuntimeAuditor:
         api = FedAvgAPI(_dataset(), _spec(), _args())
         with audit() as auditor:
             api.train_one_round()
-            api.args.batch_size = 8
+            api.runner.batch_size = 8
             api.train_one_round()
         assert auditor.retraces_per_round[1] > 0
         assert auditor.report()["audit/steady_state_retraces"] > 0

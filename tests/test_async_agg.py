@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from stream_helpers import stream_round
 
 from fedml_tpu import models
 from fedml_tpu.algorithms.specs import make_classification_spec
@@ -392,8 +393,8 @@ class TestBucketedStreamRunner:
 
         oracle = Recorder(
             AsyncAggPolicy(buffer_k=10 ** 9, staleness_decay=0.0))
-        runner.run_round(
-            jax.tree.map(jnp.copy, gs0), (), datasets, rng,
+        stream_round(
+            runner, jax.tree.map(jnp.copy, gs0), (), datasets, rng,
             data_rng=np.random.default_rng(3), aggregator=oracle)
         want, _ = fold_entries_fp64(entries.values())
         assert len(entries) == -(-len(datasets) // runner.client_chunk)
@@ -402,8 +403,8 @@ class TestBucketedStreamRunner:
         if path == "buffered":
             agg = BufferedAggregator(
                 AsyncAggPolicy(buffer_k=10 ** 9, staleness_decay=0.0))
-        gs, _, info = runner.run_round(
-            jax.tree.map(jnp.copy, gs0), (), datasets, rng,
+        gs, _, info = stream_round(
+            runner, jax.tree.map(jnp.copy, gs0), (), datasets, rng,
             data_rng=np.random.default_rng(3), aggregator=agg)
         # FedAvg's server step hands the average on as the new state
         got, want = jax.tree.leaves(gs), jax.tree.leaves(want)
@@ -437,8 +438,8 @@ class TestBucketedStreamRunner:
             edges=parse_bucket_edges(None, s_max))
         gs0 = spec.init_fn(jax.random.PRNGKey(1))
         rng = jax.random.PRNGKey(7)
-        gs_b, _, _ = runner.run_round(
-            jax.tree.map(jnp.copy, gs0), (), datasets, rng,
+        gs_b, _, _ = stream_round(
+            runner, jax.tree.map(jnp.copy, gs0), (), datasets, rng,
             data_rng=np.random.default_rng(3))
         flat = make_sim_round(spec, cfg)
         packed = {k: jnp.asarray(v) for k, v in
@@ -458,8 +459,8 @@ class TestBucketedStreamRunner:
         # n_hi=40 / bs=4 / 1 epoch -> max 10 steps; rebuild with tiny
         # shards so every client fits the first edge
         datasets = _ragged_datasets(6, seed=4, n_hi=4)
-        gs, _, info = runner.run_round(
-            jax.tree.map(jnp.copy, gs0), (), datasets,
+        gs, _, info = stream_round(
+            runner, jax.tree.map(jnp.copy, gs0), (), datasets,
             jax.random.PRNGKey(0), data_rng=np.random.default_rng(0))
         per = {b["edge"]: b for b in info["bucket"]["per_bucket"]}
         assert per[8]["skipped"] == 0 and per[8]["clients"] == 6
@@ -492,8 +493,8 @@ class TestBucketedStreamRunner:
             shapes_after_r1 = None
             for r in range(3):
                 cohort = sorted(cohort_rng.choice(24, 16, replace=False))
-                gs, ss, _ = runner.run_round(
-                    gs, ss, [population[i] for i in cohort],
+                gs, ss, _ = stream_round(
+                    runner, gs, ss, [population[i] for i in cohort],
                     jax.random.PRNGKey(r), data_rng=data_rng)
                 auditor.sync_and_mark_round(gs)
                 if r == 0:
@@ -516,8 +517,8 @@ class TestBucketedStreamRunner:
         def run(decay):
             agg = BufferedAggregator(
                 AsyncAggPolicy(buffer_k=6, staleness_decay=decay))
-            gs, _, info = runner.run_round(
-                jax.tree.map(jnp.copy, gs0), (), datasets, rng,
+            gs, _, info = stream_round(
+                runner, jax.tree.map(jnp.copy, gs0), (), datasets, rng,
                 data_rng=np.random.default_rng(3), aggregator=agg,
                 async_window=4)
             return gs, info
@@ -544,14 +545,12 @@ class TestBucketedStreamRunner:
         gs = spec.init_fn(jax.random.PRNGKey(0))
         ss = ()
         data_rng = np.random.default_rng(0)
-        gs, ss, _ = runner.run_round(gs, ss, population[:6],
-                                     jax.random.PRNGKey(1),
-                                     data_rng=data_rng)
+        gs, ss, _ = stream_round(runner, gs, ss, population[:6],
+                                 jax.random.PRNGKey(1), data_rng=data_rng)
         pinned = runner.batch_size
         assert pinned == max(len(d["y"]) for d in population[:6])
-        gs, ss, _ = runner.run_round(gs, ss, population[6:],
-                                     jax.random.PRNGKey(2),
-                                     data_rng=data_rng)
+        gs, ss, _ = stream_round(runner, gs, ss, population[6:],
+                                 jax.random.PRNGKey(2), data_rng=data_rng)
         assert runner.batch_size == pinned  # not re-derived per cohort
 
     def test_weight_accounting_is_honest(self):
@@ -561,9 +560,9 @@ class TestBucketedStreamRunner:
         runner, datasets, gs0 = self._build(C=11, chunk=3)
         agg = BufferedAggregator(
             AsyncAggPolicy(buffer_k=10 ** 9, staleness_decay=0.0))
-        runner.run_round(jax.tree.map(jnp.copy, gs0), (), datasets,
-                         jax.random.PRNGKey(0),
-                         data_rng=np.random.default_rng(0), aggregator=agg)
+        stream_round(runner, jax.tree.map(jnp.copy, gs0), (), datasets,
+                     jax.random.PRNGKey(0),
+                     data_rng=np.random.default_rng(0), aggregator=agg)
         assert agg.counters["clients_folded"] == 11
 
 
@@ -607,8 +606,8 @@ class TestFedAvgAPIWiring:
                       mesh=object())
         api = FedAvgAPI(self._dataset(), _lr_spec(),
                         self._args(compressor="qsgd:8"))
-        assert api.bucket_runner is not None
-        assert api.bucket_runner.compressor is api.compressor
+        assert api.runner.mode == "bucketed"
+        assert api.runner.compressor is api.compressor is not None
         m = api.train_one_round()
         # byte accounting present (this toy model is header-dominated,
         # so the RATIO is no gate here -- the sized gates are the soak's)
@@ -664,7 +663,7 @@ class TestStreamingEF:
                 m = api.train_one_round()
                 auditor.sync_and_mark_round(api.global_state)
         assert report["audit/steady_state_retraces"] == 0, report
-        assert api.bucket_runner.compiled_shapes() == m["bucket/shapes"] > 0
+        assert api.runner.compiled_shapes() == m["bucket/shapes"] > 0
 
     def test_async_oracle_bitwise_with_compressor(self):
         # unbounded buffer + decay 0 == the synchronous compressed fold,
@@ -684,9 +683,9 @@ class TestStreamingEF:
         # produces the identical trajectory to dense device rows
         from fedml_tpu.compression import ResidualStore
         api_d = self._api(compressor="topk:0.25")
-        assert api_d._ef_store.dense
+        assert api_d.runner.residual_store.dense
         api_s = self._api(compressor="topk:0.25")
-        api_s._ef_store = ResidualStore(api_s.global_state["params"],
+        api_s.runner.residual_store = ResidualStore(api_s.global_state["params"],
                                         dense=False)
         for _ in range(3):
             api_d.train_one_round()
@@ -705,14 +704,14 @@ class TestStreamingEF:
         c1 = set(client_sampling(0, 14, 7))
         c2 = set(client_sampling(1, 14, 7))
         touched = sorted(c1)
-        r1 = {i: api._ef_store.peek(i) for i in range(14)}
+        r1 = {i: api.runner.residual_store.peek(i) for i in range(14)}
         for i in range(14):  # round 1 touched exactly its cohort
             nz = any(np.any(v) for v in jax.tree.leaves(r1[i]))
             assert nz == (i in touched), i
         api.train_one_round()
         for i in sorted(set(range(14)) - c2):
             for a, b in zip(jax.tree.leaves(r1[i]),
-                            jax.tree.leaves(api._ef_store.peek(i))):
+                            jax.tree.leaves(api.runner.residual_store.peek(i))):
                 np.testing.assert_array_equal(a, b)
 
     def test_ef_converges_close_to_plain(self):
@@ -734,5 +733,5 @@ class TestStreamingEF:
             compressor=get_compressor("qsgd:8"))
         gs = spec.init_fn(jax.random.PRNGKey(0))
         with pytest.raises(ValueError, match="residual_store"):
-            runner.run_round(gs, (), _ragged_datasets(4, n_hi=4),
-                             jax.random.PRNGKey(1))
+            stream_round(runner, gs, (), _ragged_datasets(4, n_hi=4),
+                         jax.random.PRNGKey(1))

@@ -101,7 +101,7 @@ def test_wave_path_applies_augmentation():
             frequency_of_the_test=100, seed=0, device_resident="auto",
             wave_mode=1, client_chunk=2)
         api = FedAvgAPI(dataset, spec, args)
-        assert api.device_data is not None
+        assert api.runner.mode == "waves"
         api.train_one_round()
         return jax.tree.leaves(api.global_state["params"])
 

@@ -19,7 +19,6 @@ TOY = chip_smoke.Sizes(
     model="cnn", n_train=256, n_test=32, image_size=28, clients=4,
     batch_size=8, client_chunk=2, rounds=3, mesh=4, mesh_rounds=3,
     attn_seq_lens=(40,), attn_batch=1, attn_heads=1, head_dim=32,
-    conv_lanes=2, conv_batch=4, conv_stages=((8, 4),),
     lm_d_model=32, lm_layers=1, lm_seq=16, lm_clients=6, lm_batch=2,
     lm_chunk=4, lm_rounds=2)
 
@@ -81,7 +80,7 @@ def test_leg_c_fails_when_a_device_gains_nothing(tmp_path, cache_dir,
 
 def test_leg_b_toy(cache_dir):
     b = chip_smoke.leg_b(TOY)
-    assert set(b) == {"flash_attention", "grouped_conv_dw", "federated_lm"}
+    assert set(b) == {"flash_attention", "federated_lm"}
     assert len(b["federated_lm"]["train_loss"]) == TOY.lm_rounds
 
 

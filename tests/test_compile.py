@@ -68,7 +68,7 @@ class TestEnumeration:
         assert "advance" in names and "eval" in names
         # one chunk program per bucket edge
         edges = [n for n in names if n.startswith("bucket_chunk_s")]
-        assert len(edges) == len(api.bucket_runner.edges)
+        assert len(edges) == len(api.runner.edges)
 
     @pytest.mark.parametrize("mode,expect", [
         (1, "wave"), (2, "lane_round"), (0, "indexed_round")])
@@ -94,9 +94,9 @@ class TestEnumeration:
                               bucket_edges="geometric"))
         report = warmup_api(api)
         assert report["warmup/programs"] >= 3
-        assert api.bucket_runner.compiled_shapes() == 0
+        assert api.runner.compiled_shapes() == 0
         m = api.train_one_round()
-        assert api.bucket_runner.compiled_shapes() == m["bucket/shapes"] > 0
+        assert api.runner.compiled_shapes() == m["bucket/shapes"] > 0
 
 
 class TestWarmRestart:

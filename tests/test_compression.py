@@ -295,7 +295,7 @@ class TestCompressedFedAvg:
         assert any(
             float(np.max(np.abs(r))) > 0
             for c in range(6)
-            for r in jax.tree.leaves(comp._ef_store.peek(c)))
+            for r in jax.tree.leaves(comp.runner.residual_store.peek(c)))
 
     def test_mesh_plus_compressor_rejected(self):
         from fedml_tpu.algorithms.fedavg import FedAvgAPI
@@ -506,13 +506,13 @@ class TestResidualStore:
         from fedml_tpu.algorithms.fedavg import client_sampling
         cohort0 = set(client_sampling(0, 8, 3))
         api.train_one_round()
-        before = {c: jax.tree.map(np.copy, api._ef_store.peek(c))
+        before = {c: jax.tree.map(np.copy, api.runner.residual_store.peek(c))
                   for c in range(8)}
         cohort1 = set(client_sampling(1, 8, 3))
         api.train_one_round()
         assert cohort0 != cohort1  # the regression needs a re-sample
         for c in range(8):
-            after = api._ef_store.peek(c)
+            after = api.runner.residual_store.peek(c)
             if c in cohort1:
                 continue
             for a, b in zip(jax.tree.leaves(before[c]),
@@ -521,7 +521,7 @@ class TestResidualStore:
         # and the sampled clients' residuals are live (EF engaged)
         assert any(float(np.max(np.abs(r))) > 0
                    for c in cohort1
-                   for r in jax.tree.leaves(api._ef_store.peek(c)))
+                   for r in jax.tree.leaves(api.runner.residual_store.peek(c)))
 
 
 class TestZeroCopyViews:

@@ -181,7 +181,7 @@ class TestWaveRunner:
         s_flat, _, info_flat = flat(_fresh(state), (), dd, js, rng)
 
         wr = WaveRunner(spec, cfg, client_chunk=chunk)
-        s_wave, _, info_wave = wr.run_round(
+        s_wave, _, info_wave = wr.run_schedule(
             _fresh(state), (), dd, list(range(len(sizes))), sched, rng)
 
         for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_wave)):
@@ -218,7 +218,7 @@ class TestWaveRunner:
         js = {k: jnp.asarray(v) for k, v in sched.items()}
         s_flat, _, _ = flat(_fresh(state), (), dd, js, rng)
         wr = WaveRunner(spec, cfg, payload_fn, server_fn, client_chunk=2)
-        s_wave, _, _ = wr.run_round(
+        s_wave, _, _ = wr.run_schedule(
             _fresh(state), (), dd, list(range(len(sizes))), sched, rng)
         for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_wave)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -239,7 +239,7 @@ class TestWaveRunner:
         s_flat, _, info_flat = flat(_fresh(state), (), dd, js, rng)
 
         lr_ = LaneRunner(spec, cfg, n_lanes=n_lanes)
-        s_lane, _, info_lane = lr_.run_round(
+        s_lane, _, info_lane = lr_.run_schedule(
             _fresh(state), (), dd, list(range(len(sizes))), sched, rng)
 
         for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_lane)):
@@ -276,7 +276,7 @@ class TestWaveRunner:
         js = {k: jnp.asarray(v) for k, v in sched.items()}
         s_flat, _, _ = flat(_fresh(state), (), dd, js, rng)
         lr_ = LaneRunner(spec, cfg, payload_fn, server_fn, n_lanes=2)
-        s_lane, _, _ = lr_.run_round(
+        s_lane, _, _ = lr_.run_schedule(
             _fresh(state), (), dd, list(range(len(sizes))), sched, rng)
         for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_lane)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -316,7 +316,7 @@ class TestWaveRunner:
         placed = global_cohort(mesh, {"x": np.asarray(dd["x"]),
                                       "y": np.asarray(dd["y"])})
         slr = ShardedLaneRunner(spec, cfg, mesh, n_lanes=2)
-        s_sh, _, info_sh = slr.run_round(
+        s_sh, _, info_sh = slr.run_schedule(
             _fresh(state), (), placed, list(range(len(sizes))), sched, rng)
 
         for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_sh)):
@@ -361,7 +361,7 @@ class TestWaveRunner:
                                       "y": np.asarray(dd["y"])})
         slr = ShardedLaneRunner(spec, cfg, mesh, payload_fn, server_fn,
                                 n_lanes=2)
-        s_sh, _, info = slr.run_round(_fresh(state), (), placed, cohort,
+        s_sh, _, info = slr.run_schedule(_fresh(state), (), placed, cohort,
                                       sched, rng)
         assert float(np.asarray(info["metrics"]["count"])) == sum(ns)
         for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_sh)):
@@ -377,7 +377,7 @@ class TestWaveRunner:
         sched = pack_schedule(ns, 8, epochs=1,
                               rng=np.random.default_rng(5))
         wr = WaveRunner(spec, cfg, client_chunk=2)
-        s_wave, _, info = wr.run_round(state, (), dd, cohort, sched,
+        s_wave, _, info = wr.run_schedule(state, (), dd, cohort, sched,
                                        jax.random.PRNGKey(9))
         assert float(np.asarray(info["metrics"]["count"])) == sum(ns)
         for leaf in jax.tree.leaves(s_wave):
@@ -545,7 +545,7 @@ class TestFedAvgAPI:
                          client_chunk=2, device_resident="auto")
             api = FedAvgAPI(dataset, spec, args, mesh=mesh)
             if mode == 2:
-                assert api.sharded_lane_runner is not None
+                assert api.runner.mode == "sharded-lanes"
             api.train_one_round()
             api.train_one_round()
             return api.global_state
@@ -564,7 +564,7 @@ class TestFedAvgAPI:
                      frequency_of_the_test=100, wave_mode=2, client_chunk=3,
                      device_resident="auto")
         api = FedAvgAPI(dataset, spec, args)
-        assert api.device_data is not None
+        assert api.runner.mode == "lanes"
         first = api.train_one_round()
         for _ in range(3):
             last = api.train_one_round()
@@ -618,7 +618,7 @@ class TestFedAvgAPI:
             FedAvgAPI(dataset, spec, lanes)
         waves = _args(client_num_per_round=8, wave_mode=1, client_chunk=2,
                       device_resident="auto", device_data_cap_gb=1e-6)
-        assert FedAvgAPI(dataset, spec, waves).device_data is None
+        assert FedAvgAPI(dataset, spec, waves).runner.mode == "packed"
 
     @pytest.mark.parametrize("bypass, match", [
         (dict(device_resident="0"), "--device_resident 0"),

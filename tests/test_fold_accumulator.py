@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
 import pytest
+from stream_helpers import stream_round
 
 from fedml_tpu import models
 from fedml_tpu.algorithms.specs import make_classification_spec
@@ -204,8 +205,8 @@ def _round(runner, gs, r, dim=6, aggregator=None):
     tracer = Tracer()
     prev = set_tracer(tracer)
     try:
-        gs, _, info = runner.run_round(
-            jax.tree.map(jnp.copy, gs), (), _datasets(dim, seed=r),
+        gs, _, info = stream_round(
+            runner, jax.tree.map(jnp.copy, gs), (), _datasets(dim, seed=r),
             jax.random.PRNGKey(r), data_rng=np.random.default_rng(r),
             aggregator=aggregator)
     finally:

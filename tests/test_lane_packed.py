@@ -57,73 +57,7 @@ def test_merge_unmerge_roundtrip():
         np.asarray(lane_unmerge(lane_merge(x), 3)), np.asarray(x))
 
 
-class TestPallasGroupedConvDw:
-    """The Pallas grouped-conv dW kernel (ops/pallas_grouped_conv.py):
-    interpret-mode numerics gate vs the XLA reference lowering -- the
-    CPU half of the check; chip_smoke.py Leg B runs the compiled kernel
-    against XLA's dW at the flagship's stage shapes."""
-
-    @pytest.mark.parametrize("s,p,k", [(1, 1, 3), (1, 0, 3), (1, 2, 5),
-                                       (2, 1, 3), (2, 0, 1)])
-    def test_grads_match_xla_reference(self, s, p, k):
-        L, B, H, ci, co = 4, 3, 8, 5, 7
-        key = jax.random.PRNGKey(0)
-        x = jax.random.normal(key, (L, B, H, H, ci), jnp.float32)
-        w = jax.random.normal(jax.random.fold_in(key, 1),
-                              (L, k, k, ci, co), jnp.float32)
-        xm = lane_merge(x)
-
-        def loss(strategy):
-            def f(xm_, w_):
-                y = lane_conv(xm_, w_, L, strides=(s, s),
-                              padding=((p, p), (p, p)), strategy=strategy)
-                return jnp.sum(jnp.sin(y))
-            return f
-
-        fwd_ref = lane_conv(xm, w, L, strides=(s, s),
-                            padding=((p, p), (p, p)), strategy="bgc")
-        fwd_got = lane_conv(xm, w, L, strides=(s, s),
-                            padding=((p, p), (p, p)), strategy="pallas")
-        # the forward IS the bgc conv (same XLA program): bitwise
-        np.testing.assert_array_equal(np.asarray(fwd_got),
-                                      np.asarray(fwd_ref))
-        dref = jax.jit(jax.grad(loss("bgc"), argnums=(0, 1)))(xm, w)
-        dgot = jax.jit(jax.grad(loss("pallas"), argnums=(0, 1)))(xm, w)
-        # dX keeps XLA's transpose conv: bitwise. dW: fp32-accumulated
-        # both sides, reassociation-level tolerance (strided convs fall
-        # back to XLA's dW and stay bitwise).
-        np.testing.assert_array_equal(np.asarray(dgot[0]),
-                                      np.asarray(dref[0]))
-        if s != 1:
-            np.testing.assert_array_equal(np.asarray(dgot[1]),
-                                          np.asarray(dref[1]))
-        else:
-            np.testing.assert_allclose(np.asarray(dgot[1]),
-                                       np.asarray(dref[1]),
-                                       atol=1e-4, rtol=1e-5)
-
-    def test_kernel_direct_vs_einsum(self):
-        """grouped_conv_dw against the literal dW contraction."""
-        from fedml_tpu.ops.pallas_grouped_conv import grouped_conv_dw
-
-        L, B, H, ci, co, k, p = 2, 2, 6, 3, 4, 3, 1
-        key = jax.random.PRNGKey(7)
-        x = jax.random.normal(key, (L, B, H, H, ci), jnp.float32)
-        dy = jax.random.normal(jax.random.fold_in(key, 1),
-                               (L, B, H, H, co), jnp.float32)
-        got = grouped_conv_dw(x, dy, k, k, ((p, p), (p, p)))
-        xp = jnp.pad(x, ((0, 0), (0, 0), (p, p), (p, p), (0, 0)))
-        ref = np.zeros((L, k, k, ci, co), np.float32)
-        for dh in range(k):
-            for dw in range(k):
-                win = xp[:, :, dh:dh + H, dw:dw + H, :]
-                ref[:, dh, dw] = np.asarray(
-                    jnp.einsum("lbhwi,lbhwo->lio", win, dy))
-        np.testing.assert_allclose(np.asarray(got), ref, atol=1e-4,
-                                   rtol=1e-5)
-
-
-@pytest.mark.parametrize("lowering", ["blockdiag", "bgc", "auto", "pallas"])
+@pytest.mark.parametrize("lowering", ["blockdiag", "bgc", "auto"])
 @pytest.mark.parametrize("train", [False, True])
 def test_packed_apply_matches_vmap(train, lowering):
     L, B, H = 4, 8, 16
@@ -147,7 +81,7 @@ def test_packed_apply_matches_vmap(train, lowering):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
-@pytest.mark.parametrize("lowering", ["blockdiag", "bgc", "auto", "pallas"])
+@pytest.mark.parametrize("lowering", ["blockdiag", "bgc", "auto"])
 def test_packed_grads_match_vmap(lowering):
     import optax
 
@@ -273,7 +207,7 @@ def _run_fedavg(wave_mode, rounds=2):
         device_data_cap_gb=4.0, device_dtype=None)
     api = FedAvgAPI(dataset, spec, args)
     if wave_mode == 3:
-        assert api.packed_lane_runner is not None, (
+        assert api.runner.mode == "mxu-lanes", (
             "CifarResNet spec must provide the packed lane path")
     metrics = [api.train_one_round() for _ in range(rounds)]
     return api.global_state, metrics
@@ -326,7 +260,7 @@ def test_sharded_packed_lanes_equal_flat():
     mesh = make_client_mesh(8)
     placed = global_cohort(mesh, {"x": stacked["x"], "y": stacked["y"]})
     slr = ShardedLaneRunner(spec, cfg, mesh, n_lanes=2, packed=True)
-    s_sh, _, _ = slr.run_round(
+    s_sh, _, _ = slr.run_schedule(
         fresh(state), (), placed, list(range(len(sizes))), sched, rng)
     for a, b in zip(jax.tree.leaves(s_flat), jax.tree.leaves(s_sh)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -495,7 +495,7 @@ class TestCostModel:
 
         assert m_on["bucket/executed_flops"] > m_on["bucket/true_flops"] > 0
         assert 0.0 <= m_on["bucket/flops_waste_frac"] < 1.0
-        info = api_on._last_bucket_info["bucket"]
+        info = api_on._last_info["bucket"]
         assert info["flops_source"] == "xla"
         used = [b for b in info["per_bucket"] if not b["skipped"]]
         assert used and all("flops_per_step" in b and
@@ -506,7 +506,7 @@ class TestCostModel:
                             info["executed_flops"], rel_tol=1e-9)
         # the AOT probes never polluted the dispatch cache: compiled
         # programs still == bucket shapes (the ci.sh massive-gate anchor)
-        assert api_on.bucket_runner.compiled_shapes() == m_on["bucket/shapes"]
+        assert api_on.runner.compiled_shapes() == m_on["bucket/shapes"]
         # catalog rode the armed CostModel
         rec = cm.record()
         assert rec["cost/programs"] == len(used)

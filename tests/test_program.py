@@ -292,7 +292,7 @@ class TestSimConsumer:
         assert comp.program.codec.name == "qsgd"
         asyn = FedAvgAPI(ds, spec, self._args(async_agg=1, buffer_k=2))
         assert asyn.program.is_async
-        assert asyn.async_agg.policy is asyn.program.aggregation
+        assert asyn.runner.aggregator.policy is asyn.program.aggregation
 
     @pytest.mark.parametrize("codec", ["none", "topk:0.25"])
     def test_recompiling_the_program_is_bitwise_reproducible(self, codec):

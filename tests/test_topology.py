@@ -230,15 +230,40 @@ class TestTreeFaults:
         assert flushes and all(r["async/flush_clients"] == 1
                                for r in flushes)
 
-    def test_supervised_respawn_rejoins_same_slot(self, tmp_path):
+    def test_supervised_respawn_rejoins_same_slot(self, tmp_path,
+                                                  monkeypatch):
         # with supervision ON the dead edge's argv is respawned, the
         # fresh process re-dials the same rank, and the coordinator's
         # rejoin path readmits it -- the run completes with the full
-        # tree again. The leaf jitter keeps rounds slower than the
-        # 0.5s supervision poll, so the respawn happens mid-run
-        # instead of after the surviving edge races every update
+        # tree again. A fresh process needs seconds to come up and the
+        # surviving edge none to finish every update alone, so no clock
+        # decides this race: the coordinator sets the reports it gets
+        # aside until it has seen the rejoin, and handles them then (an
+        # edge reports once a sync, so the survivor waits with it)
+        from fedml_tpu.resilience.async_agg import \
+            AsyncBufferedFedAvgServer as Server
+        held = []
+        on_report, on_join = Server._on_report, Server._on_peer_join
+
+        def hold_until_rejoined(self, msg):
+            if self.counters["clients_rejoined"]:
+                on_report(self, msg)
+            else:
+                held.append(msg)
+
+        def rejoin_then_release(self, msg):
+            on_join(self, msg)
+            while held and self.counters["clients_rejoined"]:
+                on_report(self, held.pop(0))
+
+        monkeypatch.setattr(Server, "_on_report", hold_until_rejoined)
+        monkeypatch.setattr(Server, "_on_peer_join", rejoin_then_release)
+        # a run of reports in one chunk: one by one, through the above
+        monkeypatch.setattr(
+            Server, "_on_report_batch",
+            lambda self, msgs: [self._on_report(m) for m in msgs])
         spec = TreeSpec(fanout=(2,), leaves_per_edge=2, total_updates=3,
-                        jitter_s=1.0, flush_deadline_s=8.0)
+                        flush_deadline_s=8.0)
         res = run_tree(spec, str(tmp_path), init_params=INIT,
                        supervise=True, join_timeout=240,
                        on_spawned=lambda ch: ch["tier1-edge1"].proc
@@ -248,6 +273,7 @@ class TestTreeFaults:
         assert srv.agg.version == 3
         assert res["respawned"] >= 1
         assert srv.counters["clients_rejoined"] >= 1
+        assert not held
         assert res["zombies"] == 0
 
 
