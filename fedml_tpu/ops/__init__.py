@@ -11,8 +11,9 @@ net-new long-context layer the TPU rebuild makes first-class:
 - :mod:`fedml_tpu.ops.ring_attention` -- the same computation with the
   sequence sharded over a mesh axis; K/V blocks rotate around the ring via
   ``ppermute`` over ICI while every shard keeps only its own Q.
-- :mod:`fedml_tpu.ops.pallas_attention` -- fused flash-attention forward as a
-  Pallas TPU kernel (VMEM-blocked, MXU matmuls), with a recompute backward.
+- :mod:`fedml_tpu.ops.pallas_attention` -- fused flash attention as three
+  Pallas TPU kernels (forward, dq, dk/dv: VMEM-blocked, MXU matmuls), their
+  tiles chosen from the shape by ``flash_schedule``.
 - :mod:`fedml_tpu.ops.grouped_matmul` -- the grouped matrix product over the
   experts a chip holds (rows sorted by expert, no drop), forward and backward
   (imported as a module: its function has the module's name).

@@ -1,14 +1,35 @@
 """Flash attention (forward + backward) as fused Pallas TPU kernels.
 
-The hot op for long-context transformer workloads. Forward: one kernel
-instance computes a ``[BLOCK_Q, D]`` output tile by streaming KV blocks
-through VMEM with the online-softmax recurrence -- scores never touch HBM --
-and emits the per-row logsumexp. Backward: two kernels re-form the
-probabilities from the saved logsumexp (no second online pass needed) and
-accumulate ``dq`` (query-tile outer loop) and ``dk``/``dv`` (KV-tile outer
-loop), the standard flash-attention backward decomposition. All matmuls hit
-the MXU in the input dtype (bf16-friendly) with fp32 accumulation
-(``preferred_element_type``); softmax state lives in fp32 VMEM scratch.
+The hot op for long-context transformer workloads. Three kernels, the
+standard flash decomposition: ``flash_fwd`` streams keys and values past a
+tile of queries with the online-softmax recurrence (scores never touch
+HBM) and emits the per-row logsumexp; ``flash_bwd_dq`` and
+``flash_bwd_dkv`` re-form the probabilities from the saved logsumexp and
+accumulate ``dq`` (a tile of queries, keys streamed) and ``dk``/``dv`` (a
+tile of keys, queries streamed). All matmuls hit the MXU in the input
+dtype (bf16-friendly) with fp32 accumulation (``preferred_element_type``);
+softmax state, ``exp``, logsumexp and delta are fp32.
+
+**What one grid step holds, and why.** A Pallas grid step has a fixed
+price (block copies issued and awaited, semaphores, the ``pl.when`` tests)
+of 0.4-0.5 us on a v5e, whatever the step computes; a 128 x 128 score tile
+is 0.04-0.15 us of MXU time, so a 16 x 16 grid of such tiles spent
+four to nine tenths of its time on steps and not on arithmetic (PERF.md,
+PR 30). So a step owns a tile of hundreds of rows (:class:`Tile` ``rows``)
+and a MAJOR block of the streamed sequence (``major``: at T 2048 all of
+it, so the grid has no inner axis left and K/V of a head are fetched
+once), and loops over MINOR blocks of it (``minor``) inside the body. The
+loop's bounds come from the causal band: blocks above it are never
+visited, blocks wholly under it run a body with no mask, and only the
+blocks the diagonal crosses (or that hold padded keys) build the iotas.
+Where the major block does not cover the sequence the grid keeps its
+inner axis; a step the band does not reach runs no pass of the loop AND
+names the block already resident (clamped index maps), so nothing is
+fetched for it. :func:`flash_schedule` chooses the tiles from the shape.
+
+``flash_bwd_dkv`` works on the TRANSPOSED score tile (keys on sublanes,
+queries on lanes): ``dv += p^T dO`` and ``dk += ds^T q`` are then plain
+products, no score-sized transpose, and logsumexp/delta are rows.
 
 ``interpret=True`` is used on the CPU backend only, so the same code
 paths test on CPU against the materializing oracle (``tests/test_ops.py``).
@@ -17,6 +38,7 @@ paths test on CPU against the materializing oracle (``tests/test_ops.py``).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -25,13 +47,43 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fedml_tpu.ops.attention import NEG_INF
 
-# lse/delta ride as [T, LANES] lane-replicated fp32 (the fp32 VMEM tile is
-# (8, 128); a [T, 1] operand would fight the layout) -- column 0 is the
-# value. Lane replication in HBM costs 128x on a per-row scalar; it is the
-# same layout the upstream TPU flash kernel uses for its l/m outputs
-# (jax/experimental/pallas/ops/tpu/flash_attention.py: NUM_LANES-wide l/m),
-# trading HBM for never relayouting sublanes<->lanes inside the kernel.
+# Per-row softmax state inside the kernels (running max, running sum, the
+# logsumexp and delta columns of the dq kernel) is [rows, LANES]
+# lane-replicated fp32: the fp32 VMEM tile is (8, 128), and a replicated
+# column meets a [rows, n * 128] score tile by ``jnp.tile``, which moves
+# nothing (the layout the upstream TPU flash kernel keeps its l/m in).
+# In HBM logsumexp and delta are compact [B, H, 1, T] ROWS: a kernel turns
+# a row into a replicated column (or back) with one 32-bit transpose a
+# query tile, so no 128-fold array is written or re-read (PR 30).
 _LANES = 128
+_SUBLANES = 16      # rows of a packed bf16 tile
+
+#: what a kernel's blocks (twice: the pipeline's double buffers),
+#: accumulators and live score tiles may take together: inside the 16 MiB
+#: of VMEM a v5e scopes to a kernel by default (of 128 MiB), so no
+#: ``vmem_limit_bytes`` is asked for. The cells' shapes take 7.7-9.5 MiB
+#: by :func:`_vmem_bytes`; the probe's sweep never wanted more.
+_VMEM_BUDGET = 12 * 2 ** 20
+#: rows of a tile and of a minor block the chip preferred in every
+#: kernel at both widths (``scripts/flash_probe.py``; PERF.md, PR 30)
+_ROWS = 512
+
+
+class Tile(NamedTuple):
+    """One kernel's tile schedule. ``rows``: rows of the tile a grid row
+    owns (queries in ``flash_fwd`` / ``flash_bwd_dq``, keys in
+    ``flash_bwd_dkv``). ``major``: rows of the OTHER sequence resident in
+    VMEM in one grid step. ``minor``: rows of it one pass of the body's
+    loop takes (``major`` is a multiple of it)."""
+    rows: int
+    major: int
+    minor: int
+
+
+class Schedule(NamedTuple):
+    fwd: Tile
+    dq: Tile
+    dkv: Tile
 
 
 def _use_interpret() -> bool:
@@ -41,257 +93,429 @@ def _use_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _mask(s, *, qi, kj, block_q, block_k, seq_len, causal):
-    """NEG_INF-mask invalid scores: zero-padded keys always, upper triangle
-    when causal. Static no-op when nothing can be invalid."""
-    ragged = seq_len % block_k != 0
-    if not (causal or ragged):
-        return s
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    valid = kpos < seq_len
+def _up(n, m):
+    return -(-n // m) * m
+
+
+def _uniform(block_q, block_k):
+    """Explicit integers: one ``block_q x block_k`` score tile a grid
+    step in all three kernels, no inner loop (what they always meant)."""
+    return Schedule(Tile(block_q, block_k, block_k),
+                    Tile(block_q, block_k, block_k),
+                    Tile(block_k, block_q, block_q))
+
+
+def _clip(tile, own, other):
+    """A tile never exceeds the (padded) sequence, but stays on the TPU
+    tiling when a short sequence clips it: ``rows`` are sublanes of every
+    block (16 for packed bf16), ``minor`` rows become the LANES of the
+    score tile (128). ``T=80`` gives rows 80 over keys of 128; the caller
+    zero-pads to the blocks and the in-kernel key mask covers the pad."""
+    minor = min(tile.minor, _up(other, _LANES))
+    major = min(_up(tile.major, minor), _up(other, minor))
+    return Tile(min(tile.rows, _up(own, _SUBLANES)), major, minor)
+
+
+def _block_sizes(schedule, Tq, Tk):
+    return Schedule(_clip(schedule.fwd, Tq, Tk), _clip(schedule.dq, Tq, Tk),
+                    _clip(schedule.dkv, Tk, Tq))
+
+
+def _vmem_bytes(kernel, tile, Dqk, Dv, itemsize):
+    """What ``kernel`` keeps in VMEM under ``tile``: its blocks twice (the
+    pipeline's double buffers), its fp32 accumulators and replicated
+    columns, and the fp32 score-sized tiles live in one pass of the loop."""
+    own, streamed = tile.rows, tile.major
+    if kernel == "fwd":     # q, o | k, v | acc, m, l | s, p
+        blocks = own * (Dqk + Dv) + streamed * (Dqk + Dv)
+        state = own * (Dv + 2 * _LANES)
+        live = 3
+    elif kernel == "dq":    # q, dO, dq | k, v | acc, lse, delta | s, p, dov
+        blocks = own * (2 * Dqk + Dv) + streamed * (Dqk + Dv)
+        state = own * (Dqk + 2 * _LANES)
+        live = 4
+    else:                   # k, v, dk, dv | q, dO | dk_acc, dv_acc | ...
+        blocks = own * 2 * (Dqk + Dv) + streamed * (Dqk + Dv)
+        state = own * (Dqk + Dv)
+        live = 4
+    return 2 * blocks * itemsize + 4 * state \
+        + 4 * live * tile.rows * tile.minor
+
+
+def _steps(schedule, Tq, Tk):
+    """Grid steps one (batch, head) takes in each kernel."""
+    f, q, kv = schedule
+    return (pl.cdiv(Tq, f.rows) * pl.cdiv(Tk, f.major),
+            pl.cdiv(Tq, q.rows) * pl.cdiv(Tk, q.major),
+            pl.cdiv(Tk, kv.rows) * pl.cdiv(Tq, kv.major))
+
+
+def flash_schedule(Tq, Tk, Dqk, Dv, dtype):
+    """The tiles ``flash_attention`` runs a call of this shape with, and
+    the grid steps a (batch, head) then takes in ``(flash_fwd,
+    flash_bwd_dq, flash_bwd_dkv)``: a function of what the call can
+    observe and nothing else. ``Dqk`` is the score width as the kernels
+    see it (192 arrives as 256). The preferred tiles are the chip's
+    answer to ``scripts/flash_probe.py`` (PERF.md, PR 30); the streamed
+    sequence stays whole in VMEM while :data:`_VMEM_BUDGET` allows and is
+    halved (in whole minor blocks) while it does not."""
+    itemsize = jnp.dtype(dtype).itemsize
+    want = Schedule(fwd=Tile(_ROWS, Tk, _ROWS), dq=Tile(_ROWS, Tk, _ROWS),
+                    dkv=Tile(_ROWS, Tq, _ROWS))
+    tiles = []
+    for kernel, tile in zip(Schedule._fields, _block_sizes(want, Tq, Tk)):
+        while tile.major > tile.minor and _vmem_bytes(
+                kernel, tile, Dqk, Dv, itemsize) > _VMEM_BUDGET:
+            tile = tile._replace(
+                major=_up(tile.major // 2, tile.minor))
+        tiles.append(tile)
+    schedule = Schedule(*tiles)
+    return schedule, _steps(schedule, Tq, Tk)
+
+
+# -- what the three bodies share ----------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b^T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _lanes(col, n):
+    """A lane-replicated ``[rows, LANES]`` column against ``n`` lanes."""
+    if n % _LANES == 0:
+        return jnp.tile(col, (1, n // _LANES))
+    return jnp.broadcast_to(col[:, :1], (col.shape[0], n))
+
+
+def _col(row):
+    """``[1, rows]`` -> the lane-replicated column ``[rows, LANES]``."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _mask(s, *, q0, k0, k_len, causal, keys_on_rows=False):
+    """NEG_INF-mask invalid scores: zero-padded keys always, upper
+    triangle when causal. Only the bodies of tiles the diagonal crosses
+    or that hold padded keys call this."""
+    qa, ka = (1, 0) if keys_on_rows else (0, 1)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, ka)
+    valid = kpos < k_len
     if causal:
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, qa)
         valid = valid & (kpos <= qpos)
     return jnp.where(valid, s, NEG_INF)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, block_q, block_k, seq_len):
-    qi = pl.program_id(0)   # query tile
-    kj = pl.program_id(1)   # kv tile (innermost grid dim)
+def _keys_needed(i, tile, k_len, causal):
+    """Minor key blocks, from the first, that query tile ``i`` reaches:
+    those that hold real keys, up to the one its last row's diagonal is in."""
+    needed = pl.cdiv(k_len, tile.minor)
+    if causal:
+        needed = jnp.minimum(needed,
+                             (i * tile.rows + tile.rows - 1) // tile.minor + 1)
+    return needed
 
-    @pl.when(kj == 0)
+
+def _band(i, jm, tile, *, own_len, other_len, causal, keys_own):
+    """Which minor blocks of the streamed sequence the body visits in grid
+    step ``(i, jm)``: ``(lo, split, hi)`` in minor blocks from the start
+    of the sequence. ``keys_own`` False (``fwd``, ``dq``): tile ``i`` of
+    queries against key blocks; ``[lo, split)`` lie wholly under the
+    diagonal and inside the keys (no mask), ``[split, hi)`` are crossed by
+    it or hold padded keys. ``keys_own`` True (``dkv``): tile ``i`` of
+    keys against query blocks; ``[lo, split)`` are crossed by the
+    diagonal (masked), ``[split, hi)`` lie wholly under it -- unless the
+    key tile holds padded keys, then every block is masked."""
+    rows, major, minor = tile
+    per_step = major // minor
+    first, last = jm * per_step, (jm + 1) * per_step
+    valid = pl.cdiv(other_len, minor)       # blocks that hold real rows
+    if not keys_own:
+        full = other_len // minor
+        if causal:
+            full = jnp.minimum(full, (i * rows + 1) // minor)
+        return (first, jnp.clip(full, first, last),
+                jnp.clip(_keys_needed(i, tile, other_len, causal),
+                         first, last))
+    start = (i * rows) // minor if causal else 0
+    under = (i * rows + rows + minor - 2) // minor if causal else 0
+    under = jnp.where((i + 1) * rows > own_len, valid, under)  # ragged keys
+    hi = jnp.minimum(valid, last)
+    return (jnp.clip(start, first, hi), jnp.clip(under, first, hi), hi)
+
+
+def _loop(lo, hi, body):
+    """``body(block)`` for ``block`` in ``[lo, hi)``, bounds traced (the
+    causal band's): one pass a minor block, none where ``hi <= lo``."""
+    jax.lax.fori_loop(lo, hi, lambda b, c: (body(b), c)[1], 0)
+
+
+def _local(block, jm, tile):
+    """Minor block ``block``'s place inside major block ``jm``."""
+    return block - jm * (tile.major // tile.minor)
+
+
+def _streamed(ref, jm, block, tile):
+    """Rows of minor block ``block`` in a ref that holds major block ``jm``."""
+    start = _local(block, jm, tile) * tile.minor
+    return ref[pl.ds(pl.multiple_of(start, tile.minor), tile.minor), :]
+
+
+# -- forward ------------------------------------------------------------------
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, scale, causal, tile, q_len, k_len):
+    qi = pl.program_id(0)   # query tile
+    jm = pl.program_id(1)   # major key block (innermost grid dim)
+
+    @pl.when(jm == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _body():
-        q = q_ref[:]                      # [block_q, D]
-        k = k_ref[:]                      # [block_k, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        s = _mask(s, qi=qi, kj=kj, block_q=block_q, block_k=block_k,
-                  seq_len=seq_len, causal=causal)
-
-        m_prev = m_ref[:, :1]             # [bq, 1]
-        blk_max = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, blk_max)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+    def step(block, masked):
+        k, v = (_streamed(r, jm, block, tile) for r in (k_ref, v_ref))
+        s = _dot(q_ref[:], k, _NT) * scale             # [rows, minor]
+        if masked:
+            s = _mask(s, q0=qi * tile.rows, k0=block * tile.minor,
+                      k_len=k_len, causal=causal)
+        m_prev = m_ref[:]                              # [rows, LANES]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, tile.minor))
+        if masked:
+            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bq, D]
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_keep = jnp.where(m_new <= NEG_INF / 2, m_prev, m_new)
-        m_ref[:] = jnp.broadcast_to(m_keep, m_ref.shape)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * _lanes(corr, acc_ref.shape[1]) \
+            + _dot(p.astype(v.dtype), v, _NN)          # [rows, Dv]
+        m_ref[:] = m_new
 
-    if causal:
-        # skip KV tiles strictly above the diagonal band
-        pl.when(kj * block_k <= qi * block_q + (block_q - 1))(_body)
-    else:
-        _body()
+    lo, split, hi = _band(qi, jm, tile, own_len=q_len, other_len=k_len,
+                          causal=causal, keys_own=False)
+    _loop(lo, split, functools.partial(step, masked=False))
+    _loop(split, hi, functools.partial(step, masked=True))
 
-    @pl.when(kj == pl.num_programs(1) - 1)
+    @pl.when(jm == pl.num_programs(1) - 1)
     def _finalize():
-        l = l_ref[:, :1]
-        o_ref[:] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        l = l_ref[:]
+        o_ref[:] = (acc_ref[:] / _lanes(jnp.maximum(l, 1e-30),
+                                        acc_ref.shape[1])).astype(o_ref.dtype)
         # fully-masked rows (l == 0): any finite lse works -- the backward
         # re-masks scores to NEG_INF, so exp(s - lse) is 0 regardless
-        lse = jnp.where(l > 0, m_ref[:, :1] + jnp.log(jnp.maximum(l, 1e-30)),
+        lse = jnp.where(l > 0, m_ref[:] + jnp.log(jnp.maximum(l, 1e-30)),
                         0.0)
-        lse_ref[:] = jnp.broadcast_to(lse, lse_ref.shape)
+        lse_ref[:] = lse.T[:1]                         # the column as a row
 
 
-def _fwd_one_head(q, k, v, *, scale, causal, block_q, block_k, k_len,
-                  interpret):
+def _resident_keys(tile, k_len, causal):
+    """Index map of K and V in the query-tile grids: a step above the band
+    names the last major block the band of its tile reaches, which is the
+    block already resident, so the pipeline copies nothing for it."""
+    per_step = tile.major // tile.minor
+    return lambda i, j: (jnp.minimum(
+        j, (_keys_needed(i, tile, k_len, causal) - 1) // per_step), 0)
+
+
+def _fwd_one_head(q, k, v, *, scale, causal, tile, q_len, k_len, interpret):
     Tq, D = q.shape          # the score width (q and k)
     Tk, Dv = v.shape         # the value width (v and o)
-    grid = (pl.cdiv(Tq, block_q), pl.cdiv(Tk, block_k))
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, seq_len=k_len)
+    rows, major, _ = tile
+    kv = _resident_keys(tile, k_len, causal)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          tile=tile, q_len=q_len, k_len=k_len),
+        grid=(Tq // rows, Tk // major),
         in_specs=[
-            pl.BlockSpec((block_q, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_k, D), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_k, Dv), lambda i, j: (j, 0)),
+            pl.BlockSpec((rows, D), lambda i, j: (i, 0)),
+            pl.BlockSpec((major, D), kv),
+            pl.BlockSpec((major, Dv), kv),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, Dv), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, _LANES), lambda i, j: (i, 0)),
+            pl.BlockSpec((rows, Dv), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, rows), lambda i, j: (0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Tq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((Tq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, Tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((rows, Dv), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
 
 
-def _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *, qi, kj,
-                  scale, causal, block_q, block_k, seq_len):
-    """Shared backward re-formation: rebuild ``p = exp(s - lse)`` from the
-    saved logsumexp and form ``ds = p * (dO v^T - delta)`` -- the one block
-    both backward kernels must compute identically."""
-    s = jax.lax.dot_general(
-        q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    s = _mask(s, qi=qi, kj=kj, block_q=block_q, block_k=block_k,
-              seq_len=seq_len, causal=causal)
-    p = jnp.exp(s - lse_ref[:, :1])
-    p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-    dov = jax.lax.dot_general(
-        do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # [bq, bk]
-    ds = p * (dov - dl_ref[:, :1])
-    return p, ds
-
+# -- backward -----------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k, seq_len):
-    """Query-tile outer loop: accumulate ``dq = sum_k ds @ k * scale``."""
+               acc_ref, lse_col, dl_col, *, scale, causal, tile, q_len,
+               k_len):
+    """A tile of queries, keys streamed: ``dq = scale * sum_k ds @ k``
+    with ``p = exp(s - lse)`` re-formed from the saved logsumexp and
+    ``ds = p * (dO v^T - delta)``."""
     qi = pl.program_id(0)
-    kj = pl.program_id(1)
+    jm = pl.program_id(1)
 
-    @pl.when(kj == 0)
+    @pl.when(jm == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        lse_col[:] = _col(lse_ref[:])
+        dl_col[:] = _col(dl_ref[:])
 
-    def _body():
-        _, ds = _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                              qi=qi, kj=kj, scale=scale, causal=causal,
-                              block_q=block_q, block_k=block_k,
-                              seq_len=seq_len)
-        acc_ref[:] += scale * jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def step(block, masked):
+        k, v = (_streamed(r, jm, block, tile) for r in (k_ref, v_ref))
+        s = _dot(q_ref[:], k, _NT) * scale             # [rows, minor]
+        if masked:
+            s = _mask(s, q0=qi * tile.rows, k0=block * tile.minor,
+                      k_len=k_len, causal=causal)
+        p = jnp.exp(s - _lanes(lse_col[:], tile.minor))
+        if masked:
+            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+        ds = p * (_dot(do_ref[:], v, _NT) - _lanes(dl_col[:], tile.minor))
+        acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    if causal:
-        pl.when(kj * block_k <= qi * block_q + (block_q - 1))(_body)
-    else:
-        _body()
+    lo, split, hi = _band(qi, jm, tile, own_len=q_len, other_len=k_len,
+                          causal=causal, keys_own=False)
+    _loop(lo, split, functools.partial(step, masked=False))
+    _loop(split, hi, functools.partial(step, masked=True))
 
-    @pl.when(kj == pl.num_programs(1) - 1)
+    @pl.when(jm == pl.num_programs(1) - 1)
     def _finalize():
-        dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[:] = (scale * acc_ref[:]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
-                dv_ref, dk_acc, dv_acc, *, scale, causal, block_q, block_k,
-                seq_len):
-    """KV-tile outer loop: ``dv = sum_q p^T @ dO``, ``dk = sum_q ds^T @ q``."""
+                dv_ref, dk_acc, dv_acc, *, scale, causal, tile, q_len,
+                k_len):
+    """A tile of keys, queries streamed, on the transposed score tile
+    (keys on sublanes, queries on lanes): ``dv = sum_q p^T @ dO``,
+    ``dk = scale * sum_q ds^T @ q``; logsumexp and delta are rows."""
     kj = pl.program_id(0)
-    qi = pl.program_id(1)
+    im = pl.program_id(1)
 
-    @pl.when(qi == 0)
+    @pl.when(im == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _body():
-        p, ds = _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                              qi=qi, kj=kj, scale=scale, causal=causal,
-                              block_q=block_q, block_k=block_k,
-                              seq_len=seq_len)
-        # dv += p^T dO : contract over the q rows
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[:] += scale * jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def step(block, masked):
+        q, do = (_streamed(r, im, block, tile) for r in (q_ref, do_ref))
+        st = _dot(k_ref[:], q, _NT) * scale            # [rows, minor]
+        if masked:
+            st = _mask(st, q0=block * tile.minor, k0=kj * tile.rows,
+                       k_len=k_len, causal=causal, keys_on_rows=True)
+        pt = jnp.exp(st - lse_ref[_local(block, im, tile)])
+        if masked:
+            pt = jnp.where(st <= NEG_INF / 2, 0.0, pt)
+        dst = pt * (_dot(v_ref[:], do, _NT)
+                    - dl_ref[_local(block, im, tile)])
+        dv_acc[:] += _dot(pt.astype(do.dtype), do, _NN)
+        dk_acc[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    if causal:
-        # tiles entirely above the diagonal contribute nothing
-        pl.when(qi * block_q + (block_q - 1) >= kj * block_k)(_body)
-    else:
-        _body()
+    lo, split, hi = _band(kj, im, tile, own_len=k_len, other_len=q_len,
+                          causal=causal, keys_own=True)
+    _loop(lo, split, functools.partial(step, masked=True))
+    _loop(split, hi, functools.partial(step, masked=False))
 
-    @pl.when(qi == pl.num_programs(1) - 1)
+    @pl.when(im == pl.num_programs(1) - 1)
     def _finalize():
-        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[:] = (scale * dk_acc[:]).astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_one_head(q, k, v, do, lse, dl, *, scale, causal, block_q, block_k,
-                  k_len, interpret):
-    Tq, D = q.shape          # the score width (q, k, dq, dk)
-    Tk, Dv = v.shape         # the value width (v, dO, dv)
-    nq, nk = pl.cdiv(Tq, block_q), pl.cdiv(Tk, block_k)
-    q_spec = pl.BlockSpec((block_q, D), lambda i, j: (i, 0))
-    k_spec = pl.BlockSpec((block_k, D), lambda i, j: (j, 0))
-    v_spec = pl.BlockSpec((block_k, Dv), lambda i, j: (j, 0))
-    do_spec = pl.BlockSpec((block_q, Dv), lambda i, j: (i, 0))
-    r_spec = pl.BlockSpec((block_q, _LANES), lambda i, j: (i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=k_len),
-        grid=(nq, nk),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, r_spec, r_spec],
-        out_specs=pl.BlockSpec((block_q, D), lambda i, j: (i, 0)),
+def _dq_one_head(q, k, v, do, lse, dl, *, scale, causal, tile, q_len, k_len,
+                 interpret):
+    Tq, D = q.shape          # the score width (q, k, dq)
+    Tk, Dv = v.shape         # the value width (v, dO)
+    rows, major, _ = tile
+    kv = _resident_keys(tile, k_len, causal)
+    own = lambda width: pl.BlockSpec((rows, width), lambda i, j: (i, 0))
+    row = pl.BlockSpec((1, rows), lambda i, j: (0, i))
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal, tile=tile,
+                          q_len=q_len, k_len=k_len),
+        grid=(Tq // rows, Tk // major),
+        in_specs=[own(D), pl.BlockSpec((major, D), kv),
+                  pl.BlockSpec((major, Dv), kv), own(Dv), row, row],
+        out_specs=own(D),
         out_shape=jax.ShapeDtypeStruct((Tq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                        pltpu.VMEM((rows, _LANES), jnp.float32),
+                        pltpu.VMEM((rows, _LANES), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, dl)
-    # kv-outer grid: index maps see (kj, qi)
-    qk_spec = pl.BlockSpec((block_q, D), lambda j, i: (i, 0))
-    kk_spec = pl.BlockSpec((block_k, D), lambda j, i: (j, 0))
-    vk_spec = pl.BlockSpec((block_k, Dv), lambda j, i: (j, 0))
-    dok_spec = pl.BlockSpec((block_q, Dv), lambda j, i: (i, 0))
-    rk_spec = pl.BlockSpec((block_q, _LANES), lambda j, i: (i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=k_len),
-        grid=(nk, nq),
-        in_specs=[qk_spec, kk_spec, vk_spec, dok_spec, rk_spec, rk_spec],
-        out_specs=[pl.BlockSpec((block_k, D), lambda j, i: (j, 0)),
-                   pl.BlockSpec((block_k, Dv), lambda j, i: (j, 0))],
+
+
+def _dkv_one_head(q, k, v, do, lse, dl, *, scale, causal, tile, q_len, k_len,
+                  interpret):
+    Tq, D = q.shape          # the score width (q, k, dk)
+    Tk, Dv = v.shape         # the value width (v, dO, dv)
+    rows, major, minor = tile
+    per_step = major // minor
+    # kv-outer grid: index maps see (key tile, major query block); a step
+    # the band has not reached yet names the first block it will need
+    first = ((lambda j: (j * rows) // minor // per_step) if causal
+             else (lambda j: 0))
+    qs = lambda j, i: (jnp.minimum(jnp.maximum(i, first(j)),
+                                   Tq // major - 1), 0)
+    own = lambda width: pl.BlockSpec((rows, width), lambda j, i: (j, 0))
+    # logsumexp / delta: one [1, minor] row a minor block, picked by its
+    # leading index in the body
+    rowed = lambda x: x.reshape(Tq // minor, 1, minor)
+    row = pl.BlockSpec((per_step, 1, minor), lambda j, i: qs(j, i) + (0,))
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal, tile=tile,
+                          q_len=q_len, k_len=k_len),
+        grid=(Tk // rows, Tq // major),
+        in_specs=[pl.BlockSpec((major, D), qs), own(D), own(Dv),
+                  pl.BlockSpec((major, Dv), qs), row, row],
+        out_specs=[own(D), own(Dv)],
         out_shape=[jax.ShapeDtypeStruct((Tk, D), k.dtype),
                    jax.ShapeDtypeStruct((Tk, Dv), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, Dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                        pltpu.VMEM((rows, Dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, lse, dl)
-    return dq, dk, dv
+    )(q, k, v, do, rowed(lse), rowed(dl))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, schedule=None):
     """Fused attention: q, k ``[B, T, H, Dqk]``, v ``[B, T, H, Dv]`` ->
     ``[B, T, H, Dv]``.
 
     Forward and backward are Pallas kernels (per ``(batch, head)`` via a
-    double vmap -- each kernel grid covers query x kv tiles). Ragged
-    sequence lengths are padded here and masked in-kernel. The score width
-    ``Dqk`` may differ from the value width ``Dv`` (latent attention:
-    keys wider than values). On hardware ``Dv`` must fill 128-wide tiles;
-    a ``Dqk`` that does not is zero-padded to the next multiple of 128
-    here (exact: the extra columns add 0 to every score), and ``scale``
-    defaults to ``Dqk ** -0.5`` of the width given, never the padded one.
+    double vmap -- each kernel grid covers tiles of one sequence x major
+    blocks of the other). The tiles come from :func:`flash_schedule`
+    unless given: integers ``block_q`` / ``block_k`` mean one such score
+    tile a grid step in every kernel, a :class:`Schedule` means itself.
+    Ragged sequence lengths are padded here and masked in-kernel. The
+    score width ``Dqk`` may differ from the value width ``Dv`` (latent
+    attention: keys wider than values). On hardware ``Dv`` must fill
+    128-wide tiles; a ``Dqk`` that does not is zero-padded to the next
+    multiple of 128 here (exact: the extra columns add 0 to every score),
+    and ``scale`` defaults to ``Dqk ** -0.5`` of the width given, never
+    the padded one.
     """
-    return _fa_fwd(q, k, v, causal, scale, block_q, block_k)[0]
+    return _fa_fwd(q, k, v, causal, scale, block_q, block_k, schedule)[0]
 
 
-def _pad_t(x, pad):
-    return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
+def _pad_t(x, multiple, axis=1):
+    pad = (-x.shape[axis]) % multiple
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
 
 
 # [B, T, H, D] <-> [B, H, T, D]: self-inverse, used at every kernel boundary
@@ -334,66 +558,91 @@ def _pad_d(x, pad):
     return jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) if pad else x
 
 
-def _block_sizes(block_q, block_k, Tq, Tk):
-    """Blocks never exceed the sequence, but stay on the TPU tiling when a
-    short sequence clips them: q rows are the sublanes of every tile (16
-    for packed bf16), k rows become the LANES of the ``[bq, bk]`` score
-    tile (128). ``T=80`` gives ``(80, 128)``; the caller zero-pads K/V to
-    the block and the in-kernel key mask covers the pad."""
-    up = lambda n, m: -(-n // m) * m
-    return min(block_q, up(Tq, 16)), min(block_k, up(Tk, _LANES))
+def _schedule_of(block_q, block_k, schedule, Tq, Tk, Dqk, Dv, dtype):
+    if schedule is not None:
+        return _block_sizes(schedule, Tq, Tk)
+    if block_q is None and block_k is None:
+        return flash_schedule(Tq, Tk, Dqk, Dv, dtype)[0]
+    return _block_sizes(_uniform(block_q, block_k), Tq, Tk)
 
 
-def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
+# The kernels' launches with the pads and transposes around them are jitted
+# on their own: a model calls one attention shape in every layer of every
+# program of a round, and the kernels' bodies are the slowest thing in the
+# step to trace; jit's cache hands the later calls the first one's jaxpr
+# (set-up, not the window: the compiled program is the same, inlined).
+_STATIC = dict(static_argnames=("causal", "scale", "block_q", "block_k",
+                                "schedule", "interpret"))
+
+
+@functools.partial(jax.jit, **_STATIC)
+def _forward(q, k, v, *, causal, scale, block_q, block_k, schedule,
+             interpret):
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
     scale_ = scale if scale is not None else D ** -0.5
-    interpret = _use_interpret()
-    _require_hw_head_dim(Dv, interpret)
     pad_d = _score_pad(D, Dv, interpret)
-    bq, bk = _block_sizes(block_q, block_k, Tq, Tk)
-    qp = _swap_th(_pad_d(_pad_t(q, (-Tq) % bq), pad_d))
-    kp = _swap_th(_pad_d(_pad_t(k, (-Tk) % bk), pad_d))
-    vp = _swap_th(_pad_t(v, (-Tk) % bk))
+    tile = _schedule_of(block_q, block_k, schedule, Tq, Tk, D + pad_d, Dv,
+                        q.dtype).fwd
+    qp = _swap_th(_pad_d(_pad_t(q, tile.rows), pad_d))
+    kp = _swap_th(_pad_d(_pad_t(k, tile.major), pad_d))
+    vp = _swap_th(_pad_t(v, tile.major))
     fn = functools.partial(_fwd_one_head, scale=scale_, causal=causal,
-                           block_q=bq, block_k=bk, k_len=Tk,
+                           tile=tile, q_len=Tq, k_len=Tk,
                            interpret=interpret)
     out, lse = _double_vmap(fn)(qp, kp, vp)
-    out = _swap_th(out)[:, :Tq]                       # back to [B,T,H,D]
-    lse = jnp.transpose(lse[..., 0], (0, 2, 1))[:, :Tq]      # [B,T,H]
-    return out, (q, k, v, out, lse)
+    # back to [B,T,H,D]; the logsumexp stays [B,H,T]
+    return _swap_th(out)[:, :Tq], lse[:, :, 0, :Tq]
 
 
-def _fa_bwd(causal, scale, block_q, block_k, res, g):
-    q, k, v, out, lse = res
+@functools.partial(jax.jit, **_STATIC)
+def _backward(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
+              schedule, interpret):
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     scale_ = scale if scale is not None else D ** -0.5
-    interpret = _use_interpret()
-    pad_d = _score_pad(D, v.shape[-1], interpret)
-    bq, bk = _block_sizes(block_q, block_k, Tq, Tk)
-    pad_q, pad_k = (-Tq) % bq, (-Tk) % bk
+    pad_d = _score_pad(D, Dv, interpret)
+    tiles = _schedule_of(block_q, block_k, schedule, Tq, Tk, D + pad_d, Dv,
+                         q.dtype)
     # delta_i = dO_i . O_i (the -sum_j ds_ij term of the softmax backward)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    rep = lambda x: jnp.broadcast_to(  # [B, T, H] -> lane-replicated
-        x[..., None], x.shape + (_LANES,))
-    qp = _swap_th(_pad_d(_pad_t(q, pad_q), pad_d))
-    dop = _swap_th(_pad_t(g.astype(q.dtype), pad_q))
-    kp = _swap_th(_pad_d(_pad_t(k, pad_k), pad_d))
-    vp = _swap_th(_pad_t(v, pad_k))
+    delta = jnp.transpose(jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1), (0, 2, 1))
+    qh, kh = _swap_th(_pad_d(q, pad_d)), _swap_th(_pad_d(k, pad_d))
+    vh, doh = _swap_th(v), _swap_th(g.astype(q.dtype))
+    rows = lambda x, m: _pad_t(x, m, axis=2)          # [B,H,T,...] along T
     # padded q rows: dO rows are zero => ds rows are zero => no dk/dv
     # contribution; their dq rows are sliced off below
-    lse_p = _swap_th(_pad_t(rep(lse), pad_q))
-    dl_p = _swap_th(_pad_t(rep(delta), pad_q))
-    fn = functools.partial(_bwd_one_head, scale=scale_, causal=causal,
-                           block_q=bq, block_k=bk, k_len=Tk,
-                           interpret=interpret)
-    dq, dk, dv = _double_vmap(fn)(qp, kp, vp, dop, lse_p, dl_p)
+    row = lambda x, m: rows(x, m)[:, :, None]         # [B,H,1,T]
+    kw = dict(scale=scale_, causal=causal, q_len=Tq, k_len=Tk,
+              interpret=interpret)
+    t = tiles.dq
+    dq = _double_vmap(functools.partial(_dq_one_head, tile=t, **kw))(
+        rows(qh, t.rows), rows(kh, t.major), rows(vh, t.major),
+        rows(doh, t.rows), row(lse, t.rows), row(delta, t.rows))
+    t = tiles.dkv
+    dk, dv = _double_vmap(functools.partial(_dkv_one_head, tile=t, **kw))(
+        rows(qh, t.major), rows(kh, t.rows), rows(vh, t.rows),
+        rows(doh, t.major), row(lse, t.major), row(delta, t.major))
     # the padded score columns' gradients are sliced off with the padded rows
     return (_swap_th(dq)[:, :Tq, :, :D], _swap_th(dk)[:, :Tk, :, :D],
             _swap_th(dv)[:, :Tk])
 
 
+def _fa_fwd(q, k, v, causal, scale, block_q, block_k, schedule):
+    interpret = _use_interpret()
+    _require_hw_head_dim(v.shape[-1], interpret)
+    out, lse = _forward(q, k, v, causal=causal, scale=scale,
+                        block_q=block_q, block_k=block_k, schedule=schedule,
+                        interpret=interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _fa_bwd(causal, scale, block_q, block_k, schedule, res, g):
+    return _backward(*res, g, causal=causal, scale=scale, block_q=block_q,
+                     block_k=block_k, schedule=schedule,
+                     interpret=_use_interpret())
+
+
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "flash_schedule", "Schedule", "Tile"]

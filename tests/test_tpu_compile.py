@@ -47,28 +47,68 @@ def _custom_calls(compiled):
                       r"\"tpu_custom_call\"", compiled.as_text())
 
 
+def _flash_calls(compiled):
+    """``{kernel: output signature}`` of the program's Pallas calls, the
+    layouts left out: what the benchmark's roofline patterns tell the
+    flash kernels by (``benchmarks/layer_metrics/flash_*_roofline.json``)."""
+    calls = re.findall(r"^\s*%(\S+) = (.*?) custom-call\([^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"",
+                       compiled.as_text(), re.M)
+    kernel = lambda name: re.search(r"flash_(fwd|bwd_dq|bwd_dkv)", name)
+    assert all(kernel(n) for n, _ in calls), calls
+    return [(kernel(n).group(0), re.sub(r"\{[^}]*\}", "", sig))
+            for n, sig in calls]
+
+
+def _attention_fwd_bwd(one_chip, b, t, heads, dqk, dv):
+    shape = lambda d: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
+                                           sharding=one_chip)
+
+    def loss(q, k, v):   # no blocks given: the tiles flash_schedule chooses
+        out = pa.flash_attention(q, k, v, True, dqk ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(dqk), shape(dqk), shape(dv)).compile()
+
+
 @pytest.mark.parametrize("heads,dqk,dv", [(16, 128, 128), (32, 192, 128)],
                          ids=["gpt2_128", "latent_192_128"])
 def test_flash_kernels_compile_at_real_widths(one_chip, hardware_path,
                                               heads, dqk, dv):
+    """One attention's forward and backward at the two cells' shapes under
+    the DEFAULT tiles: exactly three Pallas calls, by the names and the
+    output signatures the benchmark finds them by -- ``(bf16 o, f32
+    logsumexp)``, ``bf16 dq``, ``(bf16 dk, bf16 dv)``."""
     b, t = 2, 2048
-    shape = lambda d: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
-                                           sharding=one_chip)
-
-    def loss(q, k, v):
-        out = pa.flash_attention(q, k, v, True, dqk ** -0.5)
-        return jnp.sum(out.astype(jnp.float32))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        shape(dqk), shape(dqk), shape(dv)).compile()
-    calls = _custom_calls(compiled)
-    names = " ".join(n for n, _ in calls)
-    assert "flash_fwd" in names and "flash_bwd_dq" in names \
-        and "flash_bwd_dkv" in names
     # scores of 192 reach the kernel as 256 columns, values stay 128
     width = 256 if dqk == 192 else dqk
-    dq = next(sig for n, sig in calls if "flash_bwd_dq" in n)
-    assert f"bf16[{b},{heads},{t},{width}]" in dq
+    calls = _flash_calls(_attention_fwd_bwd(one_chip, b, t, heads, dqk, dv))
+    assert sorted(calls) == sorted([
+        ("flash_fwd", f"(bf16[{b},{heads},{t},{dv}], f32[{b},{heads},1,{t}])"),
+        ("flash_bwd_dq", f"bf16[{b},{heads},{t},{width}]"),
+        ("flash_bwd_dkv", f"(bf16[{b},{heads},{t},{width}], "
+                          f"bf16[{b},{heads},{t},{dv}])")])
+    # the schedule of the cells' shapes, pinned: a 4 x 1 grid a head in
+    # every kernel (it was 16 x 16), the whole sequence resident
+    tile = pa.Tile(rows=512, major=2048, minor=512)
+    assert pa.flash_schedule(t, t, width, dv, jnp.bfloat16) \
+        == (pa.Schedule(tile, tile, tile), (4, 4, 4))
+
+
+@pytest.mark.parametrize("t,dqk,steps", [(80, 128, (1, 1, 1)),
+                                         (8192, 192, (32, 64, 64))],
+                         ids=["t80_clipped", "t8192_inner_grid_axis"])
+def test_flash_kernels_compile_at_other_lengths(one_chip, hardware_path,
+                                                t, dqk, steps):
+    """A sequence that clips the tiles (80 rows, keys padded to 128) and
+    one whose keys do not stay resident (the grid keeps its inner axis,
+    with the clamped index maps): Mosaic takes both."""
+    width = 256 if dqk == 192 else dqk
+    assert pa.flash_schedule(t, t, width, 128, jnp.bfloat16)[1] == steps
+    calls = _flash_calls(_attention_fwd_bwd(one_chip, 1, t, 2, dqk, 128))
+    assert sorted(n for n, _ in calls) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                           "flash_fwd"]
 
 
 def test_flash_refuses_a_value_width_the_hardware_cannot_run(hardware_path):
