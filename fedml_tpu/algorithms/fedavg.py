@@ -16,6 +16,7 @@ import time
 import jax
 import numpy as np
 
+from fedml_tpu.algorithms.specs import block_diffusion_counters
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.perfmon import get_perf_monitor
 from fedml_tpu.observability.routing import note_routing, routing_counters
@@ -245,7 +246,8 @@ class FedAvgAPI:
                 # the stream's metric sums are on the host already;
                 # "fold" says where the payload sums were combined
                 sp.set(fold=info["fold"],
-                       **routing_counters(info["metrics"]))
+                       **routing_counters(info["metrics"]),
+                       **block_diffusion_counters(info["metrics"]))
         self._last_info = info
         with tracer.span("aggregate"):
             end_of_round_sync(self.global_state)

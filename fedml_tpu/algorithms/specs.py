@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from fedml_tpu.core.trainer import TrainSpec
 
@@ -168,6 +169,85 @@ def make_seq_classification_spec(model, example_x, ignore_index=0,
 
     return TrainSpec(init_fn=init_fn, loss_fn=loss_fn, metrics_fn=metrics_fn,
                      name=name)
+
+
+def make_block_diffusion_lm_spec(model, example_x, block_length, mask_id,
+                                 name="block_diffusion_lm"):
+    """Block-diffusion LM training (BD3-LM's vectorised form): one pass
+    over a clean copy and a noised copy of every sequence.
+
+    Batch: ``x`` ``[n, L]`` int32 clean ids; ``y`` ``[n, L]`` float32, the
+    corruption as data: ``block_length / k`` at the ``k`` positions of a
+    block that are masked, 0 elsewhere (mask and weight in one array).
+    The spec builds ``x_t = where(y > 0, mask_id, x)`` and feeds ``[x ;
+    x_t]`` (``2 L`` ids) to ``model``, which returns the noised half's
+    logits ``[n, L, V]`` (``models/deepseek_v3.py`` ``DecoderLM`` with
+    ``block_length``); the logits AT a masked position predict that
+    position's clean id, no shift. Loss: ``sum_i y_i CE_i / (n L)`` over
+    the rows that count. Metric sums: ``loss_sum`` (the cross-entropy at
+    the masked positions, unweighted), ``count`` (masked positions),
+    ``correct``, ``bd_positions`` (the ``2 L`` positions a row that
+    counts runs through the model) and the routing counters the model
+    sows, as the sequence spec has them."""
+    length = example_x.shape[1]
+    theirs = getattr(getattr(model, "cfg", None), "block_length",
+                     block_length)
+    if length % block_length or theirs != block_length:
+        raise ValueError(
+            f"block diffusion: sequences of {length} ids in blocks of "
+            f"{block_length}; the model masks blocks of {theirs}")
+
+    def both_copies(x, y):
+        return jnp.concatenate([x, jnp.where(y > 0, mask_id, x)], axis=1)
+
+    def init_fn(rng):
+        return _init_state(model, both_copies(
+            example_x, jnp.zeros(example_x.shape, jnp.float32)), rng)
+
+    def _loss_and_metrics(logits, x, y, mask):
+        weight = y.astype(jnp.float32) * mask[:, None]
+        masked = (weight > 0).astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        nll = -jnp.take_along_axis(logp, x[..., None].astype(jnp.int32),
+                                   axis=-1)[..., 0]
+        rows = jnp.sum(mask)
+        loss = jnp.sum(nll * weight) / jnp.maximum(rows * length, 1.0)
+        return loss, {
+            "loss_sum": jnp.sum(nll * masked), "count": jnp.sum(masked),
+            "correct": jnp.sum((jnp.argmax(logits, axis=-1) == x) * masked),
+            "bd_positions": 2.0 * length * rows}
+
+    def loss_fn(state, batch, rng, train):
+        logits, new_state, _, sown = _apply_model(
+            model, state, both_copies(batch["x"], batch["y"]), rng, train,
+            with_sown=True, with_metrics=True)
+        loss, metrics = _loss_and_metrics(logits, batch["x"], batch["y"],
+                                          batch["mask"])
+        # a step of padding only (a ragged lane's tail) counts nothing
+        live = (jnp.sum(batch["mask"]) > 0).astype(jnp.float32)
+        metrics.update({k: v * live for k, v in sown.items()})
+        return loss, (new_state, metrics)
+
+    def metrics_fn(state, batch):
+        logits, _ = _apply_model(
+            model, state, both_copies(batch["x"], batch["y"]), None, False)
+        return _loss_and_metrics(logits, batch["x"], batch["y"],
+                                 batch["mask"])[1]
+
+    return TrainSpec(init_fn=init_fn, loss_fn=loss_fn, metrics_fn=metrics_fn,
+                     name=name)
+
+
+def block_diffusion_counters(metrics) -> dict:
+    """The round's ``bd.loss_tokens`` (masked positions that carried loss)
+    and ``bd.positions`` (positions run through the model, both copies)
+    from the metric sums of :func:`make_block_diffusion_lm_spec`; ``{}``
+    for any other spec."""
+    if not metrics or "bd_positions" not in metrics:
+        return {}
+    total = lambda k: float(np.sum(np.asarray(metrics[k])))
+    return {"bd.loss_tokens": total("count"),
+            "bd.positions": total("bd_positions")}
 
 
 def make_segmentation_spec(model, example_x, num_classes,

@@ -1,34 +1,52 @@
-"""A decoder read from a configuration: the ``deepseek_v3`` family.
+"""A decoder read from a configuration: the ``deepseek_v3`` family and
+the ``sdar_moe`` family.
 
-Token ids ``[B, T]`` -> next-token logits ``[B, T, vocab]`` (the surface
+Token ids ``[B, T]`` -> logits ``[B, T, vocab]`` (the surface
 :func:`fedml_tpu.algorithms.specs.make_seq_classification_spec` takes),
 built from a configuration dict with the key names of the family's public
 ``config.json`` (:class:`DecoderConfig`). What the block is made of:
 
 - RMSNorm before each sublayer and before the untied head, no bias
   anywhere;
-- :class:`LatentAttention` (MLA without a query bottleneck): keys and
-  values come out of one shared latent of ``kv_lora_rank`` columns, a
-  rotary part of ``qk_rope_head_dim`` columns rides beside the
-  position-free ``qk_nope_head_dim`` ones (one rotary key head shared by
-  every query head, interleaved pairs), so scores are
+- ``deepseek_v3``: :class:`LatentAttention` (MLA without a query
+  bottleneck): keys and values come out of one shared latent of
+  ``kv_lora_rank`` columns, a rotary part of ``qk_rope_head_dim`` columns
+  rides beside the position-free ``qk_nope_head_dim`` ones (one rotary
+  key head shared by every query head, interleaved pairs), so scores are
   ``qk_nope + qk_rope`` wide and values ``v_head_dim``: the flash kernels
   take the two widths apart (:mod:`fedml_tpu.ops.pallas_attention`);
+- ``sdar_moe``: :class:`GroupedQueryAttention`: ``num_attention_heads``
+  query heads over ``num_key_value_heads`` key/value heads of an explicit
+  ``head_dim`` (query head ``h`` reads key/value head ``h // group``),
+  RMSNorm on every head's q and k, rotate-half rotary positions;
 - a gated (SwiGLU) MLP in the first ``first_k_dense_replace`` layers and
-  :class:`RoutedExperts` in the others.
+  :class:`RoutedExperts` in the others (``sdar_moe``: in every layer).
+
+**Block diffusion** (``block_length`` set; grouped-query attention only:
+the latent one computes the causal mask alone). The ids
+are then ``[x_0 ; x_t]``, a clean copy and a noised copy of ``T / 2``
+positions each: position ``i`` has rotary position ``i mod T/2``,
+attention runs under :class:`~fedml_tpu.ops.pallas_attention.BlockDiffusion`
+(a clean row sees the clean blocks up to its own, a noised row the clean
+blocks before its own and the noised keys of its own), and the head runs
+on the noised half alone: logits ``[B, T / 2, vocab]``, the ones AT a
+position predicting that position's clean token
+(:func:`fedml_tpu.algorithms.specs.make_block_diffusion_lm_spec`).
 
 :class:`RoutedExperts` is what expert parallelism asks of a chip: it is
 told which experts it holds (``experts_held = (first, count)``) and how
-many the router has, routes every token over all of them (sigmoid
+many the router has, routes every token over all of them (``sigmoid``
 scores, the choice by score plus ``e_score_correction_bias``, the weights
-by score alone, renormalised and scaled), and computes its own experts'
+by score alone, renormalised and scaled; or ``softmax`` scores over all
+the router's experts and no bias), and computes its own experts'
 part of the result for the tokens routed to them: assignments sorted by
 expert, one grouped product a projection over stacked ``[count, d,
 width]`` leaves (:mod:`fedml_tpu.ops.grouped_matmul`), gathered back by
 the inverse permutation and summed by weight. No token is dropped
 whatever the imbalance: the sorted buffer has a row for every
 assignment. What absent experts would add is left out, and nothing here
-stands in for other chips. The shared expert runs on every token.
+stands in for other chips. The shared expert, where the family has one,
+runs on every token.
 
 Counters of the routing are sown into the ``metrics`` collection, one
 value a layer and step (``fedml_tpu.observability.routing`` makes the
@@ -46,55 +64,97 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.ops.grouped_matmul import grouped_matmul
-from fedml_tpu.ops.pallas_attention import flash_attention
+from fedml_tpu.ops.pallas_attention import BlockDiffusion, flash_attention
 
 _HI = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """The family's ``config.json`` keys this decoder reads, under their
-    published names. ``router_experts`` and ``experts_held`` are this
-    repo's: the router's width where ``n_routed_experts`` counts only the
-    experts held (a benchmark configuration's cut), and which they are."""
+    """The families' ``config.json`` keys this decoder reads, under their
+    published names (``deepseek_v3``'s; ``from_dict`` maps ``sdar_moe``'s
+    onto them, and is the one place that knows a family by name: the
+    modules below read features). ``attention`` (``latent`` or
+    ``grouped``, from which keys the file has), ``router_experts`` and
+    ``experts_held`` are this repo's: the router's width where
+    ``n_routed_experts`` counts only the experts held (a benchmark
+    configuration's cut), and which they are; ``block_length`` is block
+    diffusion's (module docstring)."""
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
     num_attention_heads: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
-    kv_lora_rank: int
-    intermediate_size: int
     moe_intermediate_size: int
     n_routed_experts: int
-    n_shared_experts: int
     num_experts_per_tok: int
+    attention: str = "latent"
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    kv_lora_rank: int = 0
+    num_key_value_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    intermediate_size: int = 0
+    n_shared_experts: int = 0
     first_k_dense_replace: int = 1
+    scoring_func: str = "sigmoid"
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     router_experts: Optional[int] = None
     experts_held: Optional[Tuple[int, int]] = None
+    block_length: Optional[int] = None
 
-    @classmethod
-    def from_dict(cls, cfg, **overrides):
-        """Refuses what this decoder does not compute, so that a file of
-        another member of the family is an error and not another model."""
-        cfg = {**cfg, **overrides}
-        computed = {
+    #: key -> the values this decoder computes, by family
+    _COMPUTED = {
+        "deepseek_v3": {
             "q_lora_rank": (None,), "rope_scaling": (None,),
             "rope_interleave": (True,), "moe_layer_freq": (1,),
             "n_group": (1,), "topk_group": (1,),
             "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",),
             "hidden_act": ("silu",), "attention_bias": (False,),
-            "tie_word_embeddings": (False,)}
-        for key, allowed in computed.items():
+            "tie_word_embeddings": (False,), "block_length": (None,)},
+        "sdar_moe": {
+            "rope_scaling": (None,), "decoder_sparse_step": (1,),
+            "mlp_only_layers": ([], ()), "use_sliding_window": (False,),
+            "sliding_window": (None,), "layer_types": (None,),
+            "hidden_act": ("silu",), "attention_bias": (False,),
+            "tie_word_embeddings": (False,)}}
+
+    _NEEDED = {
+        "deepseek_v3": ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                        "kv_lora_rank", "intermediate_size",
+                        "n_shared_experts"),
+        "sdar_moe": ("head_dim", "num_key_value_heads", "num_experts")}
+
+    @classmethod
+    def from_dict(cls, cfg, **overrides):
+        """Refuses what this decoder does not compute, so that a file of
+        another member of a family is an error and not another model."""
+        cfg = {**cfg, **overrides}
+        family = cfg.get("model_type", "deepseek_v3")
+        if family not in cls._COMPUTED:
+            raise NotImplementedError(
+                f"decoder: model_type={family!r} is not computed here "
+                f"(only {sorted(cls._COMPUTED)})")
+        for key, allowed in cls._COMPUTED[family].items():
             if key in cfg and cfg[key] not in allowed:
                 raise NotImplementedError(
-                    f"deepseek_v3 decoder: {key}={cfg[key]!r} is not "
+                    f"{family} decoder: {key}={cfg[key]!r} is not "
                     f"computed here (only {allowed[0]!r})")
+        missing = [k for k in cls._NEEDED[family] if k not in cfg]
+        if missing:
+            raise ValueError(f"{family} decoder: the configuration lacks "
+                             f"{missing}")
+        if family == "sdar_moe":
+            # every layer routed, softmax over the router's num_experts,
+            # no bias, no shared expert, no scaling
+            cfg.setdefault("router_experts", cfg["num_experts"])
+            cfg.setdefault("n_routed_experts", cfg["num_experts"])
+            cfg.update(first_k_dense_replace=0, n_shared_experts=0,
+                       scoring_func="softmax", routed_scaling_factor=1.0)
+        cfg["attention"] = "latent" if "kv_lora_rank" in cfg else "grouped"
         if "n_layer" in cfg:  # the depth as run, where a file cuts it
             cfg["num_hidden_layers"] = cfg["n_layer"]
         if cfg.get("experts_held") is not None:
@@ -151,8 +211,12 @@ class LatentAttention(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, mask=True, positions=None):
         c = self.cfg
+        if mask is not True:
+            raise NotImplementedError(
+                "LatentAttention computes the causal mask alone (its rotary "
+                f"turn takes no positions): got {mask!r}")
         B, T, _ = x.shape
         H, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
                          c.qk_rope_head_dim)
@@ -178,6 +242,49 @@ class LatentAttention(nn.Module):
             att.reshape(B, T, H * c.v_head_dim))
 
 
+def rotary_half(x, positions, theta):
+    """Rotary positions over the last axis of ``[B, T, H, D]`` in the
+    rotate-half layout (column ``i`` pairs with ``i + D/2``), row ``t`` at
+    position ``positions[t]``, computed in float32."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq      # [T, D/2]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class GroupedQueryAttention(nn.Module):
+    """``num_attention_heads`` query heads of ``head_dim`` over
+    ``num_key_value_heads`` key/value heads, RMSNorm on each head's q and
+    k before the rotary turn. The key/value heads are REPEATED to the
+    query heads before the flash kernels (query head ``h`` reads head ``h
+    // group``; the gradient sums a group back): the kernels take one
+    key/value head a query head (PERF.md section 7)."""
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, mask, positions):
+        c = self.cfg
+        B, T, _ = x.shape
+        H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        head_norm = lambda name: nn.RMSNorm(epsilon=c.rms_norm_eps,
+                                            dtype=self.dtype, name=name)
+        q = _dense(H * D, self.dtype, "q_proj")(x).reshape(B, T, H, D)
+        k = _dense(KV * D, self.dtype, "k_proj")(x).reshape(B, T, KV, D)
+        v = _dense(KV * D, self.dtype, "v_proj")(x).reshape(B, T, KV, D)
+        q = rotary_half(head_norm("q_norm")(q), positions, c.rope_theta)
+        k = rotary_half(head_norm("k_norm")(k), positions, c.rope_theta)
+        k, v = (jnp.repeat(a, H // KV, axis=2) for a in (k, v))
+        with jax.named_scope("bd_attn" if isinstance(mask, BlockDiffusion)
+                             else "attn"):
+            att = flash_attention(q, k, v, mask, D ** -0.5)
+        return _dense(x.shape[-1], self.dtype, "o_proj")(
+            att.reshape(B, T, H * D))
+
+
 @jax.custom_vjp
 def _take_permuted(x, perm, inverse):
     """``x[perm]`` for a permutation of the rows; the gradient is a gather
@@ -198,8 +305,8 @@ def _sum_metric(module, name, value):
 
 class RoutedExperts(nn.Module):
     """The held experts' part of a routed-expert layer plus the shared
-    expert, over flattened tokens ``[N, d] -> [N, d]`` (module docstring).
-    """
+    expert where there is one, over flattened tokens ``[N, d] -> [N, d]``
+    (module docstring)."""
     cfg: DecoderConfig
     dtype: Any = jnp.float32
 
@@ -217,16 +324,24 @@ class RoutedExperts(nn.Module):
         w_gate = self.param("w_gate", init, (count, d, width))
         w_up = self.param("w_up", init, (count, d, width))
         w_down = self.param("w_down", init, (count, width, d))
-        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
-                          (E,))
+        sigmoid = c.scoring_func == "sigmoid"
+        if sigmoid:
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (E,))
 
         with jax.named_scope("moe_route"):
-            scores = jax.nn.sigmoid(nn.Dense(
+            logits = nn.Dense(
                 E, use_bias=False, dtype=jnp.float32, precision=_HI,
-                name="router")(x.astype(jnp.float32)))            # [N, E]
-            # the bias steers the choice only: the weights are the scores
-            _, chosen = jax.lax.top_k(
-                scores + jax.lax.stop_gradient(bias), k)          # [N, k]
+                name="router")(x.astype(jnp.float32))             # [N, E]
+            if sigmoid:
+                scores = jax.nn.sigmoid(logits)
+                # the bias steers the choice only: the weights are the
+                # scores
+                _, chosen = jax.lax.top_k(
+                    scores + jax.lax.stop_gradient(bias), k)      # [N, k]
+            else:
+                scores = jax.nn.softmax(logits, axis=-1)
+                _, chosen = jax.lax.top_k(scores, k)
             weight = jnp.take_along_axis(scores, chosen, axis=-1)
             if c.norm_topk_prob:
                 weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
@@ -252,16 +367,22 @@ class RoutedExperts(nn.Module):
             routed = jnp.sum(
                 y * weight[..., None].astype(y.dtype), axis=1)
 
-        with jax.named_scope("moe_shared"):
-            shared = GatedMLP(c.n_shared_experts * width, self.dtype,
-                              name="shared")(x)
+        if c.n_shared_experts:
+            with jax.named_scope("moe_shared"):
+                routed = routed + GatedMLP(c.n_shared_experts * width,
+                                           self.dtype, name="shared")(x)
 
         rows = jnp.sum(group_sizes)
         _sum_metric(self, "moe_rows_held", rows)
         _sum_metric(self, "moe_load_max", jnp.max(group_sizes))
         _sum_metric(self, "moe_load_mean", rows / count)
         _sum_metric(self, "moe_dropped", jnp.sum(held) - rows)
-        return routed + shared
+        return routed
+
+
+#: ``DecoderConfig.attention`` -> the module and its scope in a trace
+_ATTENTION = {"latent": (LatentAttention, "mla"),
+              "grouped": (GroupedQueryAttention, "gqa")}
 
 
 class _DecoderLayer(nn.Module):
@@ -270,14 +391,15 @@ class _DecoderLayer(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, mask=True, positions=None):
         c = self.cfg
         B, T, d = x.shape
         norm = lambda name: nn.RMSNorm(epsilon=c.rms_norm_eps,
                                        dtype=self.dtype, name=name)
-        with jax.named_scope("mla"):
-            x = x + LatentAttention(c, self.dtype, name="attn")(
-                norm("attn_norm")(x))
+        attn, scope = _ATTENTION[c.attention]
+        with jax.named_scope(scope):
+            x = x + attn(c, self.dtype, name="attn")(
+                norm("attn_norm")(x), mask, positions)
         h = norm("ffn_norm")(x)
         if self.dense:
             return x + GatedMLP(c.intermediate_size, self.dtype,
@@ -286,26 +408,41 @@ class _DecoderLayer(nn.Module):
             h.reshape(B * T, d)).reshape(B, T, d)
 
 
-class DeepseekV3LM(nn.Module):
-    """Causal LM ``[B, T] -> [B, T, vocab]`` from a :class:`DecoderConfig`;
-    parameters float32, compute in ``dtype``, the head's logits float32."""
+class DecoderLM(nn.Module):
+    """LM ``[B, T] -> [B, T, vocab]`` from a :class:`DecoderConfig`,
+    causal; with ``block_length`` set, ``[x_0 ; x_t] -> [B, T / 2,
+    vocab]`` under the block-diffusion mask (module docstring).
+    Parameters float32, compute in ``dtype``, the head's logits float32."""
     cfg: DecoderConfig
     dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, idx, train: bool = False):
         c = self.cfg
+        T = idx.shape[1]
+        mask, positions, keep = True, jnp.arange(T), 0
+        if c.block_length:
+            if T % (2 * c.block_length):
+                raise ValueError(
+                    "block diffusion: ids [x_0 ; x_t] in whole blocks of "
+                    f"{c.block_length} (got {T} ids)")
+            keep = T // 2
+            mask, positions = BlockDiffusion(keep, c.block_length), \
+                positions % keep
         x = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
                      name="tok_embed")(idx)
         for i in range(c.num_hidden_layers):
             x = _DecoderLayer(c, i < c.first_k_dense_replace, self.dtype,
-                              name=f"layer{i}")(x)
+                              name=f"layer{i}")(x, mask, positions)
         x = nn.RMSNorm(epsilon=c.rms_norm_eps, dtype=self.dtype,
-                       name="norm_f")(x)
+                       name="norm_f")(x[:, keep:])
         return nn.Dense(c.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="head")(x.astype(jnp.float32))
 
 
-__all__ = ["DecoderConfig", "DeepseekV3LM", "LatentAttention",
-           "RoutedExperts", "GatedMLP", "load_config",
-           "rotary_interleaved"]
+DeepseekV3LM = DecoderLM    # the name the first family's callers know
+
+
+__all__ = ["DecoderConfig", "DecoderLM", "DeepseekV3LM", "LatentAttention",
+           "GroupedQueryAttention", "RoutedExperts", "GatedMLP",
+           "load_config", "rotary_interleaved", "rotary_half"]

@@ -27,6 +27,21 @@ inner axis; a step the band does not reach runs no pass of the loop AND
 names the block already resident (clamped index maps), so nothing is
 fetched for it. :func:`flash_schedule` chooses the tiles from the shape.
 
+**Mask kinds.** ``causal`` is ``False`` (none), ``True`` (the lower
+triangle) or a :class:`BlockDiffusion` ``(length, block)``: block-diffusion
+training's mask over keys and queries laid out ``[x_0 ; x_t]`` (a clean
+copy and a noised copy of ``length`` positions each, in blocks of
+``block``). A clean row sees the clean keys of its own and earlier
+blocks; a noised row sees the clean keys of STRICTLY earlier blocks and
+the noised keys of its own block; nothing else. That is a quarter of the
+``(2 length)^2`` pairs, so the band of a tile is TWO ranges of minor
+blocks here (:func:`_bd_spans`): for a noised query tile the clean keys
+before its first block (the block the boundary crosses is masked in the
+body, as the causal diagonal is) and its own noised blocks; for a clean
+key tile of ``flash_bwd_dkv`` the clean rows from its block on and the
+noised rows of later blocks. Blocks outside the ranges are not visited,
+and where the grid keeps its inner axis they are not fetched either.
+
 ``flash_bwd_dkv`` works on the TRANSPOSED score tile (keys on sublanes,
 queries on lanes): ``dv += p^T dO`` and ``dk += ds^T q`` are then plain
 products, no score-sized transpose, and logsumexp/delta are rows.
@@ -84,6 +99,14 @@ class Schedule(NamedTuple):
     fwd: Tile
     dq: Tile
     dkv: Tile
+
+
+class BlockDiffusion(NamedTuple):
+    """The mask of block-diffusion training (module docstring): keys and
+    queries are ``[x_0 ; x_t]``, ``length`` positions each (a multiple of
+    ``block``)."""
+    length: int
+    block: int
 
 
 def _use_interpret() -> bool:
@@ -198,14 +221,53 @@ def _col(row):
 
 def _mask(s, *, q0, k0, k_len, causal, keys_on_rows=False):
     """NEG_INF-mask invalid scores: zero-padded keys always, upper
-    triangle when causal. Only the bodies of tiles the diagonal crosses
-    or that hold padded keys call this."""
+    triangle when causal, the three rules of :class:`BlockDiffusion`.
+    Only the bodies of tiles a boundary of the mask crosses or that hold
+    padded keys call this."""
+    if isinstance(causal, BlockDiffusion):
+        return _bd_mask(s, q0=q0, k0=k0, k_len=k_len, bd=causal,
+                        keys_on_rows=keys_on_rows)
     qa, ka = (1, 0) if keys_on_rows else (0, 1)
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, ka)
     valid = kpos < k_len
     if causal:
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, qa)
         valid = valid & (kpos <= qpos)
+    return jnp.where(valid, s, NEG_INF)
+
+
+def _block_of(pos, block):
+    """``pos // block`` for positions that are not negative; a shift where
+    ``block`` is a power of two (an integer division a score would cost
+    the vector unit more than the mask is worth)."""
+    if block & (block - 1) == 0:
+        return jax.lax.shift_right_logical(
+            pos, jnp.full_like(pos, block.bit_length() - 1))
+    return pos // block
+
+
+def _bd_mask(s, *, q0, k0, k_len, bd, keys_on_rows):
+    """The block-diffusion mask of one score tile. Every per-position
+    quantity is worked out on a COLUMN (lane-replicated, ``[rows,
+    LANES]``) or a ROW (``[1, lanes]``) and meets the tile in two
+    compares: a key of the clean copy is seen by rows whose ``below``
+    exceeds its block (own block and earlier for a clean row, strictly
+    earlier for a noised one), a key of the noised copy by the noised
+    rows of its own block."""
+    length, block = bd
+    sub, lanes = s.shape
+    col = lambda p0: p0 + jax.lax.broadcasted_iota(jnp.int32, (sub, _LANES), 0)
+    row = lambda p0: p0 + jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    qpos, kpos = (row(q0), col(k0)) if keys_on_rows else (col(q0), row(k0))
+    q_noised, k_noised = qpos >= length, kpos >= length
+    qb = _block_of(jnp.where(q_noised, qpos - length, qpos), block)
+    kb = _block_of(jnp.where(k_noised, kpos - length, kpos), block)
+    below = jnp.where(q_noised, qb, qb + 1)
+    own = jnp.where(q_noised, qb, -1)
+    clean = jnp.where(k_noised, 2 ** 30, kb)
+    noised = jnp.where(k_noised & (kpos < k_len), kb, -2)
+    wide = lambda x: _lanes(x, lanes) if x.shape[0] == sub else x
+    valid = (wide(clean) < wide(below)) | (wide(noised) == wide(own))
     return jnp.where(valid, s, NEG_INF)
 
 
@@ -219,38 +281,127 @@ def _keys_needed(i, tile, k_len, causal):
     return needed
 
 
+def _bd_spans(i, tile, bd, *, own_len, other_len, keys_own):
+    """The minor blocks of the streamed sequence that tile ``i`` needs
+    under a :class:`BlockDiffusion` mask: ``(plain, masked, ranges)``.
+    ``ranges`` are the TWO runs of blocks the tile reaches at all
+    (``[(lo, hi), (lo, hi)]``, the second may be empty); ``plain`` and
+    ``masked`` are the pieces of them whose every pair is seen (no mask
+    in the body) and the others. A tile that holds rows of both copies,
+    which only a ``length`` that is no multiple of the tile gives, takes
+    every block masked: the body's mask is exact whatever the band."""
+    length, block = bd
+    rows, _, m = tile
+    start = lambda pos: (pos // block) * block      # first position of pos's block
+    valid = pl.cdiv(other_len, m)
+    p0, p1 = i * rows, jnp.minimum(i * rows + rows, own_len) - 1
+    is_clean, is_noised = p1 < length, p0 >= length
+    pick = lambda clean, noised, both: jnp.where(
+        is_clean, clean, jnp.where(is_noised, noised, both))
+    if not keys_own:
+        # clean rows: clean keys through their own block; noised rows:
+        # clean keys before their block, then the noised keys of it
+        t0, t1 = p0 - length, p1 - length
+        hi = pl.cdiv(pick(start(p1) + block, start(t1), other_len), m)
+        split = pick(start(p0) + block, start(t0), 0) // m
+        lo2 = jnp.maximum((length + start(t0)) // m, hi)
+        hi2 = pick(0, pl.cdiv(length + start(t1) + block, m), 0)
+        lo2 = jnp.minimum(lo2, hi2)
+        return ([(0, split)], [(split, hi), (lo2, hi2)],
+                [(0, hi), (lo2, hi2)])
+    # a tile of clean keys: the clean rows from its first key's block on
+    # (those from its last key's block on see all of it), then the noised
+    # rows of later blocks; a tile of noised keys: the rows of its blocks
+    s1, e1 = start(p0) // m, pl.cdiv(length, m)
+    u1 = jnp.minimum(pl.cdiv(start(p1), m), length // m)
+    s2 = jnp.maximum((length + start(p0) + block) // m, e1)
+    u2 = jnp.clip(pl.cdiv(length + start(p1) + block, m), s2, valid)
+    lo = pick(s1, (length + start(p0 - length)) // m, 0)
+    hi = pick(e1, pl.cdiv(length + start(p1 - length) + block, m), valid)
+    only = lambda v: jnp.where(is_clean, v, 0)
+    plain = [(only(u1), only(length // m)), (only(u2), only(valid))]
+    masked = [(lo, jnp.where(is_clean, u1, hi)),
+              (only(length // m), only(e1)), (only(s2), only(u2))]
+    return plain, masked, [(lo, hi), (only(s2), only(valid))]
+
+
 def _band(i, jm, tile, *, own_len, other_len, causal, keys_own):
     """Which minor blocks of the streamed sequence the body visits in grid
-    step ``(i, jm)``: ``(lo, split, hi)`` in minor blocks from the start
-    of the sequence. ``keys_own`` False (``fwd``, ``dq``): tile ``i`` of
-    queries against key blocks; ``[lo, split)`` lie wholly under the
-    diagonal and inside the keys (no mask), ``[split, hi)`` are crossed by
-    it or hold padded keys. ``keys_own`` True (``dkv``): tile ``i`` of
-    keys against query blocks; ``[lo, split)`` are crossed by the
-    diagonal (masked), ``[split, hi)`` lie wholly under it -- unless the
-    key tile holds padded keys, then every block is masked."""
+    step ``(i, jm)``: ``(plain, masked)``, each a list of ``(lo, hi)``
+    runs in minor blocks from the start of the sequence; ``plain`` runs
+    lie wholly inside the mask (the body builds none), ``masked`` ones
+    are crossed by a boundary of it or hold padded keys. No mask or
+    causal, one run each. ``keys_own`` False (``fwd``, ``dq``): tile
+    ``i`` of queries against key blocks, the blocks under the diagonal
+    then those it crosses. ``keys_own`` True (``dkv``): tile ``i`` of
+    keys against query blocks, the crossed ones then those under it --
+    unless the key tile holds padded keys, then every block is masked.
+    :class:`BlockDiffusion`: :func:`_bd_spans`."""
     rows, major, minor = tile
     per_step = major // minor
     first, last = jm * per_step, (jm + 1) * per_step
     valid = pl.cdiv(other_len, minor)       # blocks that hold real rows
+    if isinstance(causal, BlockDiffusion):
+        plain, masked, _ = _bd_spans(i, tile, causal, own_len=own_len,
+                                     other_len=other_len, keys_own=keys_own)
+        hi = jnp.minimum(valid, last)
+        clip = lambda runs: [(jnp.clip(a, first, hi), jnp.clip(b, first, hi))
+                             for a, b in runs]
+        return clip(plain), clip(masked)
     if not keys_own:
         full = other_len // minor
         if causal:
             full = jnp.minimum(full, (i * rows + 1) // minor)
-        return (first, jnp.clip(full, first, last),
-                jnp.clip(_keys_needed(i, tile, other_len, causal),
-                         first, last))
+        split = jnp.clip(full, first, last)
+        return [(first, split)], [(split, jnp.clip(
+            _keys_needed(i, tile, other_len, causal), first, last))]
     start = (i * rows) // minor if causal else 0
     under = (i * rows + rows + minor - 2) // minor if causal else 0
     under = jnp.where((i + 1) * rows > own_len, valid, under)  # ragged keys
     hi = jnp.minimum(valid, last)
-    return (jnp.clip(start, first, hi), jnp.clip(under, first, hi), hi)
+    split = jnp.clip(under, first, hi)
+    return [(split, hi)], [(jnp.clip(start, first, hi), split)]
 
 
-def _loop(lo, hi, body):
-    """``body(block)`` for ``block`` in ``[lo, hi)``, bounds traced (the
-    causal band's): one pass a minor block, none where ``hi <= lo``."""
-    jax.lax.fori_loop(lo, hi, lambda b, c: (body(b), c)[1], 0)
+def _loop(runs, body):
+    """``body(block)`` for every block of ``runs`` (``[(lo, hi)]``, bounds
+    traced: the band's), one pass a minor block, none where ``hi <= lo``.
+    Several runs share ONE loop, whose counter is mapped onto them, so
+    the body is traced once however many runs there are."""
+    (lo, hi), *more = runs
+    if not more:
+        jax.lax.fori_loop(lo, hi, lambda b, c: (body(b), c)[1], 0)
+        return
+    ends, total = [], 0
+    for a, b in runs:
+        total = total + jnp.maximum(b - a, 0)
+        ends.append(total)
+
+    def nth(n):
+        block = runs[-1][0] + n - ends[-2]
+        for k in range(len(runs) - 2, -1, -1):
+            block = jnp.where(n < ends[k],
+                              runs[k][0] + n - (ends[k - 1] if k else 0),
+                              block)
+        return block
+
+    jax.lax.fori_loop(0, total, lambda n, c: (body(nth(n)), c)[1], 0)
+
+
+def band_passes(kernel, tile, Tq, Tk, causal):
+    """Passes of the inner loop one (batch, head) takes in ``kernel``
+    (``"fwd"``, ``"dq"``, ``"dkv"``) under ``tile``: the minor blocks its
+    grid steps visit, counted on the host from :func:`_band`."""
+    keys_own = kernel == "dkv"
+    own, other = (Tk, Tq) if keys_own else (Tq, Tk)
+    tile = _clip(tile, own, other)
+    total = 0
+    for i in range(pl.cdiv(own, tile.rows)):
+        for jm in range(pl.cdiv(other, tile.major)):
+            for runs in _band(i, jm, tile, own_len=own, other_len=other,
+                              causal=causal, keys_own=keys_own):
+                total += sum(max(int(b) - int(a), 0) for a, b in runs)
+    return total
 
 
 def _local(block, jm, tile):
@@ -294,10 +445,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             + _dot(p.astype(v.dtype), v, _NN)          # [rows, Dv]
         m_ref[:] = m_new
 
-    lo, split, hi = _band(qi, jm, tile, own_len=q_len, other_len=k_len,
+    plain, masked = _band(qi, jm, tile, own_len=q_len, other_len=k_len,
                           causal=causal, keys_own=False)
-    _loop(lo, split, functools.partial(step, masked=False))
-    _loop(split, hi, functools.partial(step, masked=True))
+    _loop(plain, functools.partial(step, masked=False))
+    _loop(masked, functools.partial(step, masked=True))
 
     @pl.when(jm == pl.num_programs(1) - 1)
     def _finalize():
@@ -311,11 +462,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[:] = lse.T[:1]                         # the column as a row
 
 
-def _resident_keys(tile, k_len, causal):
+def _resident(j, ranges, per_step):
+    """The major block grid step ``j`` names where its tile reaches the
+    two ``ranges`` of minor blocks (:func:`_bd_spans`): itself inside a
+    range, else the block already resident (the end of the range behind
+    it) or, before the first range, the first block it will need."""
+    (a, b), (a2, b2) = ranges
+    in_first = jnp.clip(j, a // per_step, jnp.maximum(b - 1, a) // per_step)
+    return jnp.where((b2 > a2) & (j >= a2 // per_step),
+                     jnp.minimum(j, (b2 - 1) // per_step), in_first)
+
+
+def _resident_keys(tile, q_len, k_len, causal):
     """Index map of K and V in the query-tile grids: a step above the band
     names the last major block the band of its tile reaches, which is the
     block already resident, so the pipeline copies nothing for it."""
     per_step = tile.major // tile.minor
+    if isinstance(causal, BlockDiffusion):
+        return lambda i, j: (_resident(j, _bd_spans(
+            i, tile, causal, own_len=q_len, other_len=k_len,
+            keys_own=False)[2], per_step), 0)
     return lambda i, j: (jnp.minimum(
         j, (_keys_needed(i, tile, k_len, causal) - 1) // per_step), 0)
 
@@ -324,7 +490,7 @@ def _fwd_one_head(q, k, v, *, scale, causal, tile, q_len, k_len, interpret):
     Tq, D = q.shape          # the score width (q and k)
     Tk, Dv = v.shape         # the value width (v and o)
     rows, major, _ = tile
-    kv = _resident_keys(tile, k_len, causal)
+    kv = _resident_keys(tile, q_len, k_len, causal)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           tile=tile, q_len=q_len, k_len=k_len),
@@ -381,10 +547,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         ds = p * (_dot(do_ref[:], v, _NT) - _lanes(dl_col[:], tile.minor))
         acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    lo, split, hi = _band(qi, jm, tile, own_len=q_len, other_len=k_len,
+    plain, masked = _band(qi, jm, tile, own_len=q_len, other_len=k_len,
                           causal=causal, keys_own=False)
-    _loop(lo, split, functools.partial(step, masked=False))
-    _loop(split, hi, functools.partial(step, masked=True))
+    _loop(plain, functools.partial(step, masked=False))
+    _loop(masked, functools.partial(step, masked=True))
 
     @pl.when(jm == pl.num_programs(1) - 1)
     def _finalize():
@@ -419,10 +585,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         dv_acc[:] += _dot(pt.astype(do.dtype), do, _NN)
         dk_acc[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    lo, split, hi = _band(kj, im, tile, own_len=k_len, other_len=q_len,
+    plain, masked = _band(kj, im, tile, own_len=k_len, other_len=q_len,
                           causal=causal, keys_own=True)
-    _loop(lo, split, functools.partial(step, masked=True))
-    _loop(split, hi, functools.partial(step, masked=False))
+    _loop(masked, functools.partial(step, masked=True))
+    _loop(plain, functools.partial(step, masked=False))
 
     @pl.when(im == pl.num_programs(1) - 1)
     def _finalize():
@@ -435,7 +601,7 @@ def _dq_one_head(q, k, v, do, lse, dl, *, scale, causal, tile, q_len, k_len,
     Tq, D = q.shape          # the score width (q, k, dq)
     Tk, Dv = v.shape         # the value width (v, dO)
     rows, major, _ = tile
-    kv = _resident_keys(tile, k_len, causal)
+    kv = _resident_keys(tile, q_len, k_len, causal)
     own = lambda width: pl.BlockSpec((rows, width), lambda i, j: (i, 0))
     row = pl.BlockSpec((1, rows), lambda i, j: (0, i))
     return pl.pallas_call(
@@ -462,10 +628,15 @@ def _dkv_one_head(q, k, v, do, lse, dl, *, scale, causal, tile, q_len, k_len,
     per_step = major // minor
     # kv-outer grid: index maps see (key tile, major query block); a step
     # the band has not reached yet names the first block it will need
-    first = ((lambda j: (j * rows) // minor // per_step) if causal
-             else (lambda j: 0))
-    qs = lambda j, i: (jnp.minimum(jnp.maximum(i, first(j)),
-                                   Tq // major - 1), 0)
+    if isinstance(causal, BlockDiffusion):
+        qs = lambda j, i: (_resident(i, _bd_spans(
+            j, tile, causal, own_len=k_len, other_len=q_len,
+            keys_own=True)[2], per_step), 0)
+    else:
+        first = ((lambda j: (j * rows) // minor // per_step) if causal
+                 else (lambda j: 0))
+        qs = lambda j, i: (jnp.minimum(jnp.maximum(i, first(j)),
+                                       Tq // major - 1), 0)
     own = lambda width: pl.BlockSpec((rows, width), lambda j, i: (j, 0))
     # logsumexp / delta: one [1, minor] row a minor block, picked by its
     # leading index in the body
@@ -498,6 +669,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     blocks of the other). The tiles come from :func:`flash_schedule`
     unless given: integers ``block_q`` / ``block_k`` mean one such score
     tile a grid step in every kernel, a :class:`Schedule` means itself.
+    ``causal`` is the mask kind: ``False``, ``True`` or a
+    :class:`BlockDiffusion` (module docstring).
     Ragged sequence lengths are padded here and masked in-kernel. The
     score width ``Dqk`` may differ from the value width ``Dv`` (latent
     attention: keys wider than values). On hardware ``Dv`` must fill
@@ -631,6 +804,13 @@ def _backward(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k, schedule):
     interpret = _use_interpret()
     _require_hw_head_dim(v.shape[-1], interpret)
+    if isinstance(causal, BlockDiffusion):
+        length, block = causal
+        if length % block or not q.shape[1] == k.shape[1] == 2 * length:
+            raise ValueError(
+                f"{causal}: queries and keys are [x_0 ; x_t], 2 * length "
+                f"positions each in whole blocks (got {q.shape[1]} and "
+                f"{k.shape[1]})")
     out, lse = _forward(q, k, v, causal=causal, scale=scale,
                         block_q=block_q, block_k=block_k, schedule=schedule,
                         interpret=interpret)
@@ -645,4 +825,5 @@ def _fa_bwd(causal, scale, block_q, block_k, schedule, res, g):
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
-__all__ = ["flash_attention", "flash_schedule", "Schedule", "Tile"]
+__all__ = ["flash_attention", "flash_schedule", "band_passes",
+           "BlockDiffusion", "Schedule", "Tile"]

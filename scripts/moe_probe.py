@@ -93,15 +93,21 @@ def gmm_part(config, traffic, reps, seed):
 def rounds_part(man, workload, config, traffic, seed, rounds):
     import jax
 
-    from benchmarks.families import deepseek_v3_lm as family_costs
-
     family = importlib.import_module("benchmarks.families."
                                      + config["family"])
     cell = family.build(config, traffic, seed, man.reference(config))
-    tokens = cell.work_per_round["tokens"]
+    # whatever the family: the rows of one layer-step are what its
+    # grouped products' FLOPs were counted for (three products of
+    # 2 * rows * d * width), a round has the clients' steps, and the
+    # leading dense layers route nothing
+    rows = cell.shapes["kernels"]["moe_gmm_fwd"]["flops"] / (
+        6.0 * int(config["hidden_size"])
+        * int(config["moe_intermediate_size"]))
+    steps = sum(-(-n // int(traffic["batch_size"])) for n in cell.ns) \
+        * int(traffic["epochs"])
     layers = int(config.get("n_layer", config["num_hidden_layers"])) \
-        - int(config["first_k_dense_replace"])
-    expected = tokens * layers * family_costs.held_rows_per_token(config)
+        - int(config.get("first_k_dense_replace", 0))
+    expected = rows * steps * layers
     out = {"expected_rows_held": expected, "rounds": []}
     for _ in range(rounds):
         a = time.perf_counter()

@@ -60,12 +60,12 @@ def _flash_calls(compiled):
             for n, sig in calls]
 
 
-def _attention_fwd_bwd(one_chip, b, t, heads, dqk, dv):
+def _attention_fwd_bwd(one_chip, b, t, heads, dqk, dv, mask=True):
     shape = lambda d: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
                                            sharding=one_chip)
 
     def loss(q, k, v):   # no blocks given: the tiles flash_schedule chooses
-        out = pa.flash_attention(q, k, v, True, dqk ** -0.5)
+        out = pa.flash_attention(q, k, v, mask, dqk ** -0.5)
         return jnp.sum(out.astype(jnp.float32))
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -109,6 +109,32 @@ def test_flash_kernels_compile_at_other_lengths(one_chip, hardware_path,
     calls = _flash_calls(_attention_fwd_bwd(one_chip, 1, t, 2, dqk, 128))
     assert sorted(n for n, _ in calls) == ["flash_bwd_dkv", "flash_bwd_dq",
                                            "flash_fwd"]
+
+
+@pytest.mark.parametrize("length,block", [(2048, 4), (2048, 32),
+                                          (1280, 4)],
+                         ids=["L2048_b4", "L2048_b32", "L1280_ragged"])
+def test_flash_kernels_compile_under_the_block_diffusion_mask(
+        one_chip, hardware_path, length, block):
+    """``sdar-30b-a3b-ep8``'s attention, one sequence as ``[x_0 ; x_t]``
+    (4,096 positions at L 2048), 32 heads of 128, under the two-range
+    band: the forward and both backward kernels, still three Pallas calls
+    by the names and output signatures the benchmark finds them by, the
+    whole streamed sequence resident (an 8 x 1 grid a head); a block of
+    32 and an ``L`` that is no multiple of the tile (a tile with rows of
+    both copies) compile too."""
+    b, t, heads, d = 1, 2 * length, 32, 128
+    calls = _flash_calls(_attention_fwd_bwd(
+        one_chip, b, t, heads, d, d, pa.BlockDiffusion(length, block)))
+    # (a batch of one: the compiler drops the axis)
+    assert sorted(calls) == sorted([
+        ("flash_fwd", f"(bf16[{heads},{t},{d}], f32[{heads},1,{t}])"),
+        ("flash_bwd_dq", f"bf16[{heads},{t},{d}]"),
+        ("flash_bwd_dkv", f"(bf16[{heads},{t},{d}], bf16[{heads},{t},{d}])")])
+    if length == 2048:
+        tile = pa.Tile(rows=512, major=4096, minor=512)
+        assert pa.flash_schedule(t, t, d, d, jnp.bfloat16) \
+            == (pa.Schedule(tile, tile, tile), (8, 8, 8))
 
 
 def test_flash_refuses_a_value_width_the_hardware_cannot_run(hardware_path):
