@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.core.trainer import TrainSpec
+from fedml_tpu.ops.cross_entropy import softmax_cross_entropy_with_stats
 
 
 def _apply_model(model, state, x, rng, train, with_sown=False,
@@ -73,7 +74,7 @@ def make_classification_spec(model, example_x, num_classes=None,
                              aux_loss_weight=0.01, lane_lowering=None):
     """Softmax cross-entropy classification over ``[B, C]`` logits.
 
-    Applying log_softmax to whatever the model emits reproduces the reference
+    Applying the softmax to whatever the model emits reproduces the reference
     LR quirk automatically (sigmoid output fed to torch CrossEntropyLoss,
     ``lr.py:10-11``). Metrics are *sums* (loss-weighted, correct, count);
     divide on host -- matching the reference's test accumulation
@@ -87,12 +88,12 @@ def make_classification_spec(model, example_x, num_classes=None,
         return _init_state(model, example_x, rng)
 
     def _loss_and_metrics(logits, y, mask):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        ll = jnp.take_along_axis(logp, y[:, None].astype(jnp.int32), axis=1)[:, 0]
+        ll, pred = softmax_cross_entropy_with_stats(
+            logits.astype(jnp.float32), y)
         per_sample = -ll
         count = jnp.sum(mask)
         loss = jnp.sum(per_sample * mask) / jnp.maximum(count, 1.0)
-        correct = jnp.sum((jnp.argmax(logits, axis=-1) == y) * mask)
+        correct = jnp.sum((pred == y) * mask)
         metrics = {"loss_sum": jnp.sum(per_sample * mask),
                    "correct": correct, "count": count}
         return loss, metrics
@@ -143,12 +144,11 @@ def make_seq_classification_spec(model, example_x, ignore_index=0,
 
     def _loss_and_metrics(logits, y, mask):
         tok_mask = (y != ignore_index).astype(jnp.float32) * mask[:, None]
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        ll = jnp.take_along_axis(logp, y[..., None].astype(jnp.int32),
-                                 axis=-1)[..., 0]
+        ll, pred = softmax_cross_entropy_with_stats(
+            logits.astype(jnp.float32), y)
         count = jnp.sum(tok_mask)
         loss = jnp.sum(-ll * tok_mask) / jnp.maximum(count, 1.0)
-        correct = jnp.sum((jnp.argmax(logits, axis=-1) == y) * tok_mask)
+        correct = jnp.sum((pred == y) * tok_mask)
         return loss, {"loss_sum": jnp.sum(-ll * tok_mask),
                       "correct": correct, "count": count}
 
@@ -207,14 +207,14 @@ def make_block_diffusion_lm_spec(model, example_x, block_length, mask_id,
     def _loss_and_metrics(logits, x, y, mask):
         weight = y.astype(jnp.float32) * mask[:, None]
         masked = (weight > 0).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, x[..., None].astype(jnp.int32),
-                                   axis=-1)[..., 0]
+        ll, pred = softmax_cross_entropy_with_stats(
+            logits.astype(jnp.float32), x)
+        nll = -ll
         rows = jnp.sum(mask)
         loss = jnp.sum(nll * weight) / jnp.maximum(rows * length, 1.0)
         return loss, {
             "loss_sum": jnp.sum(nll * masked), "count": jnp.sum(masked),
-            "correct": jnp.sum((jnp.argmax(logits, axis=-1) == x) * masked),
+            "correct": jnp.sum((pred == x) * masked),
             "bd_positions": 2.0 * length * rows}
 
     def loss_fn(state, batch, rng, train):
@@ -267,12 +267,11 @@ def make_segmentation_spec(model, example_x, num_classes,
         pix_mask = ((y != ignore_index) & (y >= 0) &
                     (y < num_classes)).astype(jnp.float32)
         pix_mask = pix_mask * mask.reshape(mask.shape + (1,) * (y.ndim - 1))
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        y_safe = jnp.clip(y, 0, logits.shape[-1] - 1)
-        ll = jnp.take_along_axis(logp, y_safe[..., None], axis=-1)[..., 0]
+        # (an ignore label outside the classes hits no column of the op)
+        ll, pred = softmax_cross_entropy_with_stats(
+            logits.astype(jnp.float32), y)
         count = jnp.sum(pix_mask)
         loss = jnp.sum(-ll * pix_mask) / jnp.maximum(count, 1.0)
-        pred = jnp.argmax(logits, axis=-1)
         correct = jnp.sum((pred == y) * pix_mask)
         cm = confusion_matrix(jnp.where(pix_mask > 0, y, -1), pred,
                               num_classes)

@@ -17,6 +17,9 @@ net-new long-context layer the TPU rebuild makes first-class:
 - :mod:`fedml_tpu.ops.grouped_matmul` -- the grouped matrix product over the
   experts a chip holds (rows sorted by expert, no drop), forward and backward
   (imported as a module: its function has the module's name).
+- :mod:`fedml_tpu.ops.cross_entropy` -- softmax cross-entropy with the
+  accuracy counter's arg-max as one op with its own backward: no
+  ``[..., vocab]`` array of log-probabilities is written or kept.
 """
 
 from fedml_tpu.ops.attention import blockwise_attention, mha

@@ -1,0 +1,240 @@
+"""Name the device operations of a cell's client-update program without
+a chip (PERF.md section 5, PR 32).
+
+A ledger ``breakdown`` lists a traced round's longest device operations
+by XLA's instruction names (``multiply_reduce_fusion.36``, ``fusion.720``).
+This script compiles the same program -- the bucketed stream's
+``chunk_fn`` over the cell's model and spec, from the cell's
+configuration and traffic files, shapes only -- for a TPU v5e that is
+described and not attached, and prints for each name the instruction's
+``op_name`` (the JAX source path that made it), its output shape and its
+large operands. The numbering is the compiler's own: for the GPT-2 cells
+nine of the ledger's ten names stood in the compile to the digit (PR
+32); where one is off by a few, ``--like`` finds its neighbours by
+``op_name``.
+
+    python3 scripts/hlo_names.py --workload cgpt1.3b-silo4-long
+    python3 scripts/hlo_names.py --workload kanana2-a3b-ep8-silo2-long \
+        --names fusion.2566,add_select_fusion.106
+    python3 scripts/hlo_names.py --workload cgpt1.3b-silo4-long --like head/
+
+Default names: the ten of the workload's newest ledger line that has a
+``breakdown``. Nothing runs: this says what an operation IS, never how
+long it takes. The first line also sums the compiler's own
+``estimated_cycles`` over the program's instructions: not a time either,
+but a cheap first word on a change (it ranked four forms of the loss as
+the chip had, and agrees in sign with the 0.4 % that ``kanana2`` lost;
+PERF.md, PR 32). 20-90 s on a CPU core. ``--text <file>`` keeps
+the whole optimized HLO.
+"""
+
+import argparse
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+#: an operand or output is "large" from here on (bytes)
+LARGE = 32 << 20
+_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
+          "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8}
+_SHAPE = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+
+
+def spec_of(config, traffic, reference):
+    """The cell's model and train spec as its family builds them
+    (``benchmarks/families/<family>.py`` ``build``), without weights."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.algorithms import specs
+
+    dtype = jnp.dtype(config["as_run"]["compute_dtype"])
+    example = jnp.zeros((1, int(traffic["seq_len"])), jnp.int32)
+    family = config["family"]
+    if family == "gpt2_lm":
+        from fedml_tpu.models.transformer import TransformerLM
+
+        d = int(config["n_embd"])
+        model = TransformerLM(
+            vocab_size=int(config["vocab_size"]),
+            n_layers=int(config["n_layer"]), n_heads=int(config["n_head"]),
+            d_model=d, max_len=int(config["n_positions"]),
+            mlp_ratio=int(config["n_inner"]) // d, dtype=dtype)
+        return specs.make_seq_classification_spec(model, example, name="lm")
+    from fedml_tpu.models import deepseek_v3 as dsv3
+
+    decoder = dsv3.DecoderConfig.from_dict(config)
+    if family == "deepseek_v3_lm":
+        return specs.make_seq_classification_spec(
+            dsv3.DeepseekV3LM(decoder, dtype=dtype), example, name="lm")
+    if family == "sdar_moe_lm":
+        return specs.make_block_diffusion_lm_spec(
+            dsv3.DecoderLM(decoder, dtype=dtype), example,
+            int(config["block_length"]), reference.mask_id(config))
+    raise KeyError(f"no builder for family {family!r}")
+
+
+def compile_chunk_program(spec, traffic, steps, device=None):
+    """The stream's ``chunk_fn`` (``make_streamed_client_update`` under the
+    lane ``vmap``, the payload sum behind it) compiled for ``device`` of a
+    described v5e, at a bucket of ``steps`` local steps. The caller has
+    steered the Pallas kernels off interpret mode (``main`` below; a test
+    by its own fixture)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from fedml_tpu.parallel.engine import (BucketedStreamRunner,
+                                           ClientUpdateConfig)
+
+    if device is None:
+        from jax.experimental import topologies
+
+        device = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0]
+    on = SingleDeviceSharding(device)
+    lanes, b = int(traffic["client_chunk"]), int(traffic["batch_size"])
+    runner = BucketedStreamRunner(
+        spec, ClientUpdateConfig(optimizer=traffic.get("optimizer", "sgd"),
+                                 lr=float(traffic["lr"]),
+                                 weight_decay=float(traffic.get("wd", 0.0))),
+        client_chunk=lanes, batch_size=b, epochs=int(traffic["epochs"]),
+        edges=(steps,))
+    shaped = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on), tree)
+    state = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    x = jnp.zeros((lanes, steps, b, int(traffic["seq_len"])), jnp.int32)
+    # the batch's "y" as the spec's loss takes it: ids, or the block
+    # diffusion's float32 weights
+    y = x.astype(jnp.float32) if spec.name == "block_diffusion_lm" else x
+    args = (state,
+            {"x": x, "y": y, "mask": jnp.zeros((lanes, steps, b))},
+            jnp.zeros((lanes,), jnp.int32), jnp.zeros((), jnp.int32),
+            jax.random.split(jax.random.PRNGKey(0), lanes))
+    return runner._chunk_fn.lower(*shaped(args)).compile()
+
+
+def _nbytes(dtype, dims):
+    n = _BYTES.get(dtype, 4)
+    for d in filter(None, dims.split(",")):
+        n *= int(d)
+    return n
+
+
+def instructions(text):
+    """``{name: (output type, opcode, operand names, op_name, estimated
+    cycles)}`` of every instruction of an optimized HLO module's text
+    that stands in a computation of its own right (the entry, a loop's
+    body): what a device trace shows as one event. The insides of fusions
+    are left out. The cycles are the TPU compiler's own estimate (0 where
+    it gives none): no timing, but their sum ranked four forms of the
+    GPT-2 loss as the chip did (PERF.md, PR 32)."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", text))
+    out, inside = {}, False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1) in fused
+        m = not inside and re.match(
+            r"\s+(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)", line)
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        cycles = re.search(r'"estimated_cycles":"(\d+)"', rest)
+        out[name] = (result, opcode,
+                     re.findall(r"%([\w.\-]+)", rest.split(")")[0]),
+                     op_name.group(1) if op_name else "",
+                     int(cycles.group(1)) if cycles else 0)
+    return out
+
+
+def describe(name, instrs):
+    result, opcode, operands, op_name, cycles = instrs[name]
+    plain = lambda s: re.sub(r"\{[^}]*\}", "", s)
+    large = [f"{d}[{dims}]" for o in operands if o in instrs
+             for d, dims in _SHAPE.findall(plain(instrs[o][0]))
+             if _nbytes(d, dims) >= LARGE]
+    return {"name": name, "opcode": opcode, "op_name": op_name,
+            "output": plain(result), "large_operands": large,
+            "estimated_cycles": cycles}
+
+
+def ledger_names(workload, path=os.path.join(ROOT, "PERF_LEDGER.jsonl")):
+    """The ten longest device operations of the workload's newest ledger
+    line that has a ``breakdown``, the event suffix (``_fusion``,
+    ``_convert``, ``_custom-call``) taken off."""
+    best = None
+    with open(path, encoding="utf-8") as f:
+        for line in f:
+            rec = json.loads(line)
+            ops = (rec.get("breakdown") or {}).get("device_ops")
+            if rec.get("workload") == workload and ops:
+                best = ops
+    if best is None:
+        raise SystemExit(f"no ledger line of {workload} has a breakdown: "
+                         "give --names")
+    return [re.sub(r"_(fusion|convert|custom-call|copy|while)$", "", n)
+            for n, _ in best]
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(
+        description=__doc__, formatter_class=argparse.RawTextHelpFormatter)
+    ap.add_argument("--workload", default="cgpt1.3b-silo4-long")
+    ap.add_argument("--names", default=None,
+                    help="comma list of instruction names (default: the "
+                         "ledger's ten)")
+    ap.add_argument("--like", default=None,
+                    help="also list every instruction whose op_name "
+                         "holds this text")
+    ap.add_argument("--steps", type=int, default=8,
+                    help="the bucket edge compiled (shapes of the batches; "
+                         "the loop body is the same at every edge)")
+    ap.add_argument("--text", default=None,
+                    help="write the optimized HLO here")
+    args = ap.parse_args(argv)
+    # before JAX is imported: no chip is asked for, the compiler logs nowhere
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from benchmarks.manifest import Manifest
+    from fedml_tpu.ops import grouped_matmul as gm
+    from fedml_tpu.ops import pallas_attention as pa
+
+    # the kernels ask the default backend whether to interpret; it is the
+    # CPU's here, and the program wanted is the chip's
+    pa._use_interpret = gm._use_interpret = lambda: False
+    man = Manifest(ROOT)
+    entry = man.cell(args.workload)
+    config = man.config(entry["config"])
+    traffic = man.traffic(entry["traffic"])
+    names = args.names.split(",") if args.names \
+        else ledger_names(args.workload)
+    compiled = compile_chunk_program(
+        spec_of(config, traffic, man.reference(config)), traffic, args.steps)
+    text = compiled.as_text()
+    if args.text:
+        with open(args.text, "w", encoding="utf-8") as f:
+            f.write(text)
+    instrs = instructions(text)
+    mem = compiled.memory_analysis()
+    print(json.dumps({"workload": args.workload, "instructions": len(instrs),
+                      "estimated_cycles": sum(r[4] for r in instrs.values()),
+                      "argument_bytes": mem.argument_size_in_bytes,
+                      "output_bytes": mem.output_size_in_bytes,
+                      "temp_bytes": mem.temp_size_in_bytes}))
+    for name in names:
+        print(json.dumps(describe(name, instrs) if name in instrs
+                         else {"name": name, "missing": True}))
+    if args.like:
+        for name, rec in instrs.items():
+            if args.like in rec[3] and rec[1] != "parameter":
+                print(json.dumps(describe(name, instrs)))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -171,3 +171,63 @@ def test_bench_lane_conv_smoke():
                  "shared"):
         assert (cand, "fwd") in done and (cand, "fwd+bwd") in done, (
             cand, done)
+
+
+def _hlo_names():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "hlo_names", os.path.join(REPO, "scripts", "hlo_names.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+_HLO = """HloModule jit_f, entry_computation_layout={(f32[8,16]{1,0})->f32[8]{0}}
+
+%fused_computation (param_0.1: f32[8,16]) -> f32[8,16] {
+  %param_0.1 = f32[8,16]{1,0} parameter(0)
+  ROOT %exp.9 = f32[8,16]{1,0} exponential(%param_0.1), metadata={op_name="jit(f)/inside"}
+}
+
+ENTRY %main.7 (Arg_0.1: f32[8,16]) -> f32[8] {
+  %Arg_0.1 = f32[8,16]{1,0:T(8,128)} parameter(0)
+  %fusion.3 = f32[8,16]{1,0:T(8,128)} fusion(%Arg_0.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/while/body/exp" stack_frame_id=1}, backend_config={"window_config":{"estimated_cycles":"3720","iteration_bounds":["1"]}}
+  ROOT %reduce_fusion.1 = (f32[8]{0}, s32[8]{0}) fusion(%fusion.3, %Arg_0.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(f)/while/body/reduce_sum"}
+}
+"""
+
+
+def test_hlo_names_reads_an_optimized_module():
+    """``scripts/hlo_names.py``'s reading of an optimized HLO text: the
+    instructions a device trace shows (the insides of fusions left out),
+    each with its ``op_name``, output and operands by name."""
+    names = _hlo_names()
+    instrs = names.instructions(_HLO)
+    assert sorted(instrs) == ["Arg_0.1", "fusion.3", "reduce_fusion.1"]
+    assert instrs["fusion.3"][1:] == ("fusion", ["Arg_0.1"],
+                                      "jit(f)/while/body/exp", 3720)
+    names.LARGE = 256
+    assert names.describe("reduce_fusion.1", instrs) == {
+        "name": "reduce_fusion.1", "opcode": "fusion",
+        "op_name": "jit(f)/while/body/reduce_sum",
+        "output": "(f32[8], s32[8])",
+        "large_operands": ["f32[8,16]", "f32[8,16]"],
+        "estimated_cycles": 0}
+
+
+def test_hlo_names_takes_its_names_from_the_newest_breakdown(tmp_path):
+    names = _hlo_names()
+    ledger = tmp_path / "ledger.jsonl"
+    ledger.write_text("\n".join(json.dumps(rec) for rec in [
+        {"workload": "a", "breakdown": {"device_ops": [["old.1_fusion", 1]]}},
+        {"workload": "a", "breakdown": {"device_ops": [
+            ["fusion.720_fusion", 0.3], ["convert.9_convert", 0.2],
+            ["vmap_flash_fwd__.2_custom-call", 0.1]]}},
+        {"workload": "a"},
+        {"workload": "b", "breakdown": {"device_ops": [["x_fusion", 1]]}},
+    ]))
+    assert names.ledger_names("a", str(ledger)) == [
+        "fusion.720", "convert.9", "vmap_flash_fwd__.2"]
+    with pytest.raises(SystemExit, match="no ledger line"):
+        names.ledger_names("c", str(ledger))

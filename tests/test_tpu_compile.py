@@ -6,6 +6,7 @@ say nothing of results or times; a compile that passes is not a chip run.
 All such compiles live in this one file: the topology is described
 inside a fixture, by the one worker that is given the file."""
 
+import importlib.util
 import os
 import re
 
@@ -213,3 +214,58 @@ def test_fold_programs_keep_their_arithmetic_and_allocate_nothing(
         assert count("subtract") >= 9 and count("multiply") >= 6
     else:
         assert (count("add"), count("subtract")) == (adds, subtracts)
+
+
+def _load_script(name):
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_gpt2_client_update_passes_over_the_logits_once(topo,
+                                                        hardware_path):
+    """``cgpt1.3b-silo4-long``'s client-update program (the stream's
+    ``chunk_fn``: ``make_streamed_client_update`` under the lane ``vmap``;
+    d 2048, 4 layers, vocabulary 50257, batch 2 x 2048, from the
+    benchmark's own files through ``scripts/hlo_names.py``) compiled for
+    the described v5e: the counter that says the one cross-entropy op
+    engaged. Exactly ONE instruction writes an f32 array of the logits'
+    size (the head's forward product; ``log_softmax`` kept a second one
+    for its backward) and it is read by four: the loss's one forward
+    pass, the head's two gradient products, which form ``dlogits`` inside
+    their fusions, and the bias gradient's sum."""
+    from benchmarks.manifest import Manifest
+
+    names = _load_script("hlo_names")
+    man = Manifest()
+    entry = man.cell("cgpt1.3b-silo4-long")
+    config, traffic = man.config(entry["config"]), \
+        man.traffic(entry["traffic"])
+    compiled = names.compile_chunk_program(
+        names.spec_of(config, traffic, man.reference(config)), traffic,
+        steps=8, device=topo.devices[0])
+    instrs = names.instructions(compiled.as_text())
+    plain = lambda s: re.sub(r"\{[^}]*\}", "", s)
+    logits = re.compile(r"f32\[(1,)*2,2048,50257\]")   # not [1,2048,50257]
+    moves = {n: rec for n, rec in instrs.items() if rec[1] not in (
+        "get-tuple-element", "bitcast", "parameter", "tuple")}
+    writers = [n for n, rec in moves.items() if logits.search(plain(rec[0]))]
+    assert len(writers) == 1, writers
+    assert instrs[writers[0]][3].endswith("jvp(TransformerLM)/head/"
+                                          "dot_general")
+    reads = lambda rec: any(
+        o in instrs and logits.fullmatch(plain(instrs[o][0]))
+        for o in rec[2])
+    readers = {n: rec[3].split("body/")[-1] for n, rec in moves.items()
+               if reads(rec)}
+    assert len(readers) <= 4, readers
+    products = [n for n, op in readers.items()
+                if op == "transpose(jvp(TransformerLM))/head/dot_general"]
+    assert len(products) == 2, readers
+    # ISSUE 32's bound. The compiler's temp is a schedule's, not a count of
+    # arrays: 3.45 GB here against the parent's 2.62 with ``logp`` in it
+    # and 1.80 for three separate reductions (PERF.md, PR 32)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
