@@ -314,18 +314,18 @@ def check_flash_attention(sz: Sizes):
 
     out = {}
     if not _use_interpret():
-        # compiled kernels take head dims that fill 128 lanes only: the
-        # clean error is why --model transformer (4 heads of 64) cannot
-        # start on a TPU, and why the LM below is built with heads of 128
-        small = jnp.zeros((1, 16, 1, 64), jnp.bfloat16)
+        # compiled kernels take head dims that fill 128 lanes, or 64
+        # (since PR 34); any other width gets the clean error, not a
+        # Mosaic layout failure
+        small = jnp.zeros((1, 16, 1, 32), jnp.bfloat16)
         try:
             flash_attention(small, small, small)
         except ValueError as e:
-            check("multiple of 128" in str(e), f"D=64 raised: {e}")
+            check("multiple of 128" in str(e), f"D=32 raised: {e}")
         else:
-            raise SmokeError("flash_attention compiled at head_dim 64 "
+            raise SmokeError("flash_attention compiled at head_dim 32 "
                              "without the 'multiple of 128' error")
-        out["D64_raises"] = True
+        out["D32_raises"] = True
     B, H, D = sz.attn_batch, sz.attn_heads, sz.head_dim
     for T in sz.attn_seq_lens:
         ks = jax.random.split(jax.random.PRNGKey(T), 3)

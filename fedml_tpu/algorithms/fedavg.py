@@ -19,7 +19,8 @@ import numpy as np
 from fedml_tpu.algorithms.specs import block_diffusion_counters
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.perfmon import get_perf_monitor
-from fedml_tpu.observability.routing import note_routing, routing_counters
+from fedml_tpu.observability.routing import (layer_mix_counters,
+                                              note_routing, routing_counters)
 from fedml_tpu.observability.tracing import get_tracer
 from fedml_tpu.utils.profiling import end_of_round_sync
 from fedml_tpu.parallel.engine import ClientUpdateConfig, make_eval_fn
@@ -247,6 +248,7 @@ class FedAvgAPI:
                 # "fold" says where the payload sums were combined
                 sp.set(fold=info["fold"],
                        **routing_counters(info["metrics"]),
+                       **layer_mix_counters(info["metrics"]),
                        **block_diffusion_counters(info["metrics"]))
         self._last_info = info
         with tracer.span("aggregate"):

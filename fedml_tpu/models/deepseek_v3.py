@@ -1,5 +1,5 @@
-"""A decoder read from a configuration: the ``deepseek_v3`` family and
-the ``sdar_moe`` family.
+"""A decoder read from a configuration: the ``deepseek_v3``, ``sdar_moe``
+and ``lfm2_moe`` families.
 
 Token ids ``[B, T]`` -> logits ``[B, T, vocab]`` (the surface
 :func:`fedml_tpu.algorithms.specs.make_seq_classification_spec` takes),
@@ -19,6 +19,13 @@ built from a configuration dict with the key names of the family's public
   query heads over ``num_key_value_heads`` key/value heads of an explicit
   ``head_dim`` (query head ``h`` reads key/value head ``h // group``),
   RMSNorm on every head's q and k, rotate-half rotary positions;
+- ``lfm2_moe``: the token mixer is a property of the LAYER
+  (``layer_types``, one entry a layer as run): ``full_attention`` is
+  :class:`GroupedQueryAttention`, ``conv`` is :class:`GatedShortConv`
+  (an in-projection to the thirds ``B``, ``C``, ``u``, a depthwise
+  causal convolution of ``conv_L_cache`` taps over ``B * u`` gated by
+  ``C``, an out-projection: :mod:`fedml_tpu.ops.short_conv`); the other
+  families have no list and every layer takes the family's attention;
 - a gated (SwiGLU) MLP in the first ``first_k_dense_replace`` layers and
   :class:`RoutedExperts` in the others (``sdar_moe``: in every layer).
 
@@ -50,7 +57,9 @@ runs on every token.
 
 Counters of the routing are sown into the ``metrics`` collection, one
 value a layer and step (``fedml_tpu.observability.routing`` makes the
-round's series of them).
+round's series of them); a decoder with ``layer_types`` also sows the
+positions each kind of mixer ran (``conv_layer_positions``,
+``attn_layer_positions``).
 """
 
 from __future__ import annotations
@@ -65,6 +74,7 @@ import jax.numpy as jnp
 
 from fedml_tpu.ops.grouped_matmul import grouped_matmul
 from fedml_tpu.ops.pallas_attention import BlockDiffusion, flash_attention
+from fedml_tpu.ops.short_conv import gated_short_conv
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -73,8 +83,11 @@ _HI = jax.lax.Precision.HIGHEST
 class DecoderConfig:
     """The families' ``config.json`` keys this decoder reads, under their
     published names (``deepseek_v3``'s; ``from_dict`` maps ``sdar_moe``'s
-    onto them, and is the one place that knows a family by name: the
-    modules below read features). ``attention`` (``latent`` or
+    and ``lfm2_moe``'s onto them, and is the one place that knows a
+    family by name: the modules below read features). ``layer_types``
+    (``lfm2_moe``) names each layer's token mixer over the depth AS RUN,
+    ``conv`` or ``full_attention``; ``None`` means the family's attention
+    in every layer. ``attention`` (``latent`` or
     ``grouped``, from which keys the file has), ``router_experts`` and
     ``experts_held`` are this repo's: the router's width where
     ``n_routed_experts`` counts only the experts held (a benchmark
@@ -99,12 +112,15 @@ class DecoderConfig:
     first_k_dense_replace: int = 1
     scoring_func: str = "sigmoid"
     norm_topk_prob: bool = True
+    norm_topk_eps: float = 0.0
     routed_scaling_factor: float = 1.0
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     router_experts: Optional[int] = None
     experts_held: Optional[Tuple[int, int]] = None
     block_length: Optional[int] = None
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_L_cache: int = 0
 
     #: key -> the values this decoder computes, by family
     _COMPUTED = {
@@ -120,13 +136,24 @@ class DecoderConfig:
             "mlp_only_layers": ([], ()), "use_sliding_window": (False,),
             "sliding_window": (None,), "layer_types": (None,),
             "hidden_act": ("silu",), "attention_bias": (False,),
+            "tie_word_embeddings": (False,)},
+        "lfm2_moe": {
+            "conv_bias": (False,), "use_expert_bias": (True,),
+            "rope_scaling": (None,), "block_length": (None,),
+            "hidden_act": ("silu",), "attention_bias": (False,),
             "tie_word_embeddings": (False,)}}
 
     _NEEDED = {
         "deepseek_v3": ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
                         "kv_lora_rank", "intermediate_size",
                         "n_shared_experts"),
-        "sdar_moe": ("head_dim", "num_key_value_heads", "num_experts")}
+        "sdar_moe": ("head_dim", "num_key_value_heads", "num_experts"),
+        "lfm2_moe": ("layer_types", "conv_L_cache", "num_dense_layers",
+                     "num_key_value_heads", "num_experts", "norm_eps",
+                     "intermediate_size")}
+
+    #: a layer's token mixer, by its entry in ``layer_types``
+    MIXERS = ("conv", "full_attention")
 
     @classmethod
     def from_dict(cls, cfg, **overrides):
@@ -154,9 +181,36 @@ class DecoderConfig:
             cfg.setdefault("n_routed_experts", cfg["num_experts"])
             cfg.update(first_k_dense_replace=0, n_shared_experts=0,
                        scoring_func="softmax", routed_scaling_factor=1.0)
+        if family == "lfm2_moe":
+            # the router RoutedExperts has (sigmoid, a choice-only bias,
+            # renormalised over sum + 1e-6, scaled), dense FFN in the
+            # leading num_dense_layers, no shared expert, heads of
+            # hidden / heads where the file gives no head_dim
+            cfg.setdefault("router_experts", cfg["num_experts"])
+            cfg.setdefault("n_routed_experts", cfg["num_experts"])
+            cfg.setdefault("head_dim", cfg["hidden_size"]
+                           // cfg["num_attention_heads"])
+            cfg.setdefault("norm_topk_eps", 1e-6)
+            cfg.update(first_k_dense_replace=cfg["num_dense_layers"],
+                       n_shared_experts=0, scoring_func="sigmoid",
+                       rms_norm_eps=cfg["norm_eps"],
+                       # the depth as run, where a file cuts it
+                       layer_types=cfg.get("layer_types_as_run",
+                                           cfg["layer_types"]))
         cfg["attention"] = "latent" if "kv_lora_rank" in cfg else "grouped"
         if "n_layer" in cfg:  # the depth as run, where a file cuts it
             cfg["num_hidden_layers"] = cfg["n_layer"]
+        if cfg.get("layer_types") is not None:
+            types = cfg["layer_types"] = tuple(cfg["layer_types"])
+            unknown = sorted(set(types) - set(cls.MIXERS))
+            if unknown:
+                raise NotImplementedError(
+                    f"{family} decoder: layer_types {unknown} are not "
+                    f"computed here (only {list(cls.MIXERS)})")
+            if len(types) != cfg["num_hidden_layers"]:
+                raise ValueError(
+                    f"{family} decoder: {len(types)} layer_types for "
+                    f"{cfg['num_hidden_layers']} layers as run")
         if cfg.get("experts_held") is not None:
             cfg["experts_held"] = tuple(int(v) for v in cfg["experts_held"])
         names = {f.name for f in dataclasses.fields(cls)}
@@ -258,10 +312,10 @@ def rotary_half(x, positions, theta):
 class GroupedQueryAttention(nn.Module):
     """``num_attention_heads`` query heads of ``head_dim`` over
     ``num_key_value_heads`` key/value heads, RMSNorm on each head's q and
-    k before the rotary turn. The key/value heads are REPEATED to the
-    query heads before the flash kernels (query head ``h`` reads head ``h
-    // group``; the gradient sums a group back): the kernels take one
-    key/value head a query head (PERF.md section 7)."""
+    k before the rotary turn. The flash kernels read key/value head ``h
+    // group`` for query head ``h`` themselves and sum a group's
+    gradient back (:func:`fedml_tpu.ops.pallas_attention.flash_attention`):
+    no repeated copy of keys or values is made."""
     cfg: DecoderConfig
     dtype: Any = jnp.float32
 
@@ -277,12 +331,35 @@ class GroupedQueryAttention(nn.Module):
         v = _dense(KV * D, self.dtype, "v_proj")(x).reshape(B, T, KV, D)
         q = rotary_half(head_norm("q_norm")(q), positions, c.rope_theta)
         k = rotary_half(head_norm("k_norm")(k), positions, c.rope_theta)
-        k, v = (jnp.repeat(a, H // KV, axis=2) for a in (k, v))
         with jax.named_scope("bd_attn" if isinstance(mask, BlockDiffusion)
                              else "attn"):
             att = flash_attention(q, k, v, mask, D ** -0.5)
         return _dense(x.shape[-1], self.dtype, "o_proj")(
             att.reshape(B, T, H * D))
+
+
+class GatedShortConv(nn.Module):
+    """LFM2's ``conv`` mixer: ``[B ; C ; u] = x W_in`` (thirds in that
+    order), a depthwise causal convolution of ``conv_L_cache`` taps over
+    ``B * u`` gated by ``C`` (:func:`gated_short_conv`: one op, float32
+    arithmetic), ``W_out``. No bias, no activation, no positions (the
+    mask and the rotary positions of the attention layers are not its)."""
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, mask=True, positions=None):
+        if mask is not True:
+            raise NotImplementedError(
+                f"GatedShortConv is causal and nothing else: got {mask!r}")
+        d = x.shape[-1]
+        bcu = _dense(3 * d, self.dtype, "in_proj")(x)
+        # a depthwise filter's fan-in is its taps, not the channels beside
+        # it: the scale the benchmark's seeded weights take too
+        taps = self.cfg.conv_L_cache
+        w = self.param("conv_kernel",
+                       nn.initializers.normal(taps ** -0.5), (d, taps))
+        return _dense(d, self.dtype, "out_proj")(gated_short_conv(bcu, w))
 
 
 @jax.custom_vjp
@@ -344,7 +421,10 @@ class RoutedExperts(nn.Module):
                 _, chosen = jax.lax.top_k(scores, k)
             weight = jnp.take_along_axis(scores, chosen, axis=-1)
             if c.norm_topk_prob:
-                weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+                total = jnp.sum(weight, axis=-1, keepdims=True)
+                if c.norm_topk_eps:
+                    total = total + c.norm_topk_eps
+                weight = weight / total
             weight = weight * c.routed_scaling_factor
             # every assignment gets a row, sorted by held expert; the
             # assignments to experts held elsewhere sort behind them all
@@ -380,15 +460,20 @@ class RoutedExperts(nn.Module):
         return routed
 
 
-#: ``DecoderConfig.attention`` -> the module and its scope in a trace
-_ATTENTION = {"latent": (LatentAttention, "mla"),
-              "grouped": (GroupedQueryAttention, "gqa")}
+#: a layer's token mixer -> the module, its scope in a trace and its name
+#: in the parameter tree (its norm is ``<name>_norm``); ``latent`` and
+#: ``grouped`` are ``DecoderConfig.attention``, what ``full_attention``
+#: (and every layer of a configuration without ``layer_types``) means
+_MIXERS = {"latent": (LatentAttention, "mla", "attn"),
+           "grouped": (GroupedQueryAttention, "gqa", "attn"),
+           "conv": (GatedShortConv, "short_conv", "conv")}
 
 
 class _DecoderLayer(nn.Module):
     cfg: DecoderConfig
     dense: bool
     dtype: Any = jnp.float32
+    mixer: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, mask=True, positions=None):
@@ -396,10 +481,14 @@ class _DecoderLayer(nn.Module):
         B, T, d = x.shape
         norm = lambda name: nn.RMSNorm(epsilon=c.rms_norm_eps,
                                        dtype=self.dtype, name=name)
-        attn, scope = _ATTENTION[c.attention]
+        mixer, scope, name = _MIXERS[
+            c.attention if self.mixer == "full_attention" else self.mixer]
         with jax.named_scope(scope):
-            x = x + attn(c, self.dtype, name="attn")(
-                norm("attn_norm")(x), mask, positions)
+            x = x + mixer(c, self.dtype, name=name)(
+                norm(name + "_norm")(x), mask, positions)
+        if c.layer_types:
+            # which mix of layers a step ran: positions through this mixer
+            _sum_metric(self, f"{name}_layer_positions", B * T)
         h = norm("ffn_norm")(x)
         if self.dense:
             return x + GatedMLP(c.intermediate_size, self.dtype,
@@ -431,9 +520,10 @@ class DecoderLM(nn.Module):
                 positions % keep
         x = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
                      name="tok_embed")(idx)
-        for i in range(c.num_hidden_layers):
+        types = c.layer_types or ("full_attention",) * c.num_hidden_layers
+        for i, mixer in enumerate(types):
             x = _DecoderLayer(c, i < c.first_k_dense_replace, self.dtype,
-                              name=f"layer{i}")(x, mask, positions)
+                              mixer, name=f"layer{i}")(x, mask, positions)
         x = nn.RMSNorm(epsilon=c.rms_norm_eps, dtype=self.dtype,
                        name="norm_f")(x[:, keep:])
         return nn.Dense(c.vocab_size, use_bias=False, dtype=jnp.float32,
@@ -444,5 +534,6 @@ DeepseekV3LM = DecoderLM    # the name the first family's callers know
 
 
 __all__ = ["DecoderConfig", "DecoderLM", "DeepseekV3LM", "LatentAttention",
-           "GroupedQueryAttention", "RoutedExperts", "GatedMLP",
+           "GroupedQueryAttention", "GatedShortConv", "RoutedExperts",
+           "GatedMLP",
            "load_config", "rotary_interleaved", "rotary_half"]

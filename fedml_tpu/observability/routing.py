@@ -41,6 +41,18 @@ def routing_counters(metrics) -> dict:
             "moe_dropped": total["moe_dropped"]}
 
 
+def layer_mix_counters(metrics) -> dict:
+    """``conv.layer_positions`` and ``attn.layer_positions`` of a round:
+    positions run through conv mixers and through attention mixers
+    (layers of the kind x positions), from the sums a decoder with
+    ``layer_types`` sows; ``{}`` for any other model."""
+    if not metrics or "conv_layer_positions" not in metrics:
+        return {}
+    total = lambda k: float(np.sum(np.asarray(metrics.get(k, 0.0))))
+    return {"conv.layer_positions": total("conv_layer_positions"),
+            "attn.layer_positions": total("attn_layer_positions")}
+
+
 def note_routing(metrics) -> dict:
     counters = routing_counters(metrics)
     reg = get_registry()
@@ -51,4 +63,5 @@ def note_routing(metrics) -> dict:
     return counters
 
 
-__all__ = ["SERIES", "routing_counters", "note_routing"]
+__all__ = ["SERIES", "routing_counters", "layer_mix_counters",
+           "note_routing"]

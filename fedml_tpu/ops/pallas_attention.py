@@ -149,6 +149,8 @@ def _vmem_bytes(kernel, tile, Dqk, Dv, itemsize):
     pipeline's double buffers), its fp32 accumulators and replicated
     columns, and the fp32 score-sized tiles live in one pass of the loop."""
     own, streamed = tile.rows, tile.major
+    # (a block of 64 columns takes a whole tile's lanes in VMEM)
+    Dqk, Dv = _up(Dqk, _LANES), _up(Dv, _LANES)
     if kernel == "fwd":     # q, o | k, v | acc, m, l | s, p
         blocks = own * (Dqk + Dv) + streamed * (Dqk + Dv)
         state = own * (Dv + 2 * _LANES)
@@ -661,8 +663,11 @@ def _dkv_one_head(q, k, v, do, lse, dl, *, scale, causal, tile, q_len, k_len,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, schedule=None):
-    """Fused attention: q, k ``[B, T, H, Dqk]``, v ``[B, T, H, Dv]`` ->
-    ``[B, T, H, Dv]``.
+    """Fused attention: q ``[B, T, H, Dqk]``, k ``[B, T, KV, Dqk]``, v
+    ``[B, T, KV, Dv]`` -> ``[B, T, H, Dv]``; ``KV`` divides ``H`` and
+    query head ``h`` reads key/value head ``h // (H / KV)`` where it lies
+    (:func:`_per_head`: no repeated copy; the gradient of a key/value
+    head is its group's sum, taken right after ``flash_bwd_dkv``).
 
     Forward and backward are Pallas kernels (per ``(batch, head)`` via a
     double vmap -- each kernel grid covers tiles of one sequence x major
@@ -674,7 +679,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     Ragged sequence lengths are padded here and masked in-kernel. The
     score width ``Dqk`` may differ from the value width ``Dv`` (latent
     attention: keys wider than values). On hardware ``Dv`` must fill
-    128-wide tiles; a ``Dqk`` that does not is zero-padded to the next
+    128-wide tiles or be 64 (blocks of the array's own 64 columns); a
+    ``Dqk`` that differs from it and fills no tile is zero-padded to the next
     multiple of 128 here (exact: the extra columns add 0 to every score),
     and ``scale`` defaults to ``Dqk ** -0.5`` of the width given, never
     the padded one.
@@ -696,27 +702,55 @@ def _swap_th(x):
     return jnp.transpose(x, (0, 2, 1, 3))
 
 
-def _double_vmap(fn):
+def _per_head(fn, group=1, in_axes=0):
     """[B, H, T, ...] operands -> per-(batch, head) kernel calls. Both
     mapped axes are LEADING: on hardware Mosaic turns each vmapped axis
     into a squeezed block dim, and squeezed dims are only legal outside
     the trailing two block dims -- vmapping the middle head axis of a
     [B, T, H, D] array makes the block's last-two dims (Squeezed(H), D),
     which the TPU lowering rejects (r5 hardware run). Callers transpose
-    to [B, H, T, D] at the boundary instead."""
+    to [B, H, T, D] at the boundary instead.
+
+    Grouped-query heads (``group`` query heads a key/value head): the
+    per-query-head operands come as ``[B, KV, group, T, ...]`` and keys
+    and values as ``[B, KV, T, ...]``. A third, innermost map runs over
+    the group by ``in_axes``, which leaves keys and values unmapped
+    (``None``): their index maps ignore that grid axis, so
+    query head ``h`` READS key/value head ``h // group`` where it lies
+    (no repeated copy), and while the group turns the block index stands
+    and nothing is fetched again."""
+    if group > 1:
+        fn = jax.vmap(fn, in_axes=in_axes)
     return jax.vmap(jax.vmap(fn))
 
 
+#: the group's map over (q, k, v) and over (q, k, v, dO, lse, delta)
+_Q_OF_QKV = (0, None, None)
+_Q_OF_BWD = (0, None, None, 0, 0, 0)
+
+
+def _split_group(x, group):
+    """``[B, H, ...] -> [B, KV, group, ...]`` (nothing where ``group`` is 1)."""
+    return x if group == 1 else x.reshape(
+        (x.shape[0], x.shape[1] // group, group) + x.shape[2:])
+
+
+def _merge_group(x, group):
+    return x if group == 1 else x.reshape(
+        (x.shape[0], x.shape[1] * group) + x.shape[3:])
+
+
 def _require_hw_head_dim(D, interpret):
-    """On real TPU hardware the kernel's lane layout requires the value
-    head dim to fill 128-wide tiles; interpret mode (CPU tests) takes any
-    D. Fail loudly up front instead of leaving a Mosaic layout error to
-    decipher (ADVICE r3)."""
-    if not interpret and D % 128:
+    """On real TPU hardware the kernels run a value width that fills
+    128-wide tiles, or 64 (blocks of the array's own 64 columns: half a
+    tile's lanes, the width the chip was probed at, PERF.md PR 34);
+    interpret mode (CPU tests) takes any D. Fail loudly up front instead
+    of leaving a Mosaic layout error to decipher (ADVICE r3)."""
+    if not interpret and D % 128 and D != 64:
         raise ValueError(
             f"flash_attention on TPU hardware requires head_dim D to be a "
-            f"multiple of 128 (got D={D}); use "
-            "fedml_tpu.ops.attention.blockwise_attention for small head "
+            f"multiple of 128, or 64 (got D={D}); use "
+            "fedml_tpu.ops.attention.blockwise_attention for other head "
             "dims (same flash semantics, XLA-scheduled)")
 
 
@@ -763,7 +797,9 @@ def _forward(q, k, v, *, causal, scale, block_q, block_k, schedule,
     fn = functools.partial(_fwd_one_head, scale=scale_, causal=causal,
                            tile=tile, q_len=Tq, k_len=Tk,
                            interpret=interpret)
-    out, lse = _double_vmap(fn)(qp, kp, vp)
+    group = H // k.shape[2]
+    out, lse = (_merge_group(x, group) for x in _per_head(
+        fn, group, _Q_OF_QKV)(_split_group(qp, group), kp, vp))
     # back to [B,T,H,D]; the logsumexp stays [B,H,T]
     return _swap_th(out)[:, :Tq], lse[:, :, 0, :Tq]
 
@@ -788,14 +824,26 @@ def _backward(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
     row = lambda x, m: rows(x, m)[:, :, None]         # [B,H,1,T]
     kw = dict(scale=scale_, causal=causal, q_len=Tq, k_len=Tk,
               interpret=interpret)
+    group = H // k.shape[2]
+    mine = lambda x: _split_group(x, group)           # a query head's own
     t = tiles.dq
-    dq = _double_vmap(functools.partial(_dq_one_head, tile=t, **kw))(
-        rows(qh, t.rows), rows(kh, t.major), rows(vh, t.major),
-        rows(doh, t.rows), row(lse, t.rows), row(delta, t.rows))
+    dq = _per_head(functools.partial(_dq_one_head, tile=t, **kw), group,
+                   _Q_OF_BWD)(
+        mine(rows(qh, t.rows)), rows(kh, t.major), rows(vh, t.major),
+        mine(rows(doh, t.rows)), mine(row(lse, t.rows)),
+        mine(row(delta, t.rows)))
+    dq = _merge_group(dq, group)
     t = tiles.dkv
-    dk, dv = _double_vmap(functools.partial(_dkv_one_head, tile=t, **kw))(
-        rows(qh, t.major), rows(kh, t.rows), rows(vh, t.rows),
-        rows(doh, t.major), row(lse, t.major), row(delta, t.major))
+    dk, dv = _per_head(functools.partial(_dkv_one_head, tile=t, **kw), group,
+                       _Q_OF_BWD)(
+        mine(rows(qh, t.major)), rows(kh, t.rows), rows(vh, t.rows),
+        mine(rows(doh, t.major)), mine(row(lse, t.major)),
+        mine(row(delta, t.major)))
+    if group > 1:
+        # a key/value head's gradient: its group's query heads' parts,
+        # [B, KV, group, T, D], summed right after the kernel
+        dk, dv = (jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+                  for x in (dk, dv))
     # the padded score columns' gradients are sliced off with the padded rows
     return (_swap_th(dq)[:, :Tq, :, :D], _swap_th(dk)[:, :Tk, :, :D],
             _swap_th(dv)[:, :Tk])
@@ -804,6 +852,11 @@ def _backward(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k, schedule):
     interpret = _use_interpret()
     _require_hw_head_dim(v.shape[-1], interpret)
+    if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"flash_attention: {q.shape[2]} query heads over {k.shape[2]} "
+            f"key and {v.shape[2]} value heads (a whole group of query "
+            "heads reads each key/value head)")
     if isinstance(causal, BlockDiffusion):
         length, block = causal
         if length % block or not q.shape[1] == k.shape[1] == 2 * length:

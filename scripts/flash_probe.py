@@ -22,6 +22,17 @@ under the schedule ``flash_schedule`` chooses and under 128 x 128 tiles.
 
     python3 scripts/flash_probe.py [--shape B,T,H,Dqk,Dv ...]
         [--fwd rows,major,minor ...] [--dq ...] [--dkv ...]
+    python3 scripts/flash_probe.py --gqa 1,4096,32,8,64
+
+``--gqa B,T,H,KV,D`` (PR 34) is the row of a grouped-query layer with
+heads narrower than a tile (``lfm2-8b-a1b-ep4``: 32 query heads over 8
+key/value heads of 64, T 4,096, causal) and times the FORMS such a layer
+can take, the whole layer forward and backward: blocks of the array's own
+64 columns with the kernels reading key/value head ``h // group``
+themselves (what ships), the same with the heads repeated first
+(``jnp.repeat``, what the parent did at 128), and both again with q, k
+and v zero-padded to 128 columns (the baseline: the only form the
+parent's kernels could run).
 
 Prints one JSON object and writes it to ``chiprun_out/flash_probe/probe.json``.
 ``JAX_PLATFORMS=cpu`` rehearses it at a toy shape (interpret mode: the
@@ -125,7 +136,7 @@ def probe_shape(shape, sweep, reps, chain, seed):
         ref, table = None, []
         for choice in sweep[name]:
             tile = tile_of(*choice)
-            fn = pa._double_vmap(functools.partial(one_head, tile=tile, **kw))
+            fn = pa._per_head(functools.partial(one_head, tile=tile, **kw))
             if name == "fwd":
                 args = (q, k, v)
             else:
@@ -184,6 +195,65 @@ def probe_shape(shape, sweep, reps, chain, seed):
     return out
 
 
+# -- PR 34: the forms of a grouped-query layer with heads of 64 ---------------
+
+def probe_gqa(shape, reps, chain, seed):
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops import pallas_attention as pa
+
+    b, t, h, kvh, d = shape
+    group = h // kvh
+    interpret = pa._use_interpret()
+    dtype = jnp.bfloat16
+    key = jax.random.PRNGKey(seed % (2 ** 31))
+    rand = lambda i, heads: jax.random.normal(
+        jax.random.fold_in(key, i), (b, t, heads, d), dtype)
+    q, k, v, do = rand(0, h), rand(1, kvh), rand(2, kvh), rand(3, h)
+    scale = d ** -0.5
+    pairs = t * (t + 1) / 2
+    flops = {"fwd": 2.0 * pairs * (d + d) * b * h,
+             "fwd_bwd": 2.0 * pairs * (7 * d) * b * h}
+    out = {"shape": dict(zip("B T H KV D".split(), shape)),
+           "flash_schedule": {n: list(x) for n, x in zip(
+               pa.Schedule._fields, pa.flash_schedule(t, t, d, d, dtype)[0])}}
+    wide = lambda x: jnp.pad(x, ((0, 0),) * 3 + ((0, 128 - d),))
+    rep = lambda x: jnp.repeat(x, group, axis=2)
+    # on the CPU the forms at 128 are rehearsed too: interpret mode pads
+    # nothing by itself
+    forms = {
+        "own64_group_read": lambda q, k, v: (q, k, v),
+        "own64_repeat": lambda q, k, v: (q, rep(k), rep(v)),
+        "pad128_group_read": lambda q, k, v: (wide(q), wide(k), wide(v)),
+        "pad128_repeat": lambda q, k, v: (wide(q), rep(wide(k)),
+                                           rep(wide(v))),
+    }
+
+    def layer(form):
+        def loss(q, k, v):
+            o = pa.flash_attention(*form(q, k, v), True, scale)[..., :d]
+            return jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    out["layer_fwd_bwd"], ref = {}, None
+    for label, form in forms.items():
+        row = {}
+        try:
+            exe, row["compile_s"] = _compiled(layer(form), (q, k, v))
+            got = exe(q, k, v)
+            row["ms"] = _timed(exe, (q, k, v), reps, chain)
+            ref = got if ref is None else ref
+            row["max_abs_from_first"] = _distance(got, ref)
+            row["roofline_pct"] = 100 * flops["fwd_bwd"] / PEAK_FLOPS \
+                / (row["ms"] * 1e-3)
+        except Exception as e:
+            row["error"] = str(e).splitlines()[0][:300]
+        out["layer_fwd_bwd"][label] = row
+
+    return out
+
+
 def _tiles(values):
     return [tuple(int(x) for x in v.split(",")) for v in values]
 
@@ -196,6 +266,10 @@ def main(argv=None):
         ap.add_argument(f"--{name}", action="append", default=None,
                         help="rows,major,minor (major 0: the whole "
                         "sequence); repeatable; default: the sweep")
+    ap.add_argument("--gqa", action="append", default=None,
+                    help="B,T,H,KV,D: the forms of a grouped-query layer "
+                    "(repeatable); with it, the tile sweep runs only for "
+                    "shapes given by --shape")
     ap.add_argument("--reps", type=int, default=9)
     ap.add_argument("--chain", type=int, default=8)
     ap.add_argument("--seed", type=int, default=30)
@@ -204,14 +278,17 @@ def main(argv=None):
     import jax
 
     dev = jax.devices()[0]
-    shapes = _tiles(args.shape) if args.shape else CELL_SHAPES
+    shapes = _tiles(args.shape) if args.shape \
+        else ([] if args.gqa else CELL_SHAPES)
     sweep = {n: _tiles(getattr(args, n)) if getattr(args, n) else SWEEP[n]
              for n in SWEEP}
     result = {"device": {"platform": dev.platform, "kind": dev.device_kind},
               "times_are_device_numbers": dev.platform == "tpu",
               "reps": args.reps, "chain": args.chain,
               "shapes": [probe_shape(s, sweep, args.reps, args.chain,
-                                     args.seed) for s in shapes]}
+                                     args.seed) for s in shapes],
+              "gqa": [probe_gqa(s, args.reps, args.chain, args.seed)
+                      for s in _tiles(args.gqa or [])]}
     out_dir = os.path.join(ROOT, "chiprun_out", "flash_probe")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "probe.json"), "w") as f:

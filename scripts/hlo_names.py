@@ -67,9 +67,9 @@ def spec_of(config, traffic, reference):
     from fedml_tpu.models import deepseek_v3 as dsv3
 
     decoder = dsv3.DecoderConfig.from_dict(config)
-    if family == "deepseek_v3_lm":
+    if family in ("deepseek_v3_lm", "lfm2_moe_lm"):
         return specs.make_seq_classification_spec(
-            dsv3.DeepseekV3LM(decoder, dtype=dtype), example, name="lm")
+            dsv3.DecoderLM(decoder, dtype=dtype), example, name="lm")
     if family == "sdar_moe_lm":
         return specs.make_block_diffusion_lm_spec(
             dsv3.DecoderLM(decoder, dtype=dtype), example,
@@ -203,10 +203,12 @@ def main(argv=None):
     from benchmarks.manifest import Manifest
     from fedml_tpu.ops import grouped_matmul as gm
     from fedml_tpu.ops import pallas_attention as pa
+    from fedml_tpu.ops import short_conv as sc
 
     # the kernels ask the default backend whether to interpret; it is the
     # CPU's here, and the program wanted is the chip's
-    pa._use_interpret = gm._use_interpret = lambda: False
+    pa._use_interpret = gm._use_interpret = sc._use_interpret = \
+        lambda: False
     man = Manifest(ROOT)
     entry = man.cell(args.workload)
     config = man.config(entry["config"])

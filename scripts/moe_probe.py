@@ -106,7 +106,8 @@ def rounds_part(man, workload, config, traffic, seed, rounds):
     steps = sum(-(-n // int(traffic["batch_size"])) for n in cell.ns) \
         * int(traffic["epochs"])
     layers = int(config.get("n_layer", config["num_hidden_layers"])) \
-        - int(config.get("first_k_dense_replace", 0))
+        - int(config.get("first_k_dense_replace",
+                         config.get("num_dense_layers", 0)))
     expected = rows * steps * layers
     out = {"expected_rows_held": expected, "rounds": []}
     for _ in range(rounds):

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from fedml_tpu.ops import grouped_matmul as gm
 from fedml_tpu.ops import pallas_attention as pa
+from fedml_tpu.ops import short_conv as sc
 
 
 @pytest.fixture(scope="module")
@@ -41,6 +42,7 @@ def hardware_path(monkeypatch):
     is the CPU's, so the test steers them onto the compiled path."""
     monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    monkeypatch.setattr(sc, "_use_interpret", lambda: False)
 
 
 def _custom_calls(compiled):
@@ -61,16 +63,17 @@ def _flash_calls(compiled):
             for n, sig in calls]
 
 
-def _attention_fwd_bwd(one_chip, b, t, heads, dqk, dv, mask=True):
-    shape = lambda d: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
-                                           sharding=one_chip)
+def _attention_fwd_bwd(one_chip, b, t, heads, dqk, dv, mask=True,
+                       kv_heads=None):
+    shape = lambda d, h=kv_heads or heads: jax.ShapeDtypeStruct(
+        (b, t, h, d), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):   # no blocks given: the tiles flash_schedule chooses
         out = pa.flash_attention(q, k, v, mask, dqk ** -0.5)
         return jnp.sum(out.astype(jnp.float32))
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        shape(dqk), shape(dqk), shape(dv)).compile()
+        shape(dqk, heads), shape(dqk), shape(dv)).compile()
 
 
 @pytest.mark.parametrize("heads,dqk,dv", [(16, 128, 128), (32, 192, 128)],
@@ -138,10 +141,69 @@ def test_flash_kernels_compile_under_the_block_diffusion_mask(
             == (pa.Schedule(tile, tile, tile), (8, 8, 8))
 
 
+@pytest.mark.parametrize("kv_heads,d,mask", [
+    (8, 64, True), (4, 128, pa.BlockDiffusion(2048, 4))],
+    ids=["lfm2_32over8_64", "sdar_32over4_128_bd"])
+def test_flash_kernels_compile_with_key_value_heads_read_by_group(
+        one_chip, hardware_path, kv_heads, d, mask):
+    """``lfm2-8b-a1b-ep4``'s attention (32 query heads over 8 key/value
+    heads of 64, T 4,096, causal: blocks of the array's own 64 columns)
+    and ``sdar-30b-a3b-ep8``'s (32 over 4 of 128 under the block mask):
+    the group's map leaves keys and values unmapped, so the kernels'
+    outputs are ``[KV, group, T, D]`` and no repeated copy exists; the
+    names and the forward's ``(bf16, f32)`` signature are what the
+    benchmark's patterns find."""
+    t, heads = 4096, 32
+    group = heads // kv_heads
+    calls = _flash_calls(_attention_fwd_bwd(one_chip, 1, t, heads, d, d,
+                                            mask, kv_heads=kv_heads))
+    own = f"bf16[{kv_heads},{group},{t},{d}]"
+    assert sorted(calls) == sorted([
+        ("flash_fwd", f"({own}, f32[{kv_heads},{group},1,{t}])"),
+        ("flash_bwd_dq", own), ("flash_bwd_dkv", f"({own}, {own})")])
+    tile = pa.Tile(rows=512, major=4096, minor=512)
+    assert pa.flash_schedule(t, t, d, d, jnp.bfloat16) \
+        == (pa.Schedule(tile, tile, tile), (8, 8, 8))
+
+
+def test_short_conv_kernels_compile_at_the_cells_shape(one_chip,
+                                                       hardware_path):
+    """``gated_short_conv`` over ``[1, 4096, 3 x 2048]`` bf16, forward and
+    backward: two Pallas calls by the names the benchmark's patterns
+    match, and outputs that ``flash_fwd_roofline``'s ``(bf16, f32)``
+    signature pattern cannot take for a flash forward (the backward's
+    float32 sums come first)."""
+    import json
+
+    x = jax.ShapeDtypeStruct((1, 4096, 6144), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=one_chip)
+    loss = lambda bcu, w: jnp.sum(
+        sc.gated_short_conv(bcu, w).astype(jnp.float32))
+    # (the value too: the backward recomputes z and needs no forward)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        x, w).compile().as_text()
+    calls = dict(re.findall(
+        r"^\s*%(\S*short_conv_(?:fwd|bwd)\S*) = (.*?) custom-call\(", text,
+        re.M))
+    sigs = {re.search(r"short_conv_(fwd|bwd)", n).group(0):
+            re.sub(r"\{[^}]*\}", "", sig) for n, sig in calls.items()}
+    third = "bf16[1,4096,2048]"
+    assert sigs == {"short_conv_fwd": third,
+                    "short_conv_bwd": f"(f32[3,2048], {third}, {third}, "
+                                      f"{third})"}
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "layer_metrics",
+            "flash_fwd_roofline.json")) as f:
+        flash_fwd = re.compile(json.load(f)["pattern"])
+    lines = [ln.strip() for ln in text.splitlines() if "short_conv" in ln
+             and "custom-call(" in ln]
+    assert len(lines) == 2 and not any(flash_fwd.match(ln) for ln in lines)
+
+
 def test_flash_refuses_a_value_width_the_hardware_cannot_run(hardware_path):
     q = jnp.zeros((1, 128, 2, 192), jnp.bfloat16)
     with pytest.raises(ValueError, match="multiple of 128"):
-        pa.flash_attention(q, q, jnp.zeros((1, 128, 2, 64), jnp.bfloat16))
+        pa.flash_attention(q, q, jnp.zeros((1, 128, 2, 96), jnp.bfloat16))
 
 
 def test_grouped_products_compile_under_the_lane_vmap(one_chip,
