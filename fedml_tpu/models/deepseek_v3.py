@@ -51,13 +51,24 @@ expert, one grouped product a projection over stacked ``[count, d,
 width]`` leaves (:mod:`fedml_tpu.ops.grouped_matmul`), gathered back by
 the inverse permutation and summed by weight. No token is dropped
 whatever the imbalance: the sorted buffer has a row for every
-assignment. What absent experts would add is left out, and nothing here
-stands in for other chips. The shared expert, where the family has one,
-runs on every token.
+assignment to a held expert. A layer that holds less than half of the
+router sizes that buffer for twice what an even router sends it
+(:func:`buffer_capacity`: ``C`` rows, gathered from the tokens and
+summed back to them by :func:`_take_rows` / :func:`_sum_rows`: the first
+run of the sorted order); when any lane of the chunk holds more, the
+layer runs the whole ``tokens x top-k`` order instead, run by run of
+``C`` rows through the same function: one ``lax.cond`` a layer, on a
+flag reduced over the lane axis
+(:func:`fedml_tpu.parallel.mesh.any_lane`), so that one branch runs for
+all lanes. What absent experts would add is left out,
+and nothing here stands in for other chips. The shared expert, where the
+family has one, runs on every token.
 
 Counters of the routing are sown into the ``metrics`` collection, one
 value a layer and step (``fedml_tpu.observability.routing`` makes the
-round's series of them); a decoder with ``layer_types`` also sows the
+round's series of them; ``moe_overflow`` counts the layer-steps that
+took the whole buffer, ``moe_capacity_rows`` the compact buffer's rows);
+a decoder with ``layer_types`` also sows the
 positions each kind of mixer ran (``conv_layer_positions``,
 ``attn_layer_positions``).
 """
@@ -65,6 +76,7 @@ positions each kind of mixer ran (``conv_layer_positions``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import Any, Optional, Tuple
 
@@ -75,6 +87,7 @@ import jax.numpy as jnp
 from fedml_tpu.ops.grouped_matmul import grouped_matmul
 from fedml_tpu.ops.pallas_attention import BlockDiffusion, flash_attention
 from fedml_tpu.ops.short_conv import gated_short_conv
+from fedml_tpu.parallel.mesh import any_lane
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -375,6 +388,129 @@ _take_permuted.defvjp(
     lambda res, g: (g[res[1]], None, None))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(x, idx, rows):
+    """``x[idx]``: rows of a sorted buffer read from the ``rows ==
+    len(x)`` rows they are copies of. The gradient is :func:`_sum_rows`
+    of the cotangent."""
+    return x[idx]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _sum_rows(z, idx, rows):
+    """``out[m] = sum of z[p] over idx[p] == m`` for ``m < rows``, float32
+    in and out: one scatter-add of the buffer's rows into rows of zeros
+    (the form that shipped: ``scripts/moe_probe.py --dispatch`` times it
+    against a gather a slot summed over the slots, which writes a
+    ``tokens x top-k``-row array, and against ``top-k`` scatters at
+    unique indices; PERF.md, PR 35). The transpose of :func:`_take_rows`,
+    and the other way round."""
+    return jnp.zeros((rows,) + z.shape[1:], z.dtype).at[idx].add(z)
+
+
+_take_rows.defvjp(
+    lambda x, idx, rows: (x[idx], idx),
+    lambda rows, idx, g: (_sum_rows(g.astype(jnp.float32), idx,
+                                    rows).astype(g.dtype), None))
+_sum_rows.defvjp(
+    lambda z, idx, rows: (_sum_rows(z, idx, rows), idx),
+    lambda rows, idx, g: (_take_rows(g, idx, rows), None))
+
+
+@jax.custom_vjp
+def _fenced(x):
+    """``x``; its cotangent passes an optimization barrier, so that what
+    reads it stays where it is written (XLA otherwise moves the cast of a
+    conditional's result into both of its branches, where it cannot fuse
+    with the optimizer step that reads it)."""
+    return x
+
+
+_fenced.defvjp(lambda x: (x, None),
+               lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
+@jax.jit
+def _experts_on(slot, sizes, x, weight, w_gate, w_up, w_down):
+    """The held experts' part of the result from one run ``slot`` of the
+    sorted order (assignment ``token * top_k + choice`` each, ``sizes``
+    rows an expert, the rows past them of no group): read from their
+    tokens, through the experts, scaled by their assignments' weights and
+    summed back to the tokens, in float32. Jitted, so that the first run,
+    the loop over every run and its recomputation share one trace and
+    one derivative: the grouped products are traced at ONE number of
+    rows."""
+    N, k = weight.shape
+    token = slot // k
+    xs = _take_rows(x, token, N)
+    h = nn.silu(grouped_matmul(xs, w_gate, sizes)) \
+        * grouped_matmul(xs, w_up, sizes)
+    ys = grouped_matmul(h, w_down, sizes)                 # [len(slot), d]
+    ws = _take_rows(weight.reshape(-1), slot, N * k)
+    ys = ys * ws[:, None].astype(ys.dtype)
+    return _sum_rows(ys.astype(jnp.float32), token, N)
+
+
+def _run_sizes(ends, run, rows):
+    """Rows an expert in run ``run`` of ``rows`` rows of a sorted order
+    whose groups end at ``ends``."""
+    return jnp.diff(jnp.clip(ends - run * rows, 0, rows), prepend=0)
+
+
+def _first_run(active, slots, sizes, *operands):
+    """The first run of the sorted order: held assignments sort first, so
+    it is all of them whenever there are no more than its rows."""
+    return _experts_on(slots[0], _run_sizes(jnp.cumsum(sizes), 0,
+                                            slots.shape[1]), *operands)
+
+
+def _every_run(active, slots, sizes, *operands):
+    """The whole ``tokens x top-k`` sorted order, run by run: whatever
+    the routing, every assignment to a held expert has its row. A run in
+    which no lane of the chunk has a row (``active [runs]``) is stepped
+    over: it would add nothing."""
+    runs, rows = slots.shape
+    ends = jnp.cumsum(sizes)
+
+    def step(acc, run):
+        return jax.lax.cond(
+            active[run],
+            lambda acc: acc + _experts_on(
+                slots[run], _run_sizes(ends, run, rows), *operands),
+            lambda acc: acc, acc), None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros(operands[0].shape, jnp.float32),
+                          jnp.arange(runs))
+    return acc
+
+
+@jax.jit
+def _held_experts(active, slots, sizes, x, weight, w_gate, w_up, w_down):
+    """The first run of the sorted order ``slots [runs, rows]``, or, where
+    a second one is ``active``, every active one: one conditional, whose
+    fallback keeps only its inputs for the backward pass, so that the
+    step that does not take it writes no residual for it. Jitted, so that
+    a decoder's layers share one trace, one derivative and one lowering
+    of all of it."""
+    return jax.lax.cond(active[1], jax.checkpoint(_every_run), _first_run,
+                        active, slots, sizes, x, weight, w_gate, w_up,
+                        w_down)
+
+
+#: the compact sorted buffer has whole blocks of this many rows
+_CAPACITY_BLOCK = 512
+
+
+def buffer_capacity(assignments, count, experts):
+    """Rows of the sorted buffer of a layer that holds ``count`` of the
+    router's ``experts``: twice what an even router sends here, in whole
+    blocks, and never more than every assignment (which is what a layer
+    that holds half the router or more gets)."""
+    block = _CAPACITY_BLOCK
+    return min(assignments,
+               -(-2 * assignments * count // (experts * block)) * block)
+
+
 def _sum_metric(module, name, value):
     module.sow("metrics", name, jnp.asarray(value, jnp.float32),
                reduce_fn=jnp.add, init_fn=lambda: jnp.float32(0.0))
@@ -437,15 +573,36 @@ class RoutedExperts(nn.Module):
                 key[:, None] == jnp.arange(count)[None, :], axis=0,
                 dtype=jnp.int32)
 
+        cast = lambda w: w.astype(self.dtype)
+        cap = buffer_capacity(N * k, count, E)
+        overflow = False
         with jax.named_scope("moe_gmm"):
-            xs = _take_permuted(jnp.repeat(x, k, axis=0), order, inverse)
-            cast = lambda w: w.astype(self.dtype)
-            h = nn.silu(grouped_matmul(xs, cast(w_gate), group_sizes)) \
-                * grouped_matmul(xs, cast(w_up), group_sizes)
-            ys = grouped_matmul(h, cast(w_down), group_sizes)     # [N*k, d]
-            y = _take_permuted(ys, inverse, order).reshape(N, k, d)
-            routed = jnp.sum(
-                y * weight[..., None].astype(y.dtype), axis=1)
+            if cap == N * k:
+                # a row for every assignment: whatever the routing, it fits
+                xs = _take_permuted(jnp.repeat(x, k, axis=0), order, inverse)
+                h = nn.silu(grouped_matmul(xs, cast(w_gate), group_sizes)) \
+                    * grouped_matmul(xs, cast(w_up), group_sizes)
+                ys = grouped_matmul(h, cast(w_down), group_sizes)  # [N*k, d]
+                y = _take_permuted(ys, inverse, order).reshape(N, k, d)
+                routed = jnp.sum(
+                    y * weight[..., None].astype(y.dtype), axis=1)
+            else:
+                # the sorted order in runs of ``cap`` rows (the last one
+                # padded with rows of no group): the first run, or, when
+                # some lane of the chunk holds more rows than that, every
+                # run in which some lane has a row, for all lanes at once. The leaves go in as the
+                # products take them, so that their gradients come out of
+                # the conditional as small
+                runs = -(-N * k // cap)
+                slots = jnp.pad(order, (0, runs * cap - N * k))
+                active = any_lane(
+                    jnp.sum(group_sizes) > jnp.arange(runs) * cap)
+                overflow = active[1]
+                routed = _held_experts(
+                    active, slots.reshape(runs, cap), group_sizes, x,
+                    weight, *(_fenced(cast(w))
+                              for w in (w_gate, w_up, w_down))
+                ).astype(x.dtype)
 
         if c.n_shared_experts:
             with jax.named_scope("moe_shared"):
@@ -457,6 +614,8 @@ class RoutedExperts(nn.Module):
         _sum_metric(self, "moe_load_max", jnp.max(group_sizes))
         _sum_metric(self, "moe_load_mean", rows / count)
         _sum_metric(self, "moe_dropped", jnp.sum(held) - rows)
+        _sum_metric(self, "moe_overflow", overflow)
+        _sum_metric(self, "moe_capacity_rows", cap)
         return routed
 
 

@@ -1,9 +1,9 @@
 """Counters of expert routing, per round.
 
 A routed-expert layer (``fedml_tpu.models.deepseek_v3.RoutedExperts``)
-sows four sums a step into the client-update program's metrics; the round
+sows six sums a step into the client-update program's metrics; the round
 sums them over steps, layers and clients. ``routing_counters`` makes the
-round's three series of them:
+round's five series of them:
 
 - ``moe_rows_held``: assignments (token x chosen expert) that landed on
   the experts held here: the rows the grouped products computed;
@@ -11,10 +11,16 @@ round's three series of them:
   mean held expert's, both summed over the round's layer-steps (1.0 is a
   perfectly even load);
 - ``moe_dropped``: assignments to a held expert that got no row. The
-  layer has a row for every assignment, so this stays 0.
+  layer has a row for every assignment, so this stays 0;
+- ``moe_overflow``: layer-steps that ran the whole ``tokens x top-k``
+  buffer because some lane of the chunk held more rows than the compact
+  buffer has (0 in a layer that holds half the router or more: it has
+  no other buffer);
+- ``moe_capacity_rows``: the compact buffer's rows summed over the
+  layer-steps, so that ``moe_rows_held / moe_capacity_rows`` is its fill.
 
 ``note_routing`` also sets the registry's gauges of the same names; the
-round (``algorithms/fedavg.py``) puts the three into its record
+round (``algorithms/fedavg.py``) puts the five into its record
 (``metrics.jsonl``) and, on the bucketed stream, whose metrics reach the
 host inside it, onto the ``local-train`` span.
 """
@@ -25,7 +31,8 @@ import numpy as np
 
 from fedml_tpu.observability.registry import get_registry
 
-SERIES = ("moe_rows_held", "moe_load_max_over_mean", "moe_dropped")
+SERIES = ("moe_rows_held", "moe_load_max_over_mean", "moe_dropped",
+          "moe_overflow", "moe_capacity_rows")
 
 
 def routing_counters(metrics) -> dict:
@@ -34,11 +41,13 @@ def routing_counters(metrics) -> dict:
         return {}
     total = {k: float(np.sum(np.asarray(metrics[k])))
              for k in ("moe_rows_held", "moe_load_max", "moe_load_mean",
-                       "moe_dropped")}
+                       "moe_dropped", "moe_overflow", "moe_capacity_rows")}
     return {"moe_rows_held": total["moe_rows_held"],
             "moe_load_max_over_mean":
                 total["moe_load_max"] / max(total["moe_load_mean"], 1e-30),
-            "moe_dropped": total["moe_dropped"]}
+            "moe_dropped": total["moe_dropped"],
+            "moe_overflow": total["moe_overflow"],
+            "moe_capacity_rows": total["moe_capacity_rows"]}
 
 
 def layer_mix_counters(metrics) -> dict:

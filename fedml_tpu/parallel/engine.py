@@ -48,7 +48,8 @@ from fedml_tpu.core import pytree
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.costmodel import get_cost_model, program_cost
 from fedml_tpu.observability.tracing import get_tracer
-from fedml_tpu.parallel.mesh import CLIENT_AXIS, zero_pad_leading
+from fedml_tpu.parallel.mesh import (CLIENT_AXIS, LANE_AXIS,
+                                     zero_pad_leading)
 from fedml_tpu.program.aggregation import (split_total, two_word_add,
                                            two_word_quotient)
 
@@ -448,7 +449,8 @@ class BucketedStreamRunner:
             @jax.jit
             def chunk_fn(global_state, batches, ns, trip, rngs):
                 local_states, aux, metrics = jax.vmap(
-                    client_update, in_axes=(None, 0, 0, None, 0))(
+                    client_update, in_axes=(None, 0, 0, None, 0),
+                    axis_name=LANE_AXIS)(
                         global_state, batches, ns, trip, rngs)
                 return _aggregate(global_state, local_states, aux, metrics)
         else:
@@ -459,7 +461,8 @@ class BucketedStreamRunner:
             def chunk_fn(global_state, batches, ns, trip, rngs,
                          residuals, crngs):
                 local_states, aux, metrics = jax.vmap(
-                    client_update, in_axes=(None, 0, 0, None, 0))(
+                    client_update, in_axes=(None, 0, 0, None, 0),
+                    axis_name=LANE_AXIS)(
                         global_state, batches, ns, trip, rngs)
 
                 def compress_one(local_state, residual, crng):

@@ -12,11 +12,29 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 CLIENT_AXIS = "clients"
 MODEL_AXIS = "model"
+#: the name ``BucketedStreamRunner``'s ``chunk_fn`` gives its lane ``vmap``
+LANE_AXIS = "lanes"
+
+
+def any_lane(flag):
+    """``flag`` (a boolean scalar of one lane) in ANY lane of the chunk.
+
+    Under a ``jax.vmap`` that names its axis ``LANE_AXIS`` the result is
+    the same in every lane, so a ``lax.cond`` on it stays a conditional:
+    one branch runs, for all lanes at once. A ``cond`` on a lane's own
+    flag is batched into a select and both branches run. Where no such
+    axis is bound (a direct call, the other runners) the flag comes back
+    as it is: the same results, both branches paid for under a ``vmap``."""
+    try:
+        return jax.lax.pmax(flag.astype(jnp.int32), LANE_AXIS) > 0
+    except NameError:
+        return flag
 
 
 def make_2d_mesh(n_a: int, n_b: int, axis_names, devices=None):

@@ -124,32 +124,96 @@ def _nbytes(dtype, dims):
     return n
 
 
-def instructions(text):
-    """``{name: (output type, opcode, operand names, op_name, estimated
-    cycles)}`` of every instruction of an optimized HLO module's text
-    that stands in a computation of its own right (the entry, a loop's
-    body): what a device trace shows as one event. The insides of fusions
-    are left out. The cycles are the TPU compiler's own estimate (0 where
-    it gives none): no timing, but their sum ranked four forms of the
-    GPT-2 loss as the chip did (PERF.md, PR 32)."""
-    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", text))
-    out, inside = {}, False
+def _computations(text):
+    """``{name: its lines}`` of an optimized HLO module's text."""
+    out, lines = {}, None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
         if head:
-            inside = head.group(1) in fused
-        m = not inside and re.match(
-            r"\s+(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)", line)
-        if not m:
-            continue
-        name, result, opcode, rest = m.groups()
-        op_name = re.search(r'op_name="([^"]*)"', rest)
-        cycles = re.search(r'"estimated_cycles":"(\d+)"', rest)
-        out[name] = (result, opcode,
-                     re.findall(r"%([\w.\-]+)", rest.split(")")[0]),
-                     op_name.group(1) if op_name else "",
-                     int(cycles.group(1)) if cycles else 0)
+            lines = out.setdefault(head.group(1), [])
+        elif lines is not None:
+            lines.append(line)
     return out
+
+
+def fallback_computations(text):
+    """The computations that only a conditional's second branch reaches
+    (the one taken on true; ``RoutedExperts``' whole-buffer fallback,
+    PERF.md PR 35), whatever they call included."""
+    comps = _computations(text)
+    branches_of = lambda lines: [
+        re.findall(r"%([\w.\-]+)", m) for m in re.findall(
+            r"branch_computations=\{([^}]*)\}", "\n".join(lines))]
+    called = lambda lines: set(re.findall(
+        r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", "\n".join(lines))
+    ) | {name for b in branches_of(lines) for name in b}
+    # a conditional inside a fallback (the step over an empty run) is
+    # reached from it with both of its branches
+    out, todo = set(), [b[1] for lines in comps.values()
+                        for b in branches_of(lines) if len(b) == 2]
+    while todo:
+        name = todo.pop()
+        if name not in out:
+            out.add(name)
+            todo += list(called(comps.get(name, ())))
+    return out
+
+
+def instructions(text, skip=()):
+    """``{name: (output type, opcode, operand names, op_name, estimated
+    cycles)}`` of every instruction of an optimized HLO module's text
+    that stands in a computation of its own right (the entry, a loop's
+    body, a conditional's branch; not the computations named in
+    ``skip``): what a device trace shows as one event. The insides of
+    fusions are left out. The cycles are the TPU compiler's own estimate
+    (0 where it gives none): no timing, but their sum ranked four forms
+    of the GPT-2 loss as the chip did (PERF.md, PR 32)."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", text))
+    out = {}
+    for comp, lines in _computations(text).items():
+        if comp in fused or comp in skip:
+            continue
+        for line in lines:
+            m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (.*?) "
+                         r"([a-z][\w\-]*)\((.*)", line)
+            if not m:
+                continue
+            name, result, opcode, rest = m.groups()
+            op_name = re.search(r'op_name="([^"]*)"', rest)
+            cycles = re.search(r'"estimated_cycles":"(\d+)"', rest)
+            out[name] = (result, opcode,
+                         re.findall(r"%([\w.\-]+)", rest.split(")")[0]),
+                         op_name.group(1) if op_name else "",
+                         int(cycles.group(1)) if cycles else 0)
+    return out
+
+
+_MOVES_NOTHING = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                  "conditional", "while")
+
+
+def wide_rows(text, rows, within="/moe/", columns=64):
+    """The instructions OUTSIDE the conditionals' fallback whose result
+    or an operand has ``rows`` rows of ``columns`` or more elements: the
+    ``tokens x top-k``-row arrays that ``RoutedExperts``' compact path is
+    there to avoid (the route's index vectors over the assignments have
+    one column and are not counted). ``within``: only instructions whose
+    ``op_name`` holds this (a vocabulary slice may have as many rows)."""
+    instrs = instructions(text, skip=fallback_computations(text))
+    plain = lambda s: re.sub(r"\{[^}]*\}", "", s)
+
+    def wide(type_text):
+        for _, dims in _SHAPE.findall(plain(type_text)):
+            dims = [int(d) for d in dims.split(",") if d]
+            if rows in dims and max(
+                    [d for d in dims if d != rows] or [1]) >= columns:
+                return True
+        return False
+
+    return [name for name, rec in instrs.items()
+            if rec[1] not in _MOVES_NOTHING and within in rec[3]
+            and (wide(rec[0]) or any(o in instrs and wide(instrs[o][0])
+                                     for o in rec[2]))]
 
 
 def describe(name, instrs):
@@ -196,6 +260,10 @@ def main(argv=None):
                          "the loop body is the same at every edge)")
     ap.add_argument("--text", default=None,
                     help="write the optimized HLO here")
+    ap.add_argument("--rows", type=int, default=0,
+                    help="also count the instructions outside the "
+                         "conditionals' fallback that touch an array of "
+                         "this many rows (an expert cell's tokens x top-k)")
     args = ap.parse_args(argv)
     # before JAX is imported: no chip is asked for, the compiler logs nowhere
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -228,6 +296,18 @@ def main(argv=None):
                       "argument_bytes": mem.argument_size_in_bytes,
                       "output_bytes": mem.output_size_in_bytes,
                       "temp_bytes": mem.temp_size_in_bytes}))
+    if args.rows:
+        fallback = fallback_computations(text)
+        outside = instructions(text, skip=fallback)
+        wide = wide_rows(text, args.rows)
+        print(json.dumps({
+            "rows": args.rows, "conditionals": text.count(" conditional("),
+            "instructions_outside_fallback": len(outside),
+            "estimated_cycles_outside_fallback":
+                sum(r[4] for r in outside.values()),
+            "wide_outside_fallback": len(wide)}))
+        for name in wide:
+            print(json.dumps(describe(name, instrs)))
     for name in names:
         print(json.dumps(describe(name, instrs) if name in instrs
                          else {"name": name, "missing": True}))

@@ -305,9 +305,13 @@ def test_counters_equal_a_numpy_count(toy, grads):
     assert rows == 2 * B * T * 3   # the toy holds all 16 of its experts
     counters = routing_counters(grads.metrics)
     assert set(counters) == {"moe_rows_held", "moe_load_max_over_mean",
-                             "moe_dropped"}
+                             "moe_dropped", "moe_overflow",
+                             "moe_capacity_rows"}
     assert counters["moe_rows_held"] == rows
     assert counters["moe_dropped"] == 0
+    # all 16 experts held: the buffer is every assignment's, nothing else
+    assert counters["moe_overflow"] == 0
+    assert counters["moe_capacity_rows"] == rows
     assert counters["moe_load_max_over_mean"] == pytest.approx(
         load_max / (rows / 16))
     assert routing_counters({"loss_sum": 1.0}) == {}
@@ -562,15 +566,21 @@ def test_a_federated_round_matches_the_references(federated):
 
 
 def test_the_round_carries_the_three_counters(federated):
+    from fedml_tpu.observability.routing import SERIES as routing_series
     f = federated
+    assert len(routing_series) == 5     # the three, and PR 35's two
     for record in f.rounds:
         assert record["moe_dropped"] == 0
+        # half the router held: the capacity is every assignment of the
+        # 2 expert layers, and no layer-step has a fallback to take
+        assert record["moe_overflow"] == 0
+        assert record["moe_capacity_rows"] == 2 * f.tokens * 3
         # 2 expert layers; 3 of 16 experts a token, 8 of the 16 held
         assert 0.6 < record["moe_rows_held"] \
             / (2 * f.tokens * 3 * 8 / 16) < 1.4
         assert record["moe_load_max_over_mean"] >= 1.0
     gauges = f.registry.render_prometheus()
-    for name in ("moe_rows_held", "moe_load_max_over_mean", "moe_dropped"):
+    for name in routing_series:
         assert name in gauges
     trains = [s for s in f.tracer.finished_spans()
               if s.name == "local-train"]
@@ -578,3 +588,5 @@ def test_the_round_carries_the_three_counters(federated):
     assert trains[-1].attrs["moe_rows_held"] \
         == f.rounds[-1]["moe_rows_held"]
     assert trains[-1].attrs["moe_dropped"] == 0
+    for name in routing_series:
+        assert trains[-1].attrs[name] == f.rounds[-1][name]

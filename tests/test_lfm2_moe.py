@@ -484,6 +484,8 @@ def test_the_local_train_span_says_which_mix_of_layers_ran(federated):
         assert span.attrs["attn.layer_positions"] == f.work["tokens"]
         assert span.attrs["moe_dropped"] == 0
         assert span.attrs["moe_rows_held"] == record["moe_rows_held"]
+        assert span.attrs["moe_overflow"] == record["moe_overflow"] == 0
+        assert span.attrs["moe_capacity_rows"] == 2 * f.work["tokens"] * 2
         # 2 routed layers; 2 of 8 a position, 4 held
         assert 0.5 < record["moe_rows_held"] \
             / (2 * f.work["tokens"] * 2 * 4 / 8) < 1.5

@@ -245,6 +245,8 @@ def test_softmax_router_and_experts_match_the_reference(renormalise):
         np.testing.assert_allclose(weight.sum(axis=1), 1.0, rtol=1e-5)
     assert sown["moe_rows_held"] == 4 * x.shape[0]
     assert sown["moe_dropped"] == 0
+    assert sown["moe_overflow"] == 0
+    assert sown["moe_capacity_rows"] == 4 * x.shape[0]
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
@@ -503,6 +505,11 @@ def test_the_local_train_span_carries_the_objectives_counters(federated):
         assert span.attrs["bd.positions"] == 2 * f.work["tokens"]
         assert span.attrs["moe_dropped"] == 0
         assert span.attrs["moe_rows_held"] == record["moe_rows_held"]
+        # half the router held: every assignment has its row and there
+        # is no fallback to take
+        assert span.attrs["moe_overflow"] == record["moe_overflow"] == 0
+        assert span.attrs["moe_capacity_rows"] \
+            == 2 * 2 * f.work["tokens"] * 4
         # 2 layers, 2 L positions a sequence; 4 of 16 a position, 8 held
         assert 0.6 < record["moe_rows_held"] \
             / (2 * 2 * f.work["tokens"] * 4 * 8 / 16) < 1.4

@@ -45,6 +45,15 @@ def hardware_path(monkeypatch):
     monkeypatch.setattr(sc, "_use_interpret", lambda: False)
 
 
+def _load_script(name):
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def _custom_calls(compiled):
     return re.findall(r"%(\S+) = (\S+)[^\n]*custom_call_target="
                       r"\"tpu_custom_call\"", compiled.as_text())
@@ -232,6 +241,61 @@ def test_grouped_products_compile_under_the_lane_vmap(one_chip,
     assert count("moe_gmm_dlhs") == 3 and count("moe_gmm_drhs") == 3
 
 
+@pytest.mark.parametrize("cell,tokens,top_k,held,router,width,capacity", [
+    ("sdar", 4096, 8, 16, 128, 768, 8192),
+    ("kanana2", 4096, 6, 16, 128, 768, 6144),
+    ("lfm2", 4096, 4, 8, 32, 1792, 8192)])
+def test_an_expert_layer_keeps_the_whole_buffer_inside_its_fallback(
+        topo, one_chip, hardware_path, cell, tokens, top_k, held, router,
+        width, capacity):
+    """One ``RoutedExperts`` layer at an expert cell's shapes, forward and
+    backward under a lane ``vmap`` that names its axis as the stream's
+    ``chunk_fn`` does, compiled for the described v5e: the conditional
+    stays a conditional (one forward, one backward; the fallback has its
+    own inside, which steps over empty runs), the grouped products
+    stand in both of its branches, and OUTSIDE the fallback no
+    instruction reads or writes an array of ``tokens x top-k`` rows but
+    the route's index vectors (PERF.md, PR 35)."""
+    from fedml_tpu.models import deepseek_v3 as dsv3
+    from fedml_tpu.parallel.mesh import LANE_AXIS
+
+    d = 2048
+    cfg = dsv3.DecoderConfig(
+        vocab_size=64, hidden_size=d, num_hidden_layers=1,
+        num_attention_heads=2, moe_intermediate_size=width,
+        n_routed_experts=held, num_experts_per_tok=top_k,
+        router_experts=router, experts_held=(0, held),
+        scoring_func="softmax")
+    assert dsv3.buffer_capacity(tokens * top_k, held, router) == capacity
+    module = dsv3.RoutedExperts(cfg, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, tokens, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                       jnp.zeros((tokens, d), jnp.bfloat16))["params"])
+
+    def loss(params, x):
+        out = module.apply({"params": params}, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    step = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 1)),
+                            axis_name=LANE_AXIS))
+    text = step.lower(params, x).compile().as_text()
+    names = _load_script("hlo_names")
+    # the layer's conditional forward and backward, and inside its
+    # fallback the one that steps over a run without rows: in the loop
+    # over the runs, in its recomputation and in its backward
+    assert text.count(" conditional(") == 2 + 3
+    calls = re.findall(r"%(\S+) = \S+[^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    # nine products a branch, and the fallback's three forward products
+    # again in its backward (it is checkpointed: it keeps no residual)
+    assert sum("moe_gmm" in c for c in calls) == 9 + 9 + 3
+    assert names.fallback_computations(text)
+    assert names.wide_rows(text, tokens * top_k, within="") == []
+
+
 @pytest.mark.parametrize("program", ["fold_first", "fold_next",
                                      "fold_quotient"])
 def test_fold_programs_keep_their_arithmetic_and_allocate_nothing(
@@ -276,15 +340,6 @@ def test_fold_programs_keep_their_arithmetic_and_allocate_nothing(
         assert count("subtract") >= 9 and count("multiply") >= 6
     else:
         assert (count("add"), count("subtract")) == (adds, subtracts)
-
-
-def _load_script(name):
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", name + ".py")
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
 
 
 def test_gpt2_client_update_passes_over_the_logits_once(topo,
