@@ -20,3 +20,10 @@ Layers (mirroring reference layers, see SURVEY.md section 1):
 """
 
 __version__ = "0.1.0"
+
+# the process's start-up is on the record from here (stdlib only: the
+# transports stay importable without jax); observability/tracing.py
+# `startup_report` says where it ends
+from fedml_tpu.observability.tracing import begin_startup as _begin_startup
+
+_begin_startup()

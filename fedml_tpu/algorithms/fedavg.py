@@ -21,7 +21,8 @@ from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.perfmon import get_perf_monitor
 from fedml_tpu.observability.routing import (layer_mix_counters,
                                               note_routing, routing_counters)
-from fedml_tpu.observability.tracing import get_tracer
+from fedml_tpu.observability.jaxmon import feed_tracer
+from fedml_tpu.observability.tracing import RoundLog, get_tracer
 from fedml_tpu.utils.profiling import end_of_round_sync
 from fedml_tpu.parallel.engine import ClientUpdateConfig, make_eval_fn
 # pack_schedule: the benchmark's planted fault patches it by this name too
@@ -66,6 +67,16 @@ class FedAvgAPI:
     def __init__(self, dataset, spec: TrainSpec, args, mesh=None,
                  payload_fn=None, server_fn=None, server_state=None,
                  metrics_logger=None, compressor=None):
+        # from here on compile events are spans of the tracer's record,
+        # each under the span that paid it (start-up has no watcher)
+        feed_tracer()
+        self._round_log = RoundLog()
+        with get_tracer().span("build", api=type(self).__name__):
+            self._build(dataset, spec, args, mesh, payload_fn, server_fn,
+                        server_state, metrics_logger, compressor)
+
+    def _build(self, dataset, spec, args, mesh, payload_fn, server_fn,
+               server_state, metrics_logger, compressor):
         (self.train_data_num, self.test_data_num, self.train_data_global,
          self.test_data_global, self.train_data_local_num_dict,
          self.train_data_local_dict, self.test_data_local_dict,
@@ -111,7 +122,9 @@ class FedAvgAPI:
 
         seed = getattr(args, "seed", 0)
         self.rng = jax.random.PRNGKey(seed)
-        global_state = spec.init_fn(jax.random.fold_in(self.rng, 0))
+        tracer = get_tracer()
+        with tracer.span("init-state"):
+            global_state = spec.init_fn(jax.random.fold_in(self.rng, 0))
         self.round_idx = 0
         self.history = []
 
@@ -127,15 +140,23 @@ class FedAvgAPI:
         self.program = RoundProgram.from_args(
             args, codec=compressor if compressor is not None else "none",
             client_update=(spec, cfg))
-        self.runner = select_runner(
-            self.program, spec, cfg, args, mesh, self.train_data_local_dict,
-            global_state["params"], payload_fn=payload_fn,
-            server_fn=server_fn, compressor=compressor,
-            data_rng=np.random.default_rng(seed))
+        with tracer.span("select-runner") as sp:
+            self.runner = select_runner(
+                self.program, spec, cfg, args, mesh,
+                self.train_data_local_dict, global_state["params"],
+                payload_fn=payload_fn, server_fn=server_fn,
+                compressor=compressor,
+                data_rng=np.random.default_rng(seed))
+            sp.set(mode=self.runner.mode)
         self.compressor = self.runner.compressor
-        self.global_state = self.place_state(global_state)
-        self.server_state = self.place_state(
-            server_state if server_state is not None else ())
+        # the state's way to the device, once (the runner has refused a
+        # bogus combination by now): left on the host it would ride every
+        # chunk program's dispatch again until the first server step
+        # returns it from the device
+        with tracer.span("init-state"):
+            self.global_state = self.place_state(global_state)
+            self.server_state = self.place_state(
+                server_state if server_state is not None else ())
         self.eval_fn = make_eval_fn(spec)
 
     @property
@@ -152,9 +173,11 @@ class FedAvgAPI:
         """Put a global/server state pytree where the round functions
         return it: replicated over the mesh on the sharded paths (a state
         left on device 0 gives round 1 a new input sharding, and the whole
-        round program compiles a second time), untouched otherwise."""
+        round program compiles a second time), on the default device
+        otherwise (where the rounds leave it; device arrays pass through
+        as they are)."""
         if self.mesh is None:
-            return tree
+            return jax.device_put(tree)
         from jax.sharding import PartitionSpec as P
 
         from fedml_tpu.parallel.multihost import global_put
@@ -215,10 +238,17 @@ class FedAvgAPI:
         tracer = get_tracer()
         mon = get_perf_monitor()  # one global read when monitoring is off
         t0 = time.time()
+        self._round_log.begin()
         with (mon.xprof(self.round_idx) if mon is not None
               else contextlib.nullcontext()):
-            with tracer.span("round", round=int(self.round_idx)):
+            with tracer.span("round", round=int(self.round_idx)) as rnd:
                 train_metrics = self._traced_round_body(tracer, t0)
+        # the record's two reports: the start-up ends at the first round
+        # that compiles nothing; a later round far over its neighbours'
+        # median is warned about once, with what grew
+        self._round_log.end(
+            tracer, rnd,
+            self._last_info.get("bucket", {}).get("executed_steps"))
         if mon is not None:
             # true steps are known host-side only on the bucketed path;
             # elsewhere the per-step histogram is skipped rather than

@@ -233,8 +233,9 @@ def observability_scope(args, logger):
     Exports ``trace.json``/``spans.jsonl`` to ``--trace_dir`` (default
     ``--run_dir``), flight-recorder dumps, ``metrics.prom`` and
     ``status.json`` to ``--run_dir`` (else the trace dir); a run with
-    every flag off gets the no-op tracer and zero observability code on
-    the hot paths."""
+    every flag off keeps the default tracer (the recorder level: spans
+    timed into a ring, nothing exported, nothing on the wire) and no
+    other observability code on the hot paths."""
     from fedml_tpu.observability import enable
 
     trace = bool(getattr(args, "trace", 0))

@@ -4,8 +4,12 @@ The observability layer the scale-out arc reports against (see
 docs/OBSERVABILITY.md). Three composable pieces, one switchboard:
 
 - :mod:`~fedml_tpu.observability.tracing`: Dapper-style spans over the
-  round lifecycle, propagated across ranks in the message envelope's
-  ``__trace__`` control field; Chrome-trace + JSONL export.
+  round lifecycle, at two levels of one ``Tracer``: the recorder (the
+  process's default: a bounded ring on the host clock, from which
+  :func:`startup_report` and the ``round_stall`` warning are made) and
+  the exporting level (``--trace``: propagated across ranks in the
+  message envelope's ``__trace__`` control field; Chrome-trace + JSONL
+  export).
 - :mod:`~fedml_tpu.observability.registry`: counters/gauges/histograms
   with labels; per-round snapshots into ``metrics.jsonl`` records and a
   Prometheus text dump at exit.
@@ -13,14 +17,17 @@ docs/OBSERVABILITY.md). Three composable pieces, one switchboard:
   control-plane events dumped to ``flightrec_<reason>.jsonl`` on
   PEER_LOST, abandoned rounds, and unhandled crashes.
 - :mod:`~fedml_tpu.observability.jaxmon`: per-round compile count +
-  duration via ``jax.monitoring``.
+  duration via ``jax.monitoring``; the same events as spans under the
+  span that paid them.
 
-Everything defaults OFF: the module-level tracer is a no-op, the registry
-and recorder globals are None, and every instrumentation point in the
-engine/transports/FSMs guards on that -- a run without ``--trace`` /
-``--flightrec`` executes no observability code beyond one global read per
-event and produces bit-identical results. :func:`enable` flips the
-switchboard for a scope and writes the artifacts on exit.
+Everything but the recorder defaults OFF: ``get_tracer().enabled`` is
+False, the registry and flight-recorder globals are None, and every
+instrumentation point in the engine/transports/FSMs guards on that -- a
+run without ``--trace`` / ``--flightrec`` times its context-managed spans
+(a few microseconds each) and otherwise executes no observability code
+beyond one global read per event, sends bit-identical frames and
+produces bit-identical results. :func:`enable` flips the switchboard for
+a scope and writes the artifacts on exit.
 """
 
 from __future__ import annotations
@@ -41,9 +48,10 @@ from fedml_tpu.observability.perfmon import (PerfMonitor, StatusWriter,
                                              set_perf_monitor)
 from fedml_tpu.observability.registry import (MetricsRegistry, get_registry,
                                               set_registry)
-from fedml_tpu.observability.tracing import (NOOP_TRACER, NoopTracer, Span,
-                                             SpanContext, TRACE_KEY, Tracer,
-                                             get_tracer, set_tracer)
+from fedml_tpu.observability.tracing import (NOOP_TRACER, NoopTracer,
+                                             RoundLog, Span, SpanContext,
+                                             TRACE_KEY, Tracer, get_tracer,
+                                             set_tracer, startup_report)
 
 
 def add_observability_args(parser):
@@ -110,7 +118,9 @@ def enable(trace=False, trace_dir=None, flightrec=False, flightrec_dir=None,
     ``metrics.prom`` (in ``flightrec_dir`` or ``trace_dir`` when either
     is set), pushes the compile / perf-monitor / cost-model reports to
     ``metrics_logger``, forces a final ``status.json`` write, and
-    restores the previous globals (scopes nest).
+    pushes the process's ``startup`` record (:func:`startup_report`, as
+    far as the start-up got) and restores the previous globals (scopes
+    nest).
 
     ``compile_events`` defaults to ``trace`` -- the watcher needs jax, so
     a flight-recorder-only scope stays jax-free. ``perfmon`` arms the
@@ -159,6 +169,10 @@ def enable(trace=False, trace_dir=None, flightrec=False, flightrec_dir=None,
     try:
         yield state
     finally:
+        if metrics_logger is not None:
+            report = startup_report()
+            if report is not None and report["rounds"]:
+                metrics_logger({"startup": report})
         if state.compile_watcher is not None:
             state._watch_cm.__exit__(None, None, None)
             report = state.compile_watcher.report()
@@ -258,7 +272,8 @@ def _uninstall_crash_hooks(hooks):
 
 
 __all__ = ["Tracer", "NoopTracer", "NOOP_TRACER", "Span", "SpanContext",
-           "TRACE_KEY", "get_tracer", "set_tracer",
+           "TRACE_KEY", "get_tracer", "set_tracer", "startup_report",
+           "RoundLog",
            "MetricsRegistry", "get_registry", "set_registry",
            "FlightRecorder", "get_flight_recorder", "set_flight_recorder",
            "PerfMonitor", "StatusWriter", "get_perf_monitor",

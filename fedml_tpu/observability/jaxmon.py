@@ -15,6 +15,13 @@ the auditor uses, feeding:
 
 Unlike the auditor this is pure measurement -- no transfer guard, no
 gates -- so it can stay on for every traced run.
+
+The same events, as they end, become finished spans of the current
+tracer under the span that paid them (``jax.trace``, ``jax.lower``,
+``jax.compile`` with ``cache`` hit / miss / none, ``jax.cache_load``;
+``fun`` where jax names the function): ONE listener a process
+(:func:`feed_tracer`, which the round loop calls when it is built) feeds
+the tracer and whichever watchers are armed.
 """
 
 from __future__ import annotations
@@ -22,6 +29,8 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+
+from fedml_tpu.observability.tracing import get_tracer, union_length
 
 #: jax.monitoring event names (same stable strings the runtime auditor
 #: pins; see fedml_tpu.analysis.runtime).
@@ -44,15 +53,69 @@ CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 _current = None
 
+#: the tracer's span for each duration event
+_SPAN_OF = {TRACE_EVENT: "jax.trace", LOWER_EVENT: "jax.lower",
+            COMPILE_EVENT: "jax.compile", CACHE_LOAD_EVENT: "jax.cache_load"}
+_CACHE_OUTCOME = {CACHE_HIT_EVENT: "hit", CACHE_MISS_EVENT: "miss"}
 
-def _union_seconds(intervals):
-    """Length of the union of ``[(start, end)]``."""
-    total, reach = 0.0, float("-inf")
-    for a, b in sorted(intervals):
-        if b > reach:
-            total += b - max(a, reach)
-            reach = b
-    return total
+#: the process's one pair of jax.monitoring listeners: registered while a
+#: watcher is armed or once the round loop has asked for the tracer's feed
+_armed = []
+_state = {"pinned": False, "registered": False}
+_lock = threading.Lock()
+#: a compile's cache outcome arrives as a plain event inside its
+#: duration event, on the compiling thread
+_outcome = threading.local()
+
+
+def _on_duration(event, duration_secs, **kwargs):
+    name = _SPAN_OF.get(event)
+    if name is not None:
+        attrs = {}
+        if kwargs.get("fun_name"):
+            attrs["fun"] = str(kwargs["fun_name"])
+        if event == COMPILE_EVENT:
+            attrs["cache"] = getattr(_outcome, "last", "none")
+            _outcome.last = "none"
+        # an event of the same kind, or a trace, that ended inside this
+        # one was nested in it (a jit traced while its caller is traced
+        # or lowered): the outer one stands for both, so a start-up's
+        # thousands of nested traces are tens of spans and no second is
+        # in two sums (a cache load stays beside its compile)
+        get_tracer().record(name, duration_secs,
+                            absorb=(name, "jax.trace"), **attrs)
+    for watcher in tuple(_armed):
+        watcher._on_event(event, duration_secs)
+
+
+def _on_plain(event, **kwargs):
+    if event in _CACHE_OUTCOME:
+        _outcome.last = _CACHE_OUTCOME[event]
+    for watcher in tuple(_armed):
+        watcher._on_plain_event(event)
+
+
+def _sync_listeners():
+    """Register the pair when someone listens, take it out when no one
+    does (jax.monitoring's listener lists are process-global)."""
+    from jax import monitoring
+    wanted = bool(_state["pinned"] or _armed)
+    if wanted and not _state["registered"]:
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_plain)
+    elif not wanted and _state["registered"]:
+        monitoring.unregister_event_duration_listener(_on_duration)
+        monitoring.unregister_event_listener(_on_plain)
+    _state["registered"] = wanted
+
+
+def feed_tracer():
+    """Keep the compile events flowing to the current tracer for the rest
+    of the process, watcher or none (an operator's run without
+    ``--compile_events`` has a start-up too). Idempotent."""
+    with _lock:
+        _state["pinned"] = True
+        _sync_listeners()
 
 
 def current_watcher():
@@ -65,7 +128,6 @@ class CompileWatcher:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._active = False
         self._compiles = 0
         self._compile_s = 0.0
         self._traces = 0
@@ -90,9 +152,7 @@ class CompileWatcher:
         self.cache_hits = 0
         self.cache_misses = 0
 
-    def _on_event(self, event, duration_secs, **kwargs):
-        if not self._active:
-            return
+    def _on_event(self, event, duration_secs):
         from fedml_tpu.observability.registry import get_registry
         reg = get_registry()
         with self._lock:
@@ -119,9 +179,7 @@ class CompileWatcher:
                 reg.inc("jax_traces_total",
                         help="jaxpr traces observed")
 
-    def _on_plain_event(self, event, **kwargs):
-        if not self._active:
-            return
+    def _on_plain_event(self, event):
         if event == CACHE_HIT_EVENT:
             with self._lock:
                 self.cache_hits += 1
@@ -153,11 +211,11 @@ class CompileWatcher:
                     round(self.total_compile_seconds, 4),
                 "compile/total_traces": self.total_traces,
                 "compile/trace_seconds": round(
-                    _union_seconds(self._phases[TRACE_EVENT]), 4),
+                    union_length(self._phases[TRACE_EVENT]), 4),
                 "compile/lower_seconds": round(
-                    _union_seconds(self._phases[LOWER_EVENT]), 4),
+                    union_length(self._phases[LOWER_EVENT]), 4),
                 "compile/cache_load_seconds": round(
-                    _union_seconds(self._phases[CACHE_LOAD_EVENT]), 4),
+                    union_length(self._phases[CACHE_LOAD_EVENT]), 4),
                 "compile/cache_hits": self.cache_hits,
                 "compile/cache_misses": self.cache_misses,
             }
@@ -175,17 +233,15 @@ class CompileWatcher:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
-        from jax import monitoring
-        self._active = True
-        monitoring.register_event_duration_secs_listener(self._on_event)
-        monitoring.register_event_listener(self._on_plain_event)
+        with _lock:
+            _armed.append(self)
+            _sync_listeners()
         return self
 
     def stop(self):
-        from jax import monitoring
-        self._active = False
-        monitoring.unregister_event_duration_listener(self._on_event)
-        monitoring.unregister_event_listener(self._on_plain_event)
+        with _lock:
+            _armed.remove(self)
+            _sync_listeners()
 
 
 @contextlib.contextmanager
@@ -204,5 +260,6 @@ def watch_compiles():
 
 
 __all__ = ["CompileWatcher", "watch_compiles", "current_watcher",
+           "feed_tracer",
            "TRACE_EVENT", "COMPILE_EVENT", "LOWER_EVENT",
            "CACHE_LOAD_EVENT", "CACHE_HIT_EVENT", "CACHE_MISS_EVENT"]

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -552,42 +553,49 @@ class BucketedStreamRunner:
         from fedml_tpu.parallel.packing import (
             _steps_for, bucket_edge_for, gather_batches, pack_schedule)
 
-        data_rng, aggregator = self.data_rng, self.aggregator
-        residual_store = self.residual_store
-        client_ids = [int(i) for i in client_indexes]
-        datasets = [self.shards[i] for i in client_ids]
-        C = len(datasets)
-        if C == 0:
-            raise ValueError("bucketed round over an empty cohort")
-        if self.compressor is not None and residual_store is None:
-            raise ValueError(
-                "streaming-EF needs a residual_store: the error-feedback "
-                "accumulator is keyed by stable client id ACROSS rounds "
-                "(compression.ResidualStore; select_runner builds one)")
-        ns = [len(d["y"]) for d in datasets]
-        if sum(ns) == 0:
-            raise ValueError("bucketed round: every client shard is empty")
-        if self.batch_size in (-1, 0):
-            # full-batch convention: resolve ONCE (first cohort seen) and
-            # pin it -- a per-cohort B would change the [C, S, B] compiled
-            # shape whenever a re-sampled cohort's largest shard differs,
-            # breaking the zero-steady-state-retrace invariant. FedAvgAPI
-            # resolves from the POPULATION max before construction.
-            self.batch_size = max(1, max(ns))
-        bs = self.batch_size
-        steps_pc = np.asarray(
-            [_steps_for(max(n, 1), bs, self.epochs) for n in ns], np.int64)
-        bucket_edge_for(steps_pc.max(), self.edges)  # top-edge guard
-        client_keys = np.asarray(
-            jax.random.split(jax.random.fold_in(rng, 1), C))
-        dtypes = _payload_dtypes(self, global_state)
-        flush_rng = jax.random.fold_in(rng, 2)
-        comp_keys = None
-        if self.compressor is not None:
-            # fold 3 is the compression stream -- the same derivation
-            # rule as make_compressed_sim_round, per stable cohort slot
-            comp_keys = np.asarray(
-                jax.random.split(jax.random.fold_in(rng, 3), C))
+        # spans by the job done, one per step per chunk (never per leaf);
+        # PERF.md section 3 says which metric reads each
+        tracer = get_tracer()
+        # the round's preamble, a leaf of its own: the cohort's shards and
+        # step counts, the split of the round's keys and its fetch to the
+        # host, the payload's dtypes (everything before the first pack)
+        with tracer.span("prepare", clients=len(client_indexes)):
+            data_rng, aggregator = self.data_rng, self.aggregator
+            residual_store = self.residual_store
+            client_ids = [int(i) for i in client_indexes]
+            datasets = [self.shards[i] for i in client_ids]
+            C = len(datasets)
+            if C == 0:
+                raise ValueError("bucketed round over an empty cohort")
+            if self.compressor is not None and residual_store is None:
+                raise ValueError(
+                    "streaming-EF needs a residual_store: the error-feedback "
+                    "accumulator is keyed by stable client id ACROSS rounds "
+                    "(compression.ResidualStore; select_runner builds one)")
+            ns = [len(d["y"]) for d in datasets]
+            if sum(ns) == 0:
+                raise ValueError("bucketed round: every client shard is empty")
+            if self.batch_size in (-1, 0):
+                # full-batch convention: resolve ONCE (first cohort seen) and
+                # pin it -- a per-cohort B would change the [C, S, B] compiled
+                # shape whenever a re-sampled cohort's largest shard differs,
+                # breaking the zero-steady-state-retrace invariant. FedAvgAPI
+                # resolves from the POPULATION max before construction.
+                self.batch_size = max(1, max(ns))
+            bs = self.batch_size
+            steps_pc = np.asarray(
+                [_steps_for(max(n, 1), bs, self.epochs) for n in ns], np.int64)
+            bucket_edge_for(steps_pc.max(), self.edges)  # top-edge guard
+            client_keys = np.asarray(
+                jax.random.split(jax.random.fold_in(rng, 1), C))
+            dtypes = _payload_dtypes(self, global_state)
+            flush_rng = jax.random.fold_in(rng, 2)
+            comp_keys = None
+            if self.compressor is not None:
+                # fold 3 is the compression stream -- the same derivation
+                # rule as make_compressed_sim_round, per stable cohort slot
+                comp_keys = np.asarray(
+                    jax.random.split(jax.random.fold_in(rng, 3), C))
 
         gs, ss = global_state, server_state
         cm = get_cost_model()  # one global read when attribution is off
@@ -614,9 +622,6 @@ class BucketedStreamRunner:
         inflight = deque()
         exec_steps = 0
         per_bucket = []
-        # spans by the job done, one per step per chunk (never per leaf);
-        # PERF.md section 3 says which metric reads each
-        tracer = get_tracer()
 
         def note_bytes(sp, arrays):
             # a walk over every leaf: traced rounds only
@@ -647,7 +652,12 @@ class BucketedStreamRunner:
                     # the host did before waited for the last programs
                     # (a mid-round flush of the buffered path does not
                     # wait: chunks are still in flight behind it)
+                    waiting = time.perf_counter()
                     jax.block_until_ready((gs, ss))
+                    # the span's part in which the host waited for the
+                    # device (the stalled-round report tells it from the
+                    # dispatch before it)
+                    sp.set(blocked_s=time.perf_counter() - waiting)
 
         def fold_oldest():
             nonlocal flushes, metrics_acc, sync_w
@@ -732,8 +742,11 @@ class BucketedStreamRunner:
                 batches_dev = {"x": jnp.asarray(xb), "y": jnp.asarray(yb),
                                "mask": jnp.asarray(maskb)}
                 ns_dev, rngs_dev = jnp.asarray(n_arr), jnp.asarray(rngs)
+                # the trip count is a transfer too (0.5 ms a chunk on the
+                # chip's host): inside the span, not between two
+                trip_dev = jnp.int32(trip)
                 note_bytes(sp, (batches_dev, ns_dev, rngs_dev))
-            args = (gs, batches_dev, ns_dev, jnp.int32(trip), rngs_dev)
+            args = (gs, batches_dev, ns_dev, trip_dev, rngs_dev)
             ids = None
             if self.compressor is not None:
                 # EF residual rows for this chunk, gathered by STABLE

@@ -282,8 +282,12 @@ def select_runner(program, spec, cfg, args, mesh, shards, params, *,
     wants_residency = (device_resident and compressor is None
                        and not stream
                        and (mesh is None or wave_mode in (2, 3)))
-    stacked, nbytes = (stack_if_fits(shards, args) if wants_residency
-                       else (None, 0))
+    # the data's way to where the rounds read it, by mode: the stack for
+    # residency here and its upload below, or the population's step
+    # counts that size the stream's bucket edges
+    with get_tracer().span("index-data", clients=len(shards)):
+        stacked, nbytes = (stack_if_fits(shards, args) if wants_residency
+                           else (None, 0))
     if stacked is None and wave_mode in (2, 3):
         raise ValueError(
             f"--wave_mode {wave_mode} runs lanes over device-resident "
@@ -322,14 +326,15 @@ def select_runner(program, spec, cfg, args, mesh, shards, params, *,
         # edges are sized from the POPULATION max so bucket shapes -- and
         # therefore compiled programs -- are stable across rounds no
         # matter which cohort is sampled
-        pop_ns = [len(d["y"]) for d in shards.values()]
-        # the RESOLVED batch size: -1 (full-batch) must pin to the
-        # population max, not each cohort's, or re-sampled cohorts change
-        # the compiled [C, S, B] shape
-        eff_bs = (args.batch_size if args.batch_size not in (-1, 0)
-                  else max(1, max(pop_ns)))
-        s_max = max(_steps_for(max(n, 1), eff_bs, args.epochs)
-                    for n in pop_ns)
+        with get_tracer().span("index-data", clients=len(shards)):
+            pop_ns = [len(d["y"]) for d in shards.values()]
+            # the RESOLVED batch size: -1 (full-batch) must pin to the
+            # population max, not each cohort's, or re-sampled cohorts
+            # change the compiled [C, S, B] shape
+            eff_bs = (args.batch_size if args.batch_size not in (-1, 0)
+                      else max(1, max(pop_ns)))
+            s_max = max(_steps_for(max(n, 1), eff_bs, args.epochs)
+                        for n in pop_ns)
         return program.compile_bucketed(
             *hooks, client_chunk=chunk, batch_size=eff_bs,
             epochs=args.epochs,
@@ -350,7 +355,8 @@ def select_runner(program, spec, cfg, args, mesh, shards, params, *,
                             data_rng, mesh=mesh, **ef)
     host = {"x": stacked["x"], "y": stacked["y"]}
     if mesh is None:
-        device_data = jax.tree.map(jnp.asarray, host)
+        with get_tracer().span("index-data", bytes=int(nbytes)):
+            device_data = jax.tree.map(jnp.asarray, host)
         dispatch = (
             LaneRunner(*hooks, n_lanes=chunk, packed=wave_mode == 3)
             if wave_mode in (2, 3)
@@ -363,7 +369,8 @@ def select_runner(program, spec, cfg, args, mesh, shards, params, *,
         # aggregation is one psum; wave_mode 3 additionally folds each
         # shard's lane axis into channels (MXU-shaped lowering)
         from fedml_tpu.parallel.multihost import global_cohort
-        device_data = global_cohort(mesh, host)
+        with get_tracer().span("index-data", bytes=int(nbytes)):
+            device_data = global_cohort(mesh, host)
         dispatch = ShardedLaneRunner(spec, cfg, mesh, payload_fn, server_fn,
                                      n_lanes=chunk, packed=wave_mode == 3)
     return ResidentRunner(dispatch, device_data, stacked["n"],

@@ -245,7 +245,8 @@ class TestFlightRecorder:
     def test_enable_scope_installs_and_restores_globals(self, tmp_path):
         assert get_flight_recorder() is None
         assert get_registry() is None
-        assert get_tracer() is NOOP_TRACER
+        default = get_tracer()  # the recorder level, not the no-op
+        assert isinstance(default, Tracer) and not default.enabled
         with enable(trace=True, trace_dir=str(tmp_path), flightrec=True,
                     compile_events=False) as obs:
             assert get_flight_recorder() is obs.recorder
@@ -253,7 +254,7 @@ class TestFlightRecorder:
             assert get_tracer() is obs.tracer
         assert get_flight_recorder() is None
         assert get_registry() is None
-        assert get_tracer() is NOOP_TRACER
+        assert get_tracer() is default
         assert os.path.exists(obs.chrome_path)
         assert os.path.exists(obs.prom_path)
 

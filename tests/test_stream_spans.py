@@ -1,7 +1,9 @@
 """Spans inside the bucketed round (ISSUE 25): the fold's
 steps, the feed and the wait each have a span where the work happens, a
 real ``Tracer``'s spans stand on the profiler's timeline too, and the
-no-op tracer leaves the round bitwise what it was."""
+no-op tracer leaves the round bitwise what it was. Since ISSUE 36 the
+round's preamble is a leaf (``prepare``) and the default tracer records
+the same tree less what only a traced round does."""
 
 import os
 import subprocess
@@ -11,7 +13,7 @@ import types
 import numpy as np
 import pytest
 
-from fedml_tpu.observability import Tracer, set_tracer
+from fedml_tpu.observability import NOOP_TRACER, Tracer, set_tracer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CLIENTS, CHUNK = 10, 4
@@ -20,7 +22,8 @@ CHUNKS = -(-CLIENTS // CHUNK)
 #: chunk's payload sum becomes the device accumulator's high word (no
 #: span: nothing is done), every later chunk's is added to it by a
 #: program of its own (``fold.add``, the dispatch)
-SYNC_SPANS = {"pack": CHUNKS, "h2d": CHUNKS, "bucket-chunk": CHUNKS,
+SYNC_SPANS = {"prepare": 1,
+              "pack": CHUNKS, "h2d": CHUNKS, "bucket-chunk": CHUNKS,
               "fold.wait": CHUNKS, "fold.d2h": CHUNKS,
               "fold.add": CHUNKS - 1,
               "fold.finalize": 1, "fold.apply": 1}
@@ -154,11 +157,48 @@ def test_non_scalar_attributes_stay_out_of_the_annotation(tmp_path):
     assert "listed" in {n for n, _, _ in summary.host}
 
 
-def test_noop_and_real_tracer_rounds_are_bitwise_equal(traced):
+def test_prepare_is_the_leaf_before_the_first_pack(traced):
+    _, _, spans = traced
+    by = _by_name(spans)
+    prepare, = by["prepare"]
+    local, = by["local-train"]
+    assert prepare.parent_id == local.span_id
+    assert prepare.attrs == {"clients": CLIENTS}
+    first_pack = min(by["pack"], key=lambda s: s.t0)
+    assert local.t0 <= prepare.t0 and prepare.t1 <= first_pack.t0
+    # a leaf: no span opens inside it; the first round's compile events
+    # (the key split's program) hang under it as the span that paid them
+    inside = {s.name for s in spans if s.parent_id == prepare.span_id}
+    assert inside <= {"jax.trace", "jax.lower", "jax.compile",
+                      "jax.cache_load"}
+
+
+def test_the_default_tracer_records_the_round_less_the_traced_work():
+    """The recorder level: the same tree, but ``enabled`` is False, so no
+    explicit wait (it lands in ``fold.d2h``) and no walk over leaves."""
+    recorder = Tracer(max_spans=512, exporting=False)
+    _round(recorder)
+    spans = recorder.finished_spans()
+    by = _by_name(spans)
+    expect = dict(SYNC_SPANS, **{"fold.wait": 0})
+    assert {n: len(by.get(n, ())) for n in expect} == expect
+    assert not any("bytes" in s.attrs or "arrays" in s.attrs
+                   for s in by["h2d"] + by["fold.d2h"])
+    apply, = by["fold.apply"]
+    assert 0 <= apply.attrs["blocked_s"] <= (apply.t1 - apply.t0) / 1e6
+    by_id = {s.span_id: s for s in spans}
+    for name in SYNC_SPANS:
+        for s in by.get(name, ()):
+            assert "local-train" in _ancestors(s, by_id), name
+
+
+@pytest.mark.parametrize("off", [None, NOOP_TRACER],
+                         ids=["recorder", "noop"])
+def test_noop_and_real_tracer_rounds_are_bitwise_equal(traced, off):
     import jax
 
     api_on, m_on, _ = traced
-    api_off, m_off = _round()
+    api_off, m_off = _round(off)
     on = jax.tree.leaves(jax.tree.map(np.asarray, api_on.global_state))
     off = jax.tree.leaves(jax.tree.map(np.asarray, api_off.global_state))
     assert len(on) == len(off)
