@@ -276,7 +276,7 @@ class FedAvgAPI:
             if "fold" in info:
                 # the stream's metric sums are on the host already;
                 # "fold" says where the payload sums were combined
-                sp.set(fold=info["fold"],
+                sp.set(fold=info["fold"], **info["embed"],
                        **routing_counters(info["metrics"]),
                        **layer_mix_counters(info["metrics"]),
                        **block_diffusion_counters(info["metrics"]))

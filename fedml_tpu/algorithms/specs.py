@@ -14,6 +14,7 @@ import numpy as np
 
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.ops.cross_entropy import softmax_cross_entropy_with_stats
+from fedml_tpu.ops.row_embed import ROW_STEP
 
 
 def _apply_model(model, state, x, rng, train, with_sown=False,
@@ -29,6 +30,9 @@ def _apply_model(model, state, x, rng, train, with_sown=False,
     ``with_sown``) appends the counters the model sows into ``metrics``
     (the routing counters of ``models/deepseek_v3.py``), summed by name
     over the modules that sowed them: ``{}`` for a model that sows none.
+    A state that carries the row step's collection (``ops/row_embed.py``
+    ``ROW_STEP``, put there by the client-update loop alone) gets it back
+    mutated: the ids each lookup read.
     """
     variables = dict(state)
     rngs = ({"dropout": rng, "droppath": jax.random.fold_in(rng, 7)}
@@ -36,16 +40,18 @@ def _apply_model(model, state, x, rng, train, with_sown=False,
     mutable = ((["losses"] if with_sown else [])
                + (["metrics"] if with_metrics else [])
                + (["batch_stats"]
-                  if ("batch_stats" in state and train) else []))
+                  if ("batch_stats" in state and train) else [])
+               + ([ROW_STEP] if ROW_STEP in state else []))
     if not mutable:
         out = model.apply(variables, x, train=train, rngs=rngs)
         return out, state
     out, mutated = model.apply(variables, x, train=train, mutable=mutable,
                                rngs=rngs)
     new_state = state
-    if "batch_stats" in mutated:
-        new_state = dict(state)
-        new_state["batch_stats"] = mutated["batch_stats"]
+    for kept in ("batch_stats", ROW_STEP):
+        if kept in mutated:
+            new_state = dict(new_state)
+            new_state[kept] = mutated[kept]
     if not with_sown:
         return out, new_state
     aux = sum(jax.tree.leaves(mutated.get("losses", {})), 0.0)

@@ -86,6 +86,7 @@ import jax.numpy as jnp
 
 from fedml_tpu.ops.grouped_matmul import grouped_matmul
 from fedml_tpu.ops.pallas_attention import BlockDiffusion, flash_attention
+from fedml_tpu.ops.row_embed import RowEmbed
 from fedml_tpu.ops.short_conv import gated_short_conv
 from fedml_tpu.parallel.mesh import any_lane
 
@@ -677,8 +678,8 @@ class DecoderLM(nn.Module):
             keep = T // 2
             mask, positions = BlockDiffusion(keep, c.block_length), \
                 positions % keep
-        x = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
-                     name="tok_embed")(idx)
+        x = RowEmbed(c.vocab_size, c.hidden_size, dtype=self.dtype,
+                     name="tok_embed")(idx)   # ops/row_embed.py
         types = c.layer_types or ("full_attention",) * c.num_hidden_layers
         for i, mixer in enumerate(types):
             x = _DecoderLayer(c, i < c.first_k_dense_replace, self.dtype,

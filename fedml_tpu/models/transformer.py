@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.ops.pallas_attention import flash_attention
+from fedml_tpu.ops.row_embed import RowEmbed
 
 
 class _Block(nn.Module):
@@ -82,9 +83,11 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, idx, train: bool = False):
         B, T = idx.shape
-        tok = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+        # lookups a plain SGD step may update by rows (ops/row_embed.py);
+        # a table read whole (pos_embed at T = max_len) stays dense
+        tok = RowEmbed(self.vocab_size, self.d_model, dtype=self.dtype,
                        name="tok_embed")(idx)
-        pos = nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
+        pos = RowEmbed(self.max_len, self.d_model, dtype=self.dtype,
                        name="pos_embed")(jnp.arange(T)[None])
         x = tok + pos
         for i in range(self.n_layers):

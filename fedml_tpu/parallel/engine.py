@@ -49,6 +49,7 @@ from fedml_tpu.core import pytree
 from fedml_tpu.core.trainer import TrainSpec
 from fedml_tpu.observability.costmodel import get_cost_model, program_cost
 from fedml_tpu.observability.tracing import get_tracer
+from fedml_tpu.ops import row_embed
 from fedml_tpu.parallel.mesh import (CLIENT_AXIS, LANE_AXIS,
                                      zero_pad_leading)
 from fedml_tpu.program.aggregation import (split_total, two_word_add,
@@ -98,23 +99,35 @@ def _tree_select(pred, new, old):
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, old)
 
 
+def _augmented(spec: TrainSpec, batch, step_rng):
+    if spec.augment_fn is None:
+        return batch
+    batch = dict(batch)
+    batch["x"] = spec.augment_fn(batch["x"], jax.random.fold_in(step_rng, 13))
+    return batch
+
+
 def _make_grad_at(spec: TrainSpec):
     """``grad_at(params, rest, batch, step_rng)``: one step's
     augmentation, loss and gradient, as every client-update variant
-    takes them."""
+    takes them. The row step (:func:`_make_trip_loop_core`) passes the
+    tables it steps by rows apart from ``params`` and the lookups'
+    ``deltas``, and takes the gradient by both: ``(params, deltas)``."""
 
-    def grad_at(params, rest, batch, step_rng):
-        if spec.augment_fn is not None:
-            batch = dict(batch)
-            batch["x"] = spec.augment_fn(
-                batch["x"], jax.random.fold_in(step_rng, 13))
+    def grad_at(params, rest, batch, step_rng, tables=None, deltas=None):
+        batch = _augmented(spec, batch, step_rng)
 
-        def loss_wrapper(p):
+        def loss_wrapper(p, d=None):
             state = dict(rest)
-            state["params"] = p
+            state["params"] = row_embed.join_tables(p, tables)
+            if d is not None:
+                state[row_embed.ROW_STEP] = d
             return spec.loss_fn(state, batch, step_rng, True)
 
-        return jax.value_and_grad(loss_wrapper, has_aux=True)(params)
+        if deltas is None:
+            return jax.value_and_grad(loss_wrapper, has_aux=True)(params)
+        return jax.value_and_grad(loss_wrapper, argnums=(0, 1),
+                                  has_aux=True)(params, deltas)
 
     return grad_at
 
@@ -220,28 +233,63 @@ def _make_trip_loop_core(spec: TrainSpec, cfg: ClientUpdateConfig):
     chunk batches) differ ONLY in their ``batch_at`` -- fixes to
     masking, augmentation RNG, or optimizer semantics land here once.
 
+    **The row step.** Under plain SGD (no momentum, weight decay or
+    clipping: a step is ``p - lr * g`` and nothing else) a table that the
+    loss reads by one lookup of fewer positions than it has rows
+    (``ops/row_embed.py`` ``RowEmbed``: the token embedding of the LM
+    families) is carried apart from the other parameters and stepped by
+    the rows it looked up: ``table.at[ids].add(-lr * rows_ct)``, in place,
+    with the ids and the rows' cotangent from the lookup itself. No
+    table-shaped cast, gradient or select is made. What the probe of the
+    first step found is decided while tracing and kept in ``run.row_plan``
+    (``{module path: positions a step stepped by rows, 0 for dense}``).
+    Every other table, optimizer and model takes the dense step.
+
     Returns ``run(global_state, batch_at, trip, rng) ->
     (params, rest, metrics_sum)``.
     """
     optimizer = make_optimizer(cfg)
     grad_at = _make_grad_at(spec)
+    plain_sgd = (cfg.optimizer == "sgd" and not cfg.momentum
+                 and not cfg.weight_decay and not cfg.grad_clip)
+    row_plan = {}
+
+    def probe(params, rest, batch, rng):
+        # one step's forward: its metric structure and, under plain SGD,
+        # the lookups that could be stepped by rows
+        state = dict(rest)
+        state["params"] = params
+        if plain_sgd:
+            state[row_embed.ROW_STEP] = {}
+        _, (new_state, metrics) = spec.loss_fn(
+            state, _augmented(spec, batch, rng), rng, True)
+        return metrics, row_embed.lookups(
+            new_state.get(row_embed.ROW_STEP, {}))
 
     def run(global_state, batch_at, trip, rng):
         params, rest = _split_state(global_state)
+
+        # abstract-eval one step: carry zeros of its metrics; decide the
+        # tables stepped by rows from the shapes it looked up
+        metrics0, found = jax.eval_shape(
+            lambda: probe(params, rest, batch_at(0), rng))
+        metrics0 = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                metrics0)
+        rows = row_embed.plan_rows(params, found)
+        row_plan.update({"/".join(p): (rows[p].size if p in rows else 0)
+                         for p in found})
+        tables, params = row_embed.split_tables(params, rows)
         opt_state = optimizer.init(params)
 
-        # metric-structure discovery: abstract-eval one step, carry zeros
-        metrics0 = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype),
-            jax.eval_shape(
-                lambda: grad_at(params, rest, batch_at(0), rng))[0][1][1])
-
         def body(i, carry):
-            params, rest, opt_state, msum = carry
+            params, tables, rest, opt_state, msum = carry
             batch = batch_at(i)
             step_rng = jax.random.fold_in(rng, i)
+            deltas = row_embed.zero_deltas(tables, rows) if rows else None
             (_, (new_state, metrics)), grads = grad_at(
-                params, rest, batch, step_rng)
+                params, rest, batch, step_rng, tables, deltas)
+            if rows:
+                grads, rows_ct = grads
             updates, new_opt = optimizer.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
             new_rest = {k: new_state[k] for k in rest}
@@ -249,13 +297,18 @@ def _make_trip_loop_core(spec: TrainSpec, cfg: ClientUpdateConfig):
             params, rest, opt_state = _tree_select(
                 valid, (new_params, new_rest, new_opt),
                 (params, rest, opt_state))
+            if rows:
+                tables = row_embed.step_rows(
+                    tables, new_state[row_embed.ROW_STEP], rows_ct, valid,
+                    cfg.lr)
             msum = jax.tree.map(jnp.add, msum, metrics)
-            return (params, rest, opt_state, msum)
+            return (params, tables, rest, opt_state, msum)
 
-        params, rest, _, msum = jax.lax.fori_loop(
-            0, trip, body, (params, rest, opt_state, metrics0))
-        return params, rest, msum
+        params, tables, rest, _, msum = jax.lax.fori_loop(
+            0, trip, body, (params, tables, rest, opt_state, metrics0))
+        return row_embed.join_tables(params, tables), rest, msum
 
+    run.row_plan = row_plan
     return run
 
 
@@ -321,6 +374,7 @@ def make_streamed_client_update(spec: TrainSpec, cfg: ClientUpdateConfig):
         aux = {"n": n, "steps": steps_done}
         return local_state, aux, msum
 
+    client_update.row_plan = run.row_plan
     return client_update
 
 
@@ -434,6 +488,8 @@ class BucketedStreamRunner:
         self.residual_store = residual_store
         self.wire_bytes = wire_bytes
         client_update = make_streamed_client_update(spec, cfg)
+        # the row step's decisions, made when chunk_fn is first traced
+        self._row_plan = client_update.row_plan
         payload_fn_ = self.payload_fn
         server_fn_ = self.server_fn
 
@@ -857,11 +913,16 @@ class BucketedStreamRunner:
             async_info = None
 
         true_steps = int(steps_pc.sum())
+        row_positions = sum(self._row_plan.values())
         info = {
             "aux": {"n": np.asarray(ns, np.float32),
                     "steps": steps_pc.astype(np.int64)},
             "metrics": metrics_acc,
             "fold": "device" if on_device else "host",
+            # how the lookup tables were stepped: by the rows looked up
+            # (positions a step times the clients' steps) or densely
+            "embed": {"embed.step": "rows" if row_positions else "dense",
+                      "embed.rows": row_positions * true_steps},
             "bucket": {
                 "edges": list(self.edges),
                 "buckets_used": sum(1 for b in per_bucket

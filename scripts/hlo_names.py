@@ -25,7 +25,9 @@ long it takes. The first line also sums the compiler's own
 but a cheap first word on a change (it ranked four forms of the loss as
 the chip had, and agrees in sign with the 0.4 % that ``kanana2`` lost;
 PERF.md, PR 32). 20-90 s on a CPU core. ``--text <file>`` keeps
-the whole optimized HLO.
+the whole optimized HLO; ``--table 50257,2048`` lists what writes an
+array of the token table's shape (PR 38: the loop body keeps one, the
+in-place scatter of the row step).
 """
 
 import argparse
@@ -216,6 +218,31 @@ def wide_rows(text, rows, within="/moe/", columns=64):
                                      for o in rec[2]))]
 
 
+def table_shaped(text, rows, columns):
+    """``[(computation, name, opcode, output, op_name)]`` of every
+    instruction that stands in a computation of its own right (as
+    :func:`instructions` counts them) and writes an array of ``rows`` x
+    ``columns`` (a leading lane axis of 1 allowed): a token table's
+    shape. Since PR 38 the loop body of a row-stepped cell holds one,
+    the in-place scatter into the carried table."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", text))
+    shapes = {f"[{rows},{columns}]", f"[1,{rows},{columns}]"}
+    out = []
+    for comp, lines in _computations(text).items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ([a-z]+[0-9]*)"
+                         r"(\[[0-9,]*\])\S* ([a-z][\w\-]*)\((.*)", line)
+            if m and m.group(3) in shapes \
+                    and m.group(4) not in _MOVES_NOTHING:
+                op_name = re.search(r'op_name="([^"]*)"', m.group(5))
+                out.append((comp, m.group(1), m.group(4),
+                            m.group(2) + m.group(3),
+                            op_name.group(1) if op_name else ""))
+    return out
+
+
 def describe(name, instrs):
     result, opcode, operands, op_name, cycles = instrs[name]
     plain = lambda s: re.sub(r"\{[^}]*\}", "", s)
@@ -260,6 +287,10 @@ def main(argv=None):
                          "the loop body is the same at every edge)")
     ap.add_argument("--text", default=None,
                     help="write the optimized HLO here")
+    ap.add_argument("--table", default=None,
+                    help="ROWS,COLUMNS: also list every instruction that "
+                         "writes an array of a token table's shape, with "
+                         "the computation it stands in")
     ap.add_argument("--rows", type=int, default=0,
                     help="also count the instructions outside the "
                          "conditionals' fallback that touch an array of "
@@ -308,6 +339,13 @@ def main(argv=None):
             "wide_outside_fallback": len(wide)}))
         for name in wide:
             print(json.dumps(describe(name, instrs)))
+    if args.table:
+        rows, columns = (int(v) for v in args.table.split(","))
+        for comp, name, opcode, output, op_name in table_shaped(
+                text, rows, columns):
+            print(json.dumps({"table_shaped": name, "opcode": opcode,
+                              "output": output, "computation": comp,
+                              "op_name": op_name}))
     for name in names:
         print(json.dumps(describe(name, instrs) if name in instrs
                          else {"name": name, "missing": True}))

@@ -342,18 +342,13 @@ def test_fold_programs_keep_their_arithmetic_and_allocate_nothing(
         assert (count("add"), count("subtract")) == (adds, subtracts)
 
 
-def test_gpt2_client_update_passes_over_the_logits_once(topo,
-                                                        hardware_path):
+@pytest.fixture(scope="module")
+def gpt2_program(topo):
     """``cgpt1.3b-silo4-long``'s client-update program (the stream's
     ``chunk_fn``: ``make_streamed_client_update`` under the lane ``vmap``;
     d 2048, 4 layers, vocabulary 50257, batch 2 x 2048, from the
-    benchmark's own files through ``scripts/hlo_names.py``) compiled for
-    the described v5e: the counter that says the one cross-entropy op
-    engaged. Exactly ONE instruction writes an f32 array of the logits'
-    size (the head's forward product; ``log_softmax`` kept a second one
-    for its backward) and it is read by four: the loss's one forward
-    pass, the head's two gradient products, which form ``dlogits`` inside
-    their fusions, and the bias gradient's sum."""
+    benchmark's own files through ``scripts/hlo_names.py``) compiled once
+    for the described v5e: ``(the script, the compiled program)``."""
     from benchmarks.manifest import Manifest
 
     names = _load_script("hlo_names")
@@ -361,9 +356,23 @@ def test_gpt2_client_update_passes_over_the_logits_once(topo,
     entry = man.cell("cgpt1.3b-silo4-long")
     config, traffic = man.config(entry["config"]), \
         man.traffic(entry["traffic"])
-    compiled = names.compile_chunk_program(
-        names.spec_of(config, traffic, man.reference(config)), traffic,
-        steps=8, device=topo.devices[0])
+    with pytest.MonkeyPatch.context() as mp:
+        for kernels in (pa, gm, sc):
+            mp.setattr(kernels, "_use_interpret", lambda: False)
+        compiled = names.compile_chunk_program(
+            names.spec_of(config, traffic, man.reference(config)), traffic,
+            steps=8, device=topo.devices[0])
+    return names, compiled
+
+
+def test_gpt2_client_update_passes_over_the_logits_once(gpt2_program):
+    """The counter that says the one cross-entropy op engaged. Exactly
+    ONE instruction writes an f32 array of the logits' size (the head's
+    forward product; ``log_softmax`` kept a second one for its backward)
+    and it is read by four: the loss's one forward pass, the head's two
+    gradient products, which form ``dlogits`` inside their fusions, and
+    the bias gradient's sum."""
+    names, compiled = gpt2_program
     instrs = names.instructions(compiled.as_text())
     plain = lambda s: re.sub(r"\{[^}]*\}", "", s)
     logits = re.compile(r"f32\[(1,)*2,2048,50257\]")   # not [1,2048,50257]
@@ -386,3 +395,26 @@ def test_gpt2_client_update_passes_over_the_logits_once(topo,
     # arrays: 3.45 GB here against the parent's 2.62 with ``logp`` in it
     # and 1.80 for three separate reductions (PERF.md, PR 32)
     assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
+
+
+def test_gpt2_client_update_steps_the_token_table_by_rows(gpt2_program):
+    """The row step (ISSUE 38) as the v5e compiler leaves it: in the step
+    loop's body ONE instruction writes an array of the token table's
+    shape, a fusion whose root is the scatter into its table operand. No
+    copy or cast of the table (a gather batched over the one lane asks
+    for the table retiled, and the compiler casts it to bf16 on the way:
+    ``ops/row_embed.py`` ``take_rows`` takes the lane axis off; the CPU's
+    compiler makes no such copy, so only this compile shows it), no
+    zeroed dense gradient, no dense select. Outside the loop: the copy of
+    the carried table and the payload's weighted sum."""
+    names, compiled = gpt2_program
+    text = compiled.as_text()
+    entry = re.search(r"^ENTRY %([\w.\-]+)", text, re.M).group(1)
+    body = [(name, opcode) for comp, name, opcode, _, _ in
+            names.table_shaped(text, 50257, 2048) if comp != entry]
+    assert len(body) == 1 and body[0][1] == "fusion", body
+    called = re.search(rf"%{re.escape(body[0][0])} = .*?calls=%([\w.\-]+)",
+                       text).group(1)
+    root = names._computations(text)[called]
+    assert any(re.match(r"\s+ROOT %\S+ = f32\[50257,2048\]\S* scatter\(",
+                        line) for line in root), called
